@@ -7,11 +7,13 @@ RUN apt-get update && apt-get install -y --no-install-recommends g++ \
 
 WORKDIR /pilosa-tpu
 COPY pilosa_tpu ./pilosa_tpu
-COPY bench.py Makefile ./
+COPY bench.py chip_smoke.py Makefile ./
 
 RUN pip install --no-cache-dir numpy jax \
     && make native
 
+# One process owns the chip; compiled programs persist here.
+ENV JAX_COMPILATION_CACHE_DIR=/data/.jax_cache
 VOLUME /data
 EXPOSE 10101
 ENTRYPOINT ["python", "-m", "pilosa_tpu.cli"]

@@ -27,7 +27,7 @@ plannercheck:
 profcheck:
 	JAX_PLATFORMS=cpu python tools/profcheck.py
 
-# Perf-regression gate over PERF_LEDGER.jsonl (PR 19): the latest row
+# Perf-regression gate over benchmarks/ledger.jsonl (PR 19): the latest row
 # of every recorded (bench, metric, backend) series is checked against
 # its trailing-median baseline with MAD-widened tolerance. Green on an
 # absent/young ledger; deterministic on re-run.
@@ -165,11 +165,15 @@ check-collect:
 lint:
 	python tools/lint.py pilosa_tpu tests
 
-native: pilosa_tpu/native/libpilosa_native.so
+# Strict build of the native host runtime (one compiler line, in
+# pilosa_tpu/native/__init__.py): fails loudly where the lazy loader
+# would log and serve from pure Python.
+native:
+	python -c "from pilosa_tpu import native; native.build()"
 
-pilosa_tpu/native/libpilosa_native.so: pilosa_tpu/native/roaring.cpp
-	g++ -O3 -shared -fPIC -std=c++17 -o $@ $<
-
+# Kernel-floor microbenchmark; exits non-zero when JAX finds no
+# accelerator. Run it, and chip_smoke.py (the served path's proof of
+# life), through the chip tool: one process per chip.
 bench:
 	python bench.py
 
@@ -178,4 +182,5 @@ cover:
 
 clean:
 	rm -f pilosa_tpu/native/libpilosa_native.so
+	rm -rf .jax_cache chiprun_out
 	find . -name __pycache__ -type d -exec rm -rf {} +

@@ -23,9 +23,9 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from pilosa_tpu.utils.platform import apply_platform_override  # noqa: E402
+from pilosa_tpu.utils import compilecache  # noqa: E402
 
-apply_platform_override()
+compilecache.enable()
 # This benchmark's metric is EXECUTION latency of the fused Tanimoto
 # TopN (its repeated identical queries would otherwise be served by
 # the whole-result memos as dict lookups — the r3 chip comparison

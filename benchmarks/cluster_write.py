@@ -17,9 +17,9 @@ import urllib.request
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-from pilosa_tpu.utils.platform import apply_platform_override  # noqa: E402
+from pilosa_tpu.utils import compilecache  # noqa: E402
 
-apply_platform_override()
+compilecache.enable()
 
 import numpy as np  # noqa: E402
 

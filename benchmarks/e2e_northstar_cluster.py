@@ -38,9 +38,9 @@ os.environ.setdefault("PILOSA_TPU_STACK_BYTES", str(256 << 20))
 import numpy as np  # noqa: E402
 
 from pilosa_tpu import SLICE_WIDTH  # noqa: E402
-from pilosa_tpu.utils.platform import apply_platform_override  # noqa: E402
+from pilosa_tpu.utils import compilecache  # noqa: E402
 
-apply_platform_override()
+compilecache.enable()
 
 N_SLICES = int(os.environ.get("NORTHSTAR_SLICES", "954"))
 SECONDS = float(os.environ.get("NORTHSTAR_SECONDS", "10"))

@@ -19,10 +19,10 @@ Two workload shapes:
 
 Reports bits/s + sustained q/s during each phase, the headline ratio
 (wide shape, under load), and the compressed-landing evidence
-(containers seeded by format, zero conversion churn). ``--record``
-appends the JSONL rows to BENCH_DETAIL.md.
+(containers seeded by format, zero conversion churn), as JSONL rows on
+stdout.
 
-Run: python benchmarks/ingest.py [--bits 250000] [--record]
+Run: python benchmarks/ingest.py [--bits 250000]
 """
 import argparse
 import json
@@ -39,9 +39,9 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from pilosa_tpu.utils.platform import apply_platform_override  # noqa: E402
+from pilosa_tpu.utils import compilecache  # noqa: E402
 
-apply_platform_override()
+compilecache.enable()
 
 from pilosa_tpu import SLICE_WIDTH  # noqa: E402
 from pilosa_tpu.ingest import codec  # noqa: E402
@@ -126,8 +126,6 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--bits", type=int, default=250_000)
     ap.add_argument("--slices", type=int, default=2)
-    ap.add_argument("--record", action="store_true",
-                    help="append JSONL rows to BENCH_DETAIL.md")
     opts = ap.parse_args()
 
     tmp = tempfile.mkdtemp(prefix="ingest-bench-")
@@ -205,13 +203,6 @@ def main():
         print(f"\nheadline: ingest {wide:.1f}x legacy (wide shape, "
               f"under concurrent query load); containers land "
               f"compressed with {conv} conversions")
-        if opts.record:
-            with open(os.path.join(os.path.dirname(__file__), "..",
-                                   "BENCH_DETAIL.md"), "a") as f:
-                f.write("\n```\n")
-                for r in rows_out:
-                    f.write(json.dumps(r) + "\n")
-                f.write("```\n")
         return 0 if wide >= 10 else 1
     finally:
         srv.close()

@@ -18,8 +18,7 @@ overhead of the BENCHMARK harness itself is out of both arms.
 
 MESH_FANOUT_SLICES (default 64) sets the slice count;
 MESH_FANOUT_NODES (default 4) the pod size; MESH_FANOUT_N (default
-200) the timed queries per arm; --record appends the JSONL rows to
-BENCH_DETAIL.md.
+200) the timed queries per arm. The JSONL rows go to stdout.
 """
 import json
 import os
@@ -34,9 +33,9 @@ if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
         flags + " --xla_force_host_platform_device_count=8").strip()
 
-from pilosa_tpu.utils.platform import apply_platform_override  # noqa: E402
+from pilosa_tpu.utils import compilecache  # noqa: E402
 
-apply_platform_override()
+compilecache.enable()
 
 try:
     from benchmarks import _ledger  # noqa: E402
@@ -169,16 +168,6 @@ def main():
         if ok:
             print(f"PASS bit-exact ({mesh_count}), one collective "
                   f"launch per query, {speedup:.1f}x over HTTP")
-        if "--record" in sys.argv:
-            with open(os.path.join(
-                    os.path.dirname(os.path.dirname(
-                        os.path.abspath(__file__))),
-                    "BENCH_DETAIL.md"), "a") as f:
-                f.write("\n## Collective data plane — mesh vs HTTP "
-                        "fan-out (mesh_fanout.py)\n\n```\n")
-                for row in rows:
-                    f.write(json.dumps(row) + "\n")
-                f.write("```\n")
         return 0 if ok else 1
     finally:
         cluster.close()

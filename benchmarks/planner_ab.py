@@ -24,7 +24,7 @@ those shapes planner-on vs planner-off on the same engine:
                                      the perfwatch trend)
 
 Every pair is checked bit-exact before timing; rows land in
-PERF_LEDGER.jsonl via benchmarks/_ledger.py so tools/perfwatch.py
+benchmarks/ledger.jsonl via benchmarks/_ledger.py so tools/perfwatch.py
 gates the trend.
 
 Env knobs:

@@ -1,4 +1,4 @@
-"""BASELINE.json config suite on the real chip, with single-thread CPU
+"""BASELINE.json config suite on the chip, with single-thread CPU
 NumPy baselines of the identical computation.
 
 Configs (BASELINE.json "configs"):
@@ -9,8 +9,8 @@ Configs (BASELINE.json "configs"):
   5. 64-slice sharded Count(Intersect)  (bench.py's north star)
 
 Timing uses the marginal-cost method (see bench.py): K in-jit
-repetitions, per-op time from the repetition delta, so the ~65 ms relay
-round-trip this environment adds per host fetch cancels out.
+repetitions, per-op time from the repetition delta, so the fixed
+dispatch and host-fetch cost of a call cancels out.
 
 Run: python benchmarks/suite.py   (prints a markdown table)
 """

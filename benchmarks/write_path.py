@@ -27,9 +27,9 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from pilosa_tpu.utils.platform import apply_platform_override  # noqa: E402
+from pilosa_tpu.utils import compilecache  # noqa: E402
 
-apply_platform_override()
+compilecache.enable()
 
 from pilosa_tpu import SLICE_WIDTH  # noqa: E402
 from pilosa_tpu.server.server import Server  # noqa: E402

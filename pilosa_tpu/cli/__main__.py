@@ -6,11 +6,7 @@ bench, generate-config, config.
 """
 import sys
 
-from pilosa_tpu.utils.platform import apply_platform_override
-
-apply_platform_override()
-
-from pilosa_tpu.cli import commands  # noqa: E402
+from pilosa_tpu.cli import commands
 
 
 def main(argv=None):

@@ -2,13 +2,7 @@
 cmd/root.go:60-107 setAllConfig)."""
 import os
 
-try:
-    import tomllib  # Python 3.11+
-except ModuleNotFoundError:  # pragma: no cover - interpreter-dependent
-    try:
-        import tomli as tomllib  # the PyPI backport, same API
-    except ModuleNotFoundError:
-        from pilosa_tpu.utils import minitoml as tomllib
+import tomllib
 
 DEFAULT_PORT = 10101        # ref: config.go:17-32
 DEFAULT_BIND = f"localhost:{DEFAULT_PORT}"

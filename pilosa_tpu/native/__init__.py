@@ -1,11 +1,14 @@
 """ctypes loader for the native host runtime (roaring.cpp).
 
-Compiles on demand with g++ (cached beside the source); every consumer
-falls back to the pure-Python implementation when the toolchain or the
-shared object is unavailable, so the native layer is a transparent
-accelerator, never a hard dependency.
+Compiles on demand with g++ (cached beside the source). Every consumer
+keeps a bit-identical pure-Python implementation, so a host without a
+toolchain still serves — but never quietly: a failed build or load is
+logged, and ``nativeLoaded`` in the /debug/vars ``device`` block says
+which implementation is running. :func:`build` is the strict entry
+(``make native``, the Dockerfile, ``chip_smoke.py``): it raises.
 """
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -16,15 +19,35 @@ _HERE = os.path.dirname(__file__)
 _SRC = os.path.join(_HERE, "roaring.cpp")
 _SO = os.path.join(_HERE, "libpilosa_native.so")
 
+_LOG = logging.getLogger("pilosa_tpu.native")
+
 _lock = lockcheck.register("native._lock", threading.Lock())
 _lib = None
 _tried = False
 
 
-def _build(out=_SO):
+def build(out=_SO):
+    """Compile roaring.cpp into ``out``; raise RuntimeError with the
+    compiler's own words when g++ is missing or refuses. Installed by
+    rename, so a process that has the old object mapped keeps it."""
+    tmp = out + ".tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-           "-o", out, _SRC]
-    subprocess.run(cmd, check=True, capture_output=True)
+           "-o", tmp, _SRC]
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, text=True)
+        os.replace(tmp, out)
+    except FileNotFoundError as exc:
+        raise RuntimeError(f"native build impossible: {exc}") from exc
+    except subprocess.CalledProcessError as exc:
+        raise RuntimeError(
+            f"native build failed (rc={exc.returncode}): "
+            f"{exc.stderr.strip()[-2000:]}") from exc
+
+
+def _unavailable(exc):
+    _LOG.warning("native runtime unavailable, serving from pure "
+                 "Python: %s", exc)
+    return None
 
 
 def load():
@@ -37,7 +60,7 @@ def load():
         try:
             if (not os.path.exists(_SO)
                     or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-                _build()
+                build()
             lib = ctypes.CDLL(_SO)
             lib.pn_serialize_w  # newest symbol: stale .so (equal mtimes
         except AttributeError:  # after checkout) -> force one rebuild
@@ -46,19 +69,18 @@ def load():
             # path; the fresh build also replaces _SO for next time.
             rebuilt = _SO + ".rebuild.so"
             try:
-                _build(rebuilt)
+                build(rebuilt)
                 lib = ctypes.CDLL(rebuilt)
                 lib.pn_serialize_w
                 os.replace(rebuilt, _SO)
-            except (OSError, subprocess.CalledProcessError,
-                    AttributeError):
+            except (OSError, RuntimeError, AttributeError) as exc:
                 try:
                     os.unlink(rebuilt)
                 except OSError:
                     pass
-                return None
-        except (OSError, subprocess.CalledProcessError):
-            return None
+                return _unavailable(exc)
+        except (OSError, RuntimeError) as exc:
+            return _unavailable(exc)
 
         u8p = ctypes.POINTER(ctypes.c_uint8)
         u64p = ctypes.POINTER(ctypes.c_uint64)

@@ -15,46 +15,25 @@ All kernels are count-only reductions over ``uint32`` words:
 - :func:`count_and_rows`— per-row popcount(matrix & filter) (TopN Src /
   BSI plane counts / Tanimoto numerators)
 
-**Measured result (v5e, 2026-07, benchmarks/pallas_vs_xla.py): XLA wins.**
-On the 64-slice Count(Intersect) shape XLA's auto-fusion reaches
-~670-690 GB/s effective vs ~470-530 GB/s for the best Pallas geometry
-here (vector VMEM accumulators, (8, 2048) blocks); on the per-row TopN
-shape XLA reaches ~790-920 GB/s vs ~420-540 GB/s. These ops are pure
-bandwidth-bound elementwise+reduce chains — exactly what XLA schedules
-optimally — so the production paths in :mod:`pilosa_tpu.ops.bitops`
-stay on XLA and this module is an experimental backend kept for
-geometry re-tuning on future TPU generations. Nothing routes through
-it by default.
+Not measured on current code: the production paths in
+:mod:`pilosa_tpu.ops.bitops` stay on XLA's own fusion, nothing routes
+through this module, and ``chip_smoke.py`` only proves the kernels
+compile under Mosaic and agree with XLA (ROADMAP D10 decides whether
+they stay).
+
+Every kernel takes ``interpret``: False (the default) compiles for the
+TPU; tests pass True to run the same bodies in the Pallas interpreter
+on the CPU mesh. The mode is something the caller asks for, never a
+backend sniff.
 """
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # pallas is TPU/GPU-only at runtime but always importable
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
-
-
-def use_pallas() -> bool:
-    """True when the default backend is a real TPU (not the CPU mesh)."""
-    if not _HAVE_PALLAS:
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
-
-
-def _interpret() -> bool:
-    """Off-TPU (the 8-device CPU test mesh) run kernels in interpreter
-    mode so their logic stays unit-testable everywhere."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # pragma: no cover
-        return True
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 # Block geometry. A slice row is 32768 uint32 words; (8, 2048) int32
@@ -122,8 +101,8 @@ def _count_and_kernel(a_ref, b_ref, out_ref, acc_ref):
         out_ref[0, 0] = jnp.sum(acc_ref[:])
 
 
-@jax.jit
-def count_and(a, b):
+@partial(jax.jit, static_argnames=("interpret",))
+def count_and(a, b, interpret=False):
     """popcount(a & b) -> int32 scalar; a, b: uint32[S, W]."""
     if a.ndim == 1:
         a = a[None, :]
@@ -143,7 +122,7 @@ def count_and(a, b):
         out_specs=pl.BlockSpec((1, 1), lambda i, j: (0, 0),
                                memory_space=pltpu.SMEM),
         scratch_shapes=[pltpu.VMEM((1, _LANE), jnp.int32)],
-        interpret=_interpret(),
+        interpret=interpret,
     )(a, b)
     return out[0, 0]
 
@@ -169,8 +148,8 @@ def _count_and_rows_kernel(m_ref, f_ref, out_ref, acc_ref):
         out_ref[:] = jnp.sum(acc_ref[:], axis=1, keepdims=True)
 
 
-@jax.jit
-def count_and_rows(m, filt):
+@partial(jax.jit, static_argnames=("interpret",))
+def count_and_rows(m, filt, interpret=False):
     """Per-row popcount(m & filt): uint32[R, W], uint32[W] -> int32[R]."""
     n_rows = m.shape[0]
     m, filt = _pad_rows(_pad_lanes(m)), _pad_lanes(filt)
@@ -186,13 +165,13 @@ def count_and_rows(m, filt):
         ],
         out_specs=pl.BlockSpec((br, 1), lambda i, j: (i, 0)),
         scratch_shapes=[pltpu.VMEM((br, _LANE), jnp.int32)],
-        interpret=_interpret(),
+        interpret=interpret,
     )(m, filt[None, :])
     return out[:n_rows, 0]
 
 
-@jax.jit
-def count_rows(m):
+@partial(jax.jit, static_argnames=("interpret",))
+def count_rows(m, interpret=False):
     """Per-row popcount: uint32[R, W] -> int32[R].
 
     Routed through :func:`count_and_rows` with an all-ones filter so
@@ -200,4 +179,5 @@ def count_rows(m):
     filter read is W words against R×W read for the matrix.
     """
     return count_and_rows(m, jnp.full((m.shape[-1],), 0xFFFFFFFF,
-                                      dtype=jnp.uint32))
+                                      dtype=jnp.uint32),
+                          interpret=interpret)

@@ -31,10 +31,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from pilosa_tpu.parallel.compat import UNCHECKED, shard_map
 
 REPLICA_AXIS = "replica"
 SLICE_AXIS = "slice"
@@ -192,13 +190,12 @@ class ReplicaMeshEngine:
             local = lax.psum(jnp.sum(mixed), SLICE_AXIS)
             return lax.all_gather(local, REPLICA_AXIS)
 
-        # Replication checking off (compat.UNCHECKED spells the kwarg
-        # for this JAX version): after the all_gather every device
+        # Replication checking off: after the all_gather every device
         # holds the same [replica_n] vector, but varying-mesh-axis
         # inference can't prove replica-invariance statically.
         return shard_map(kernel, mesh=self.mesh,
                          in_specs=(P(SLICE_AXIS),),
-                         out_specs=P(), **UNCHECKED)(rows)
+                         out_specs=P(), check_vma=False)(rows)
 
     def replicas_consistent(self, rows):
         """Host-side check: True when all replica copies digest equal."""

@@ -22,12 +22,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pilosa_tpu.ops import bitops
-
-from pilosa_tpu.parallel.compat import shard_map
 
 
 def make_mesh(n_devices=None, axis="slice"):

@@ -1816,9 +1816,7 @@ class Handler:
             data["remoteBatcher"] = dict(rb)
         if self._resp_cache is not None:
             data["responseCache"] = self._resp_cache.stats()
-        warm = getattr(self.executor, "_warm_stats", None)
-        if warm and (warm.get("compiled") or warm.get("failed")):
-            data["widthWarmer"] = dict(warm)
+        data["widthWarmer"] = self.executor.warm_snapshot()
         if self.tracer.enabled:
             data["tracing"] = self.tracer.summary()
         # One consistent snapshot: the qos/faults/memory groups answer
@@ -1847,6 +1845,7 @@ class Handler:
         data["slo"] = self.slo.snapshot()
         data["costModel"] = costmodel_mod.ACTIVE.snapshot()
         data["autopilot"] = self.autopilot.snapshot()
+        data["device"] = stats_mod.device_telemetry()
         if self.histograms.enabled:
             data["histograms"] = self.histograms.snapshot()
         return 200, "application/json", json.dumps(data).encode()

@@ -18,6 +18,7 @@ from pilosa_tpu.executor import Executor
 from pilosa_tpu.server.handler import Handler, make_http_server
 from pilosa_tpu.stats import new_stats_client
 from pilosa_tpu.storage.holder import Holder
+from pilosa_tpu.utils import compilecache
 
 DEFAULT_ANTI_ENTROPY_INTERVAL = 600   # 10 min (ref: server.go:44)
 DEFAULT_POLLING_INTERVAL = 60         # max-slice poll (ref: server.go:321)
@@ -48,6 +49,7 @@ class Server:
                  rebalance_drain_timeout=None,
                  observe=None, profile=None, slo=None, mesh=None,
                  autopilot=None, hedge=None):
+        compilecache.enable()  # before the first jit of this process
         self.data_dir = data_dir
         self.bind = bind
         self.host = bind
@@ -682,6 +684,9 @@ class Server:
 
     def open(self):
         """(ref: Server.Open server.go:123-234)."""
+        # Say what this process computes on before serving anything: a
+        # CPU fallback must never pass for a chip (also /debug/vars).
+        _LOG.info("device: %s", stats_mod.device_telemetry())
         self.holder.open()
         self._load_path_model()
         # Master response replay on EVERY topology: single-node

@@ -185,9 +185,9 @@ class RelayCostModel:
 
 class WorkerExecutor:
     def __init__(self, data_dir):
-        from pilosa_tpu.utils.platform import apply_platform_override
+        from pilosa_tpu.utils import compilecache
 
-        apply_platform_override()
+        compilecache.enable()
         from pilosa_tpu.executor import Executor
         from pilosa_tpu.server.handler import Handler
         from pilosa_tpu.storage import fragment as fragment_mod

@@ -824,3 +824,25 @@ def process_telemetry(started_at=None):
     except OSError:
         pass
     return out
+
+
+def device_telemetry():
+    """What this process computes on, as JAX reports it: platform,
+    device kind and count, per-device ``memory_stats()`` (None where
+    the backend keeps none, e.g. CPU), whether the native host runtime
+    loaded, and where the persistent compile cache lives. Logged at
+    server boot and served as the ``device`` block of /debug/vars, so a
+    client can always tell a chip from a CPU fallback."""
+    import jax
+
+    from pilosa_tpu import native
+
+    devs = jax.devices()
+    return {
+        "platform": devs[0].platform,
+        "deviceKind": devs[0].device_kind,
+        "deviceCount": len(devs),
+        "memoryStats": [d.memory_stats() for d in devs],
+        "nativeLoaded": native.available(),
+        "compileCacheDir": jax.config.jax_compilation_cache_dir,
+    }

@@ -1912,7 +1912,7 @@ class Fragment:
     def _row_counts_device(self, n_phys):
         """Device copy of the per-row cardinalities, memoized against
         the mutation version — the Tanimoto denominator reads it every
-        query and a per-query upload costs a relay round trip. The
+        query and would otherwise be uploaded per query. The
         version check subsumes every invalidation site (any mutation
         bumps ``_version``); callers hold ``self.mu``."""
         rc = self._rc_dev

@@ -37,7 +37,7 @@ def _post(port, path, body, timeout=30):
 
 def _spawn(data_dir, port, workers=0):
     env = dict(os.environ)
-    env["PILOSA_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))
     args = [sys.executable, "-m", "pilosa_tpu.cli", "server", "-d",

@@ -438,24 +438,10 @@ def test_local_mesh_rebuilds_on_device_topology_change(monkeypatch):
     assert ex._local_mesh().devices.size == len(real)
 
 
-def test_shard_map_compat_shim_version_probe():
-    """parallel/compat.py pin: the NEXT JAX skew must fail HERE, not
-    silently run every unchecked kernel fully-checked (or worse, stop
-    collecting). If this fails, teach compat.py the new kwarg name."""
-    import inspect
-
-    from pilosa_tpu.parallel import compat
-
-    params = inspect.signature(compat.shard_map).parameters
-    known = [k for k in ("check_vma", "check_rep") if k in params]
-    assert known, (
-        "JAX version skew: shard_map exposes neither check_vma nor "
-        f"check_rep (params: {sorted(params)}); update "
-        "parallel/compat.py's probe list")
-    assert compat.UNCHECKED == {known[0]: False}
-
-    # Functional probe: an UNCHECKED kernel (all_gather output the
-    # replication checker can't see through) must actually compile.
+def test_shard_map_unchecked_kernel_compiles():
+    """A kernel whose output replication the checker can't prove (an
+    all_gather) must compile with ``check_vma=False``, the form
+    parallel/distributed.py uses."""
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
@@ -467,8 +453,8 @@ def test_shard_map_compat_shim_version_probe():
     def kernel(x):
         return lax.all_gather(jnp.sum(x), "slice")
 
-    out = compat.shard_map(kernel, mesh=mesh, in_specs=(P("slice"),),
-                           out_specs=P(), **compat.UNCHECKED)(
+    out = jax.shard_map(kernel, mesh=mesh, in_specs=(P("slice"),),
+                        out_specs=P(), check_vma=False)(
         jnp.arange(len(jax.devices()), dtype=jnp.int32))
     assert int(np.asarray(out).sum()) >= 0
 

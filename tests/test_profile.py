@@ -354,12 +354,5 @@ def test_perfwatch_direction_and_baseline_rules(tmp_path, capsys):
     assert "no baseline yet" in out
 
 
-def test_perfwatch_informational_rows_never_gate(tmp_path):
-    path = str(tmp_path / "ledger.jsonl")
-    _write_series(path, [1.0, 1.0, 1.0, 1.0, 0.0],
-                  metric="relay_healthy", unit="1 = probe ok")
-    assert perfwatch.main([path]) == 0
-
-
 def test_perfwatch_empty_ledger_ok(tmp_path):
     assert perfwatch.main([str(tmp_path / "absent.jsonl")]) == 0

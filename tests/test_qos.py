@@ -407,16 +407,6 @@ def test_oversized_body_413(tmp_path):
     cfg.validate()
 
 
-def test_minitoml_parses_dotted_qos_quotas_table():
-    """The vendored TOML fallback must parse the documented
-    [qos.quotas] nested table — the form Config.to_toml emits."""
-    from pilosa_tpu.utils import minitoml
-
-    out = minitoml.loads(
-        '[qos]\nenabled = true\n\n[qos.quotas]\n"etl" = 0.5\n')
-    assert out == {"qos": {"enabled": True, "quotas": {"etl": 0.5}}}
-
-
 def test_negative_content_length_400(tmp_path):
     """Content-Length: -1 must 400, never reach rfile.read(-1) (an
     unbounded until-EOF buffer past the 413 gate)."""

@@ -379,7 +379,7 @@ def test_holder_dir_lock_replaces_per_fragment_flocks(tmp_path):
             [sys.executable, "-c", f"""
 import sys; sys.path.insert(0, {os.path.dirname(os.path.dirname(os.path.abspath(__file__)))!r})
 import os
-os.environ["PILOSA_TPU_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 from pilosa_tpu.storage.holder import Holder
 from pilosa_tpu import errors as perr
 try:
@@ -432,7 +432,7 @@ def test_mixed_era_locks_still_mutually_exclude(tmp_path):
         r = subprocess.run([sys.executable, "-c", f"""
 import sys; sys.path.insert(0, {root!r})
 import os
-os.environ["PILOSA_TPU_PLATFORM"] = "cpu"
+os.environ["JAX_PLATFORMS"] = "cpu"
 from pilosa_tpu.storage.fragment import Fragment
 from pilosa_tpu import errors as perr
 try:

@@ -2,9 +2,8 @@
 observability satellites: span nesting, ring eviction, header
 propagation through Handler.dispatch and across a real 2-node
 cluster, the slow-query flight recorder on /metrics, prometheus
-exposition edge cases, statsd client-side sampling, and the py3.10
-config (tomllib fallback) regression."""
-import io
+exposition edge cases, statsd client-side sampling, and the config
+TOML round trip."""
 import json
 import urllib.request
 
@@ -393,12 +392,12 @@ def test_statsd_rate_sampling_deterministic():
     assert sent[-1] == "tagged:1|c|@0.9|#k:v"
 
 
-# ------------------------------------------------- config py3.10 regression
+# ------------------------------------------------- config TOML round trip
 
 
 def test_config_imports_and_loads_on_this_interpreter(tmp_path):
-    """Regression for the py3.10 tomllib break: the module must import
-    and parse TOML on whatever interpreter runs the suite."""
+    """The module must import and parse TOML, and what it generates
+    must round-trip through the same reader."""
     import pilosa_tpu.config as cfgmod
 
     p = tmp_path / "c.toml"
@@ -413,30 +412,3 @@ def test_config_imports_and_loads_on_this_interpreter(tmp_path):
     p2.write_text(cfg.to_toml())
     rt = cfgmod.Config.load(str(p2), env={})
     assert rt.trace == cfg.trace
-
-
-def test_minitoml_fallback_parses_config_subset():
-    """The vendored last-resort reader handles everything
-    Config.to_toml emits, with the tomllib API shape."""
-    from pilosa_tpu.config import Config
-    from pilosa_tpu.utils import minitoml
-
-    text = Config().to_toml()
-    data = minitoml.load(io.BytesIO(text.encode()))
-    assert data["bind"] == Config().bind
-    assert data["cluster"]["replicas"] == 1
-    assert data["cluster"]["hosts"] == [Config().bind]
-    assert data["trace"]["enabled"] is False
-    assert data["trace"]["slow-threshold"] == 0.25
-    # Inline comments after values — including after a closed string,
-    # the docs/configuration.md example shape — must strip.
-    inline = minitoml.loads('host = "127.0.0.1:8125"  # statsd target\n'
-                            'n = 3  # count\n'
-                            'frag = "has # inside"\n'
-                            '[trace]  # table-header comment\n'
-                            'enabled = true\n')
-    assert inline == {"host": "127.0.0.1:8125", "n": 3,
-                      "frag": "has # inside",
-                      "trace": {"enabled": True}}
-    with pytest.raises(minitoml.TOMLDecodeError):
-        minitoml.loads("key value-without-equals")

@@ -50,7 +50,7 @@ def _wait_listening(port, timeout=30):
 
 def _spawn_worker(port, sock_path, extra=(), env_extra=()):
     env = dict(os.environ)
-    env["PILOSA_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     if "--exec-reads" in extra:
         env["PILOSA_TPU_READ_ONLY"] = "1"  # as WorkerPool does
     env.update(dict(env_extra))

@@ -34,9 +34,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from pilosa_tpu import SLICE_WIDTH  # noqa: E402
-from pilosa_tpu.utils.platform import apply_platform_override  # noqa: E402
-
-apply_platform_override()
 
 N_SLICES = 3
 PAIRS = [(1, 2), (1, 3), (2, 3), (1, 5), (2, 5), (3, 4), (4, 5)]

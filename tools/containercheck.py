@@ -28,9 +28,6 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-from pilosa_tpu.utils.platform import apply_platform_override  # noqa: E402
-
-apply_platform_override()
 
 SLICE_WIDTH = 1 << 20
 

@@ -46,9 +46,6 @@ if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
         flags + " --xla_force_host_platform_device_count=4").strip()
 
-from pilosa_tpu.utils.platform import apply_platform_override  # noqa: E402
-
-apply_platform_override()
 
 from pilosa_tpu import SLICE_WIDTH  # noqa: E402
 

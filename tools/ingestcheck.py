@@ -32,9 +32,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from pilosa_tpu.utils.platform import apply_platform_override  # noqa: E402
-
-apply_platform_override()
 
 from pilosa_tpu import SLICE_WIDTH  # noqa: E402
 from pilosa_tpu.ingest import codec  # noqa: E402

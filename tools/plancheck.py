@@ -22,9 +22,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from pilosa_tpu import SLICE_WIDTH  # noqa: E402
-from pilosa_tpu.utils.platform import apply_platform_override  # noqa: E402
-
-apply_platform_override()
 
 WARM_QUERIES = 50
 
