@@ -1,0 +1,5 @@
+"""Layer: HTTP front end. Source: program_span (client latency minus the
+root span of ``?profile=true``), median. Moves query_p50_ms."""
+from perfbench.lib import layer
+
+read = layer.http_outside_ms
