@@ -71,6 +71,52 @@ KNOWN_CALLS = frozenset({
 
 logger = logging.getLogger("pilosa_tpu.executor")
 
+
+# Operand counts past this share one program name: names stay few.
+PROGRAM_NAME_MAX_OPERANDS = 16
+
+
+def program_name(tier, operands):
+    """The name a jitted program of this executor carries in a device
+    trace (``jit_<name>`` on the ``XLA Modules`` line): the tier that
+    built it and how many operand stacks it reads, as
+    ``pilosa_count_batched_k3``. No hash and no row id, so a trace's
+    gaps and device times can be put to a query shape by name."""
+    k = (f"k{operands}" if operands <= PROGRAM_NAME_MAX_OPERANDS
+         else f"k{PROGRAM_NAME_MAX_OPERANDS}p")
+    return f"pilosa_{tier}_{k}"
+
+
+def _plan_operands(node):
+    """How many operand stacks a batched plan reads: one past its
+    highest leaf slot (0 for no plan, as an unfiltered Sum has)."""
+    if node is None or node[0] == "empty":
+        return 0
+    if node[0] == "leaf":
+        return node[1] + 1
+    if node[0] == "bsi":
+        return max(node[1], *node[2]) + 1
+    return max((_plan_operands(kid) for kid in node[1]), default=0)
+
+
+def _run_count(fn, stacks):
+    """The batched Count call as the served path makes it: enqueue,
+    device wait and device-to-host copy in one expression."""
+    return np.asarray(fn(*stacks))
+
+
+def _run_count_split(fn, stacks):
+    """The same call under a trace, cut where the time can hide: the
+    jitted call until it returns (enqueue), the wait for the device,
+    and the copy to the host."""
+    with tracing.span("kernel.dispatch"):
+        out = fn(*stacks)
+    with tracing.span("kernel.wait"):
+        out.block_until_ready()
+    with tracing.span("kernel.fetch"):
+        return np.asarray(out)
+
+
 # Sentinel a batch_fn returns for "ran, and the answer is empty" — as
 # opposed to None, which means "ineligible, use the serial path".
 # _map_reduce absorbs it (empty overall result / skipped partial);
@@ -252,6 +298,10 @@ class Executor:
         self._warm_q = []
         self._warm_thread = None
         self._warm_stats = {"compiled": 0, "failed": 0}
+        # Batched dispatches the device refused for memory
+        # (RESOURCE_EXHAUSTED) and that fell to the per-slice path:
+        # /debug/vars ``oomFallbacks``, beside the per-query key.
+        self.oom_fallbacks = 0
         # Hinted handoff: writes skipped because a replica was DOWN,
         # keyed by host, replayed on rejoin (anti-entropy remains the
         # backstop for hints lost to a coordinator restart).
@@ -780,13 +830,17 @@ class Executor:
             if (req_deadline is not None
                     and time.monotonic() > req_deadline):
                 raise qos.DeadlineExceeded()
-            if first_map is not None:
-                by_node, first_map = first_map, None
-            elif route_on:
-                by_node = self._route_slices_by_node(nodes, index,
-                                                     pending)
-            else:
-                by_node = self._slices_by_node(nodes, index, pending)
+            with tracing.span("exec.route") as rsp:
+                if first_map is not None:
+                    by_node, first_map = first_map, None
+                elif route_on:
+                    by_node = self._route_slices_by_node(nodes, index,
+                                                         pending)
+                else:
+                    by_node = self._slices_by_node(nodes, index,
+                                                   pending)
+                if rsp is not tracing.NOP_SPAN:
+                    rsp.tag(choice="fanout", nodes=len(by_node))
             if hedger is not None and hedger.enabled:
                 remote_legs = sum(1 for node in by_node
                                   if node.host != self.host)
@@ -1033,6 +1087,17 @@ class Executor:
             else:
                 querystats.note_tier("batched")
             return out
+        with tracing.span("exec.route") as rsp:
+            choice, st, n, b = self._path_choice(call, node_slices)
+            if rsp is not tracing.NOP_SPAN:
+                rsp.tag(choice=choice)
+        return self._run_path(choice, st, n, b, node_slices, map_fn,
+                              reduce_fn, batch_fn)
+
+    def _path_choice(self, call, node_slices):
+        """The path model's pick for one (call structure, slice-count
+        bucket): (choice, the bucket's stat entry, its query count
+        before this one, its batched minimum)."""
         key = (self._call_shape(call), max(len(node_slices), 1).bit_length())
         with self._path_mu:
             st = self._path_stats.get(key)
@@ -1071,7 +1136,11 @@ class Executor:
                 # nothing anyway — the minima keep both honest).
                 choice = ("serial" if (s < 0.98 * b and probe_ok)
                           else "batched")
+        return choice, st, n, b
 
+    def _run_path(self, choice, st, n, b, node_slices, map_fn, reduce_fn,
+                  batch_fn):
+        """Serve by the chosen path and record what it took."""
         t0 = time.perf_counter()
         if choice.startswith("serial"):
             deadline = None
@@ -1275,10 +1344,13 @@ class Executor:
             # Direct (unwindowed) callers treat over-budget as a plain
             # decline.
             return None if out is BATCH_OVER_BUDGET else out
-        except Exception:
+        except Exception as e:
             logger.warning("batched path failed; falling back to "
                            "per-slice execution", exc_info=True)
             querystats.note_fallback("batched", "error")
+            if "RESOURCE_EXHAUSTED" in str(e):
+                self.oom_fallbacks += 1
+                querystats.add("oomFallbacks")
             return BATCH_TRANSIENT
 
     def _node_is_down(self, node):
@@ -1679,8 +1751,11 @@ class Executor:
         # Compact slice key (plancache.slice_key): hashing the full
         # slices tuple cost ~0.5 ms/query at 9,540 slices — the single
         # largest warm engine-path item profiled at 10B scale.
-        pkey = (kind, index, str(call), slice_key(slices))
-        hit = self._result_memo_get(pkey)
+        with tracing.span("result.memo") as msp:
+            pkey = (kind, index, str(call), slice_key(slices))
+            hit = self._result_memo_get(pkey)
+            if msp is not tracing.NOP_SPAN:
+                msp.tag(kind=kind, hit=hit is not None)
         if hit is not None:
             # Tier attribution: a memo replay never reaches the
             # mesh/coalesce/batched decision chain — "memo" is the
@@ -1737,21 +1812,25 @@ class Executor:
         # memoized, so a warm query pays one dict hit. None =
         # unplannable; the pre-planner path runs untouched.
         pl = self.planner
-        planned = (pl.plan_count(self, index, child, slices)
-                   if pl.active() and slices else None)
-        if planned is not None and planned["staticEmpty"]:
-            # Plan-time short-circuit: a statically-empty subtree
-            # (the BSI out-of-range shortcut) zeroes the whole count.
-            # No kernel, no fan-out — the plan derives from schema
-            # facts every node shares.
-            pl.note_static_empty()
-            querystats.note_tier("planner")
-            return 0
-        child2 = planned["child"] if planned is not None else child
-        use_sc = (planned is not None and planned["sc"]
-                  and pl.short_circuit)
-        tier, forced_record = (pl.decide_tier(self, planned)
-                               if planned is not None else (None, False))
+        with tracing.span("count.plan") as psp:
+            planned = (pl.plan_count(self, index, child, slices)
+                       if pl.active() and slices else None)
+            if planned is not None and planned["staticEmpty"]:
+                # Plan-time short-circuit: a statically-empty subtree
+                # (the BSI out-of-range shortcut) zeroes the whole
+                # count. No kernel, no fan-out — the plan derives
+                # from schema facts every node shares.
+                pl.note_static_empty()
+                querystats.note_tier("planner")
+                return 0
+            child2 = planned["child"] if planned is not None else child
+            use_sc = (planned is not None and planned["sc"]
+                      and pl.short_circuit)
+            tier, forced_record = (
+                pl.decide_tier(self, planned)
+                if planned is not None else (None, False))
+            if psp is not tracing.NOP_SPAN:
+                psp.tag(tier=tier or "static")
 
         def map_fn(s):
             if use_sc:
@@ -1814,7 +1893,8 @@ class Executor:
                     and len(self.cluster.nodes) > 1
                     and self.client is not None):
                 return run()
-            est = cm.estimate_count(self, index, child, slices)
+            with tracing.span("costmodel.estimate"):
+                est = cm.estimate_count(self, index, child, slices)
             qs0 = querystats.active()
             qs = qs0 if qs0 is not None else querystats.QueryStats()
             # Per-CALL mark: an inspected multi-call request's
@@ -1828,8 +1908,9 @@ class Executor:
                     out = run()
             else:
                 out = run()
-            cm.record_count(est, qs.served_since(mark),
-                            time.perf_counter() - t0)
+            elapsed = time.perf_counter() - t0
+            with tracing.span("costmodel.record"):
+                cm.record_count(est, qs.served_since(mark), elapsed)
             return out
 
         return self._scalar_result_memo(
@@ -2130,9 +2211,16 @@ class Executor:
             if ksp is not tracing.NOP_SPAN:
                 ksp.tag(first_compile=not hit,
                         warm_compiled=self._warm_stats["compiled"])
-            fn = self._batched_fn(tree_key, plan, padded_n, win[1])
+                with tracing.span("kernel.fn") as fsp:
+                    fn = self._batched_fn(tree_key, plan, padded_n,
+                                          win[1])
+                    fsp.tag(compile=not hit)
+                run = _run_count_split
+            else:
+                fn = self._batched_fn(tree_key, plan, padded_n, win[1])
+                run = _run_count
             if not obs.enabled:
-                counts = np.asarray(fn(*stacks))
+                counts = run(fn, stacks)
             else:
                 # The batched tree program: one cost row per
                 # (slice-count, width) shape class — np.asarray
@@ -2146,14 +2234,14 @@ class Executor:
                 w = 0 if w % self.OBS_STRIDE else self.OBS_STRIDE
                 if not hit or w:
                     t0 = time.perf_counter()
-                    counts = np.asarray(fn(*stacks))
+                    counts = run(fn, stacks)
                     obs.note(
                         "count_batched", "dense*dense",
                         kerneltime_mod.shape_bucket(padded_n * win[1] * 4),
                         time.perf_counter() - t0, compiled=not hit,
                         device=True, n=(1 if not hit else w))
                 else:
-                    counts = np.asarray(fn(*stacks))
+                    counts = run(fn, stacks)
                 if not hit:
                     # Cache-size gauge stamped on compiles only —
                     # per-query introspection would tax the warm path.
@@ -2172,7 +2260,8 @@ class Executor:
                             kerneltime_mod.shape_bucket(
                                 padded_n * win[1] * 4), fn, stacks)
         self._warm_wider(tree_key, plan, padded_n, win[1], stacks)
-        return int(counts[: len(slices)].sum())
+        with tracing.span("reduce"):
+            return int(counts[: len(slices)].sum())
 
     # ------------------------------------- cross-query count coalescing
 
@@ -3480,12 +3569,12 @@ class Executor:
                     exists, eval_node(plan, leaf_args, shape))
                 return Executor._minmax_descent(planes, m, depth,
                                                 find_max)
-            return jax.jit(jax.vmap(
-                single, in_axes=(None,) + (0,) * arity))
+            return jax.vmap(single, in_axes=(None,) + (0,) * arity)
 
         return self._cached_fn(
             ("minmaxK", tree_key, depth, find_max, padded_n, width32,
-             k_pad, arity), build)
+             k_pad, arity), build,
+            "max_fused" if find_max else "min_fused", arity)
 
     @staticmethod
     def _minmax_descent(planes, m, depth, find_max):
@@ -3541,12 +3630,11 @@ class Executor:
                     lax.population_count(filt).astype(jnp.int32),
                     axis=1)
                 return counts, filt_counts
-            return jax.jit(jax.vmap(
-                single, in_axes=(None,) + (0,) * arity))
+            return jax.vmap(single, in_axes=(None,) + (0,) * arity)
 
         return self._cached_fn(
             ("sumK", tree_key, depth, padded_n, width32, k_pad, arity),
-            build)
+            build, "sum_fused", arity)
 
     def _co_fused_fn(self, tree_key, plan, padded_n, width32, k_pad):
         import jax
@@ -3561,10 +3649,11 @@ class Executor:
                 out = eval_node(plan, args, shape)
                 return jnp.sum(
                     lax.population_count(out).astype(jnp.int32), axis=1)
-            return jax.jit(jax.vmap(single))
+            return jax.vmap(single)
 
         return self._cached_fn(
-            ("countK", tree_key, padded_n, width32, k_pad), build)
+            ("countK", tree_key, padded_n, width32, k_pad), build,
+            "count_fused", _plan_operands(plan))
 
     def _leaf_stack(self, index, frame_name, row_id, slices, pad, n_dev,
                     view=VIEW_STANDARD, win=None, frags=None):
@@ -3586,6 +3675,7 @@ class Executor:
         hit, stale = self._stack_cache_lookup(key, tokens)
         if hit is not None:
             return hit
+        querystats.add("stackBuilds")
 
         zero = self._zero_row(width32)
         stack = self._stack_incremental(
@@ -3649,6 +3739,7 @@ class Executor:
         stack, stale = self._stack_cache_lookup(key, tokens)
         if stack is not None:
             return stack
+        querystats.add("stackBuilds")
         zero_planes = jnp.zeros((depth + 1, width32), jnp.uint32)
         stack = self._stack_incremental(
             key, tokens, stale,
@@ -3911,53 +4002,71 @@ class Executor:
         _prelude_memo_get. The plan phase is timed into the active
         query-stats accumulator (``planMs``) so ``?profile=true``
         shows whether a query paid the walk."""
-        import jax
-
-        from pilosa_tpu.storage import fragment as _frag
-
         if not slices:
             return None
         qs = querystats.active()
         t0 = time.perf_counter() if qs is not None else 0.0
-        plan, leaves = self._plan_memoized(index, call)
+        with tracing.span("plan.tree"):
+            plan, leaves = self._plan_memoized(index, call)
         if plan is None or (compound_only and plan[0] == "leaf"):
             if qs is not None and plan is None:
                 qs.note_fallback("batched", "plan")
             return None
-        pkey = ("plan", index, slice_key(slices), str(plan),
-                tuple(leaves), extra_rows)
-        memo = self._prelude_memo_get(pkey)
+        with tracing.span("stacks.memo") as msp:
+            pkey = ("plan", index, slice_key(slices), str(plan),
+                    tuple(leaves), extra_rows)
+            memo = self._prelude_memo_get(pkey)
+            if msp is not tracing.NOP_SPAN:
+                msp.tag(hit=memo is not None)
         if memo is not None:
             if qs is not None:
                 qs.add("planMs", (time.perf_counter() - t0) * 1000)
             (mplan,), stacks, (padded_n, win) = memo
             return mplan, stacks, padded_n, win
+        with tracing.span("stacks.build"):
+            out = self._build_stacks(index, plan, leaves, slices,
+                                     extra_rows, pkey, qs)
+        if qs is not None and isinstance(out, tuple):
+            qs.add("planMs", (time.perf_counter() - t0) * 1000)
+        return out
+
+    def _build_stacks(self, index, plan, leaves, slices, extra_rows, pkey,
+                      qs):
+        """The prelude's miss path: fragment lists, the compressed-tier
+        and budget gates, the column window, one device stack per leaf
+        (from the stack cache where it holds one), and the memo entry
+        for the next query of this plan."""
+        import jax
+
+        from pilosa_tpu.storage import fragment as _frag
+
         epoch = _frag.mutation_epoch(index)  # BEFORE building (racy writes
         # during the build make the memo stale-on-arrival, not wrong)
         n_dev = len(jax.devices())
         pad = (-len(slices)) % n_dev
-        frag_map = self._leaf_frags(index, leaves, slices)
-        if self._compressed_plan(leaves, frag_map):
-            if qs is not None:
-                qs.note_fallback("batched", "compressed")
-            return None  # serial fallback = the compressed serving tier
-        win = self._union_window(frag_map)
-        rows = sum(self._spec_rows(sp) for sp in leaves) + extra_rows
-        if not self._fits_device_budget(rows, len(slices) + pad,
-                                        width32=win[1]):
-            if qs is not None:
-                qs.note_fallback("batched", "budget")
-            return BATCH_OVER_BUDGET
-        stacks = [self._spec_arg(index, sp, slices, pad, n_dev, win,
-                                 frag_map)
-                  for sp in leaves]
-        self._prelude_memo_put(
-            pkey, (plan,),
-            self._prelude_specs(index, leaves, stacks, slices, n_dev,
-                                win),
-            (len(slices) + pad, win), epoch)
-        if qs is not None:
-            qs.add("planMs", (time.perf_counter() - t0) * 1000)
+        with tracing.span("build.frags"):
+            frag_map = self._leaf_frags(index, leaves, slices)
+        with tracing.span("build.window"):
+            if self._compressed_plan(leaves, frag_map):
+                if qs is not None:
+                    qs.note_fallback("batched", "compressed")
+                return None  # serial fallback = the compressed tier
+            win = self._union_window(frag_map)
+            rows = sum(self._spec_rows(sp) for sp in leaves) + extra_rows
+            if not self._fits_device_budget(rows, len(slices) + pad,
+                                            width32=win[1]):
+                if qs is not None:
+                    qs.note_fallback("batched", "budget")
+                return BATCH_OVER_BUDGET
+        with tracing.span("build.args"):
+            stacks = [self._spec_arg(index, sp, slices, pad, n_dev, win,
+                                     frag_map)
+                      for sp in leaves]
+            self._prelude_memo_put(
+                pkey, (plan,),
+                self._prelude_specs(index, leaves, stacks, slices,
+                                    n_dev, win),
+                (len(slices) + pad, win), epoch)
         return plan, stacks, len(slices) + pad, win
 
     def _batched_bitmap_fn(self, tree_key, plan, padded_n, width32):
@@ -3969,7 +4078,6 @@ class Executor:
         shape = (padded_n, width32)
 
         def build():
-            @jax.jit
             def fn(*args):
                 out = eval_node(plan, args, shape)
                 counts = jnp.sum(
@@ -3978,7 +4086,8 @@ class Executor:
             return fn
 
         return self._cached_fn(("bitmap", tree_key, padded_n, width32),
-                               build)
+                               build, "bitmap_batched",
+                               _plan_operands(plan))
 
     def _topn_call_params(self, call):
         """Shared TopN arg parsing + validation: (frame_name, view, n,
@@ -4347,13 +4456,12 @@ class Executor:
         shape = (padded_n, width32)
 
         def build():
-            @jax.jit
             def fn(*args):
                 return eval_node(plan, args, shape)
             return fn
 
         return self._cached_fn(("src", tree_key, padded_n, width32),
-                               build)
+                               build, "topn_src", _plan_operands(plan))
 
     def _batched_topn_fn(self, has_src, r_pad, padded_n):
         import jax
@@ -4362,14 +4470,12 @@ class Executor:
 
         def build():
             if has_src:
-                @jax.jit
                 def fn(src, *rows):
                     outs = [jnp.sum(lax.population_count(
                         lax.bitwise_and(r, src)).astype(jnp.int32), axis=1)
                         for r in rows]
                     return jnp.stack(outs)
             else:
-                @jax.jit
                 def fn(*rows):
                     outs = [jnp.sum(
                         lax.population_count(r).astype(jnp.int32), axis=1)
@@ -4377,7 +4483,9 @@ class Executor:
                     return jnp.stack(outs)
             return fn
 
-        return self._cached_fn(("topn", has_src, r_pad, padded_n), build)
+        return self._cached_fn(
+            ("topn", has_src, r_pad, padded_n), build,
+            "topn_src_rows" if has_src else "topn_rows", r_pad)
 
     def _batched_topn_tanimoto_fn(self, r_pad, padded_n):
         import jax
@@ -4387,7 +4495,6 @@ class Executor:
         from pilosa_tpu.ops import topn as topn_ops
 
         def build():
-            @jax.jit
             def fn(src, *rows):
                 src_n = jnp.sum(
                     lax.population_count(src).astype(jnp.int32), axis=1)
@@ -4402,7 +4509,8 @@ class Executor:
                 return inter, scores
             return fn
 
-        return self._cached_fn(("topn_tan", r_pad, padded_n), build)
+        return self._cached_fn(("topn_tan", r_pad, padded_n), build,
+                               "topn_tanimoto", r_pad)
 
     def _batched_sum(self, index, call, slices):
         """Sum over the local slice list as one sharded XLA program:
@@ -4515,7 +4623,6 @@ class Executor:
         shape = (padded_n, width32)
 
         def build():
-            @jax.jit
             def fn(planes, *leaf_args):
                 exists = planes[:, depth, :]
                 if plan is None:
@@ -4529,7 +4636,8 @@ class Executor:
 
         return self._cached_fn(
             ("minmax", tree_key, depth, find_max, padded_n, width32),
-            build)
+            build, "max_batched" if find_max else "min_batched",
+            _plan_operands(plan))
 
     def _batched_sum_fn(self, tree_key, plan, depth, padded_n, width32):
         import jax
@@ -4540,7 +4648,6 @@ class Executor:
         shape = (padded_n, width32)
 
         def build():
-            @jax.jit
             def fn(planes, *leaf_args):
                 exists = planes[:, depth, :]
                 if plan is None:
@@ -4558,7 +4665,8 @@ class Executor:
             return fn
 
         return self._cached_fn(("sum", tree_key, depth, padded_n,
-                                width32), build)
+                                width32), build, "sum_batched",
+                               _plan_operands(plan))
 
     def _fits_device_budget(self, n_rows, padded_slices, width32=None):
         """Up-front HBM guard for batched stacks: ``n_rows`` row-sized
@@ -4606,12 +4714,12 @@ class Executor:
         import jax
 
         def build():
-            @jax.jit
             def fn(stack, idx, rows):
                 return stack.at[idx].set(rows)
             return fn
 
-        return self._cached_fn(("scatter_rows",), build)
+        return self._cached_fn(("scatter_rows",), build,
+                               "stack_scatter", 1)
 
     def _stack_incremental(self, key, tokens, stale, build_changed,
                            n_dev, ndim):
@@ -4824,12 +4932,18 @@ class Executor:
             return dict(self._warm_stats,
                         inflight=len(self._warm_inflight))
 
-    def _cached_fn(self, key, build):
-        """Bounded cache of jitted tree evaluators."""
+    def _cached_fn(self, key, build, tier, operands):
+        """Bounded cache of jitted tree evaluators. ``build`` returns
+        the plain function; it is jitted here under its program name
+        (``program_name``), which is what a device trace shows."""
+        import jax
+
         with self._cache_mu:
             if key in self._batched_cache:
                 return self._batched_cache[key]
         fn = build()
+        fn.__name__ = fn.__qualname__ = program_name(tier, operands)
+        fn = jax.jit(fn)
         with self._cache_mu:
             while len(self._batched_cache) >= self.BATCHED_FN_CACHE_MAX:
                 self._batched_cache.pop(next(iter(self._batched_cache)))
@@ -4929,14 +5043,14 @@ class Executor:
         shape = (padded_n, width32)
 
         def build():
-            @jax.jit
             def fn(*args):
                 out = eval_node(plan, args, shape)
                 return jnp.sum(
                     lax.population_count(out).astype(jnp.int32), axis=1)
             return fn
 
-        return self._cached_fn((tree_key, padded_n, width32), build)
+        return self._cached_fn((tree_key, padded_n, width32), build,
+                               "count_batched", _plan_operands(plan))
 
     # --------------------------------------------------------------- sum
 

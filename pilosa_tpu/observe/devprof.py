@@ -24,13 +24,15 @@ read.
 
 Also owns the on-demand bounded device trace capture behind
 ``POST /debug/profile/device`` (``jax.profiler.start_trace`` armed
-with a watchdog that stops it after ``seconds`` — the existing
-unbounded /debug/profile/start|stop pair's safe sibling).
+with a watchdog that stops it after ``seconds``), its state behind
+``GET /debug/profile/device``, and the stop a shutdown needs.
 """
+import glob
+import os
 import threading
 import time
 
-from pilosa_tpu import lockcheck
+from pilosa_tpu import lockcheck, tracing
 
 # (op, cell, bucket) capture cap — the same closed product as the
 # kerneltime cell table; a backstop, not a working limit.
@@ -38,6 +40,10 @@ MAX_ENTRIES = 1024
 
 # Device-capture bounds: one trace at a time, hard-capped duration.
 MAX_CAPTURE_SECONDS = 30.0
+# How long a shutdown waits for the profiler to write its file.
+FINISH_TIMEOUT = 120.0
+# What the state route answers before the first capture.
+IDLE_STATE = {"state": "idle", "dir": None, "file": None}
 
 
 class Unsupported(RuntimeError):
@@ -59,7 +65,8 @@ class DevProfiler:
         self._unsupported = False
         self._capture_mu = lockcheck.register(
             "devprof.DevProfiler._capture_mu", threading.Lock())
-        self._capture = None   # {"dir", "until", "seconds"} while armed
+        self._capture = None   # the capture in flight (armed/stopping)
+        self._last = None      # {"dir", "id", "file"} of the last one done
         self.captures = 0
 
     # ------------------------------------------------------ write path
@@ -150,9 +157,14 @@ class DevProfiler:
     def device_capture(self, trace_dir, seconds):
         """Arm a BOUNDED jax.profiler trace to ``trace_dir``: started
         now, stopped by a watchdog after ``seconds`` (hard cap
-        MAX_CAPTURE_SECONDS). One at a time; raises Unsupported where
-        the backend/jax build cannot trace (handler answers 501) and
-        RuntimeError when a capture is already armed (409)."""
+        MAX_CAPTURE_SECONDS). The one place that starts a device
+        trace. The Python tracer is off (with it on, the served path
+        ran at a ninth of its rate and the capture measured the
+        profiler); the host tracer stays on, because the span mirror
+        (tracing.arm_capture) writes through it. One at a time; raises
+        Unsupported where the backend/jax build cannot trace (handler
+        answers 501) and RuntimeError while a capture is armed or
+        still being written (409)."""
         seconds = min(max(float(seconds), 0.1), MAX_CAPTURE_SECONDS)
         try:
             import jax
@@ -160,37 +172,85 @@ class DevProfiler:
             raise Unsupported(f"jax unavailable: {e}")
         with self._capture_mu:
             if self._capture is not None:
-                raise RuntimeError(
-                    f"device capture already armed: {self._capture}")
+                raise RuntimeError("device capture already armed: "
+                                   f"{self._public(self._capture)}")
             try:
-                jax.profiler.start_trace(trace_dir)
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                jax.profiler.start_trace(trace_dir,
+                                         profiler_options=options)
             except Exception as e:  # noqa: BLE001 — backend-dependent
                 raise Unsupported(f"device trace unsupported: {e}")
+            self.captures += 1
             # Operator-facing "until" stamp (409 body / capture
             # state): wall clock is the point — the watchdog itself
-            # sleeps the duration.
-            info = {"dir": trace_dir, "seconds": seconds,
-                    # pilint: disable=deadline-clock
-                    "until": time.time() + seconds}
-            self._capture = info
-            self.captures += 1
+            # waits the duration.
+            cap = {"dir": trace_dir, "seconds": seconds,
+                   "id": self.captures, "state": "armed",
+                   # pilint: disable=deadline-clock
+                   "until": time.time() + seconds,
+                   "wake": threading.Event(), "done": threading.Event()}
+            self._capture = cap
+            tracing.arm_capture(jax.profiler.TraceAnnotation,
+                                {"dir": trace_dir, "id": cap["id"]})
 
         def _watchdog():
-            time.sleep(seconds)
-            with self._capture_mu:
-                try:
-                    jax.profiler.stop_trace()
-                except Exception:  # noqa: BLE001; pilint: disable=swallow
-                    pass  # stopped manually / backend torn down
-                self._capture = None
+            cap["wake"].wait(seconds)
+            self._stop(cap, jax.profiler.stop_trace)
 
         threading.Thread(target=_watchdog, daemon=True,
                          name="devprof-capture-watchdog").start()
-        return {"dir": trace_dir, "seconds": seconds}
+        return {"dir": trace_dir, "seconds": seconds, "id": cap["id"]}
+
+    def _stop(self, cap, stop_trace):
+        """armed -> stopping -> done. The profiler writes its file
+        inside ``stop_trace``; the lock is not held meanwhile, so the
+        state route answers "stopping" and a new capture is refused
+        until the file is whole."""
+        with self._capture_mu:
+            cap["state"] = "stopping"
+        tracing.disarm_capture()
+        try:
+            stop_trace()
+        except Exception:  # noqa: BLE001; pilint: disable=swallow
+            pass  # backend torn down: there is no file to wait for
+        found = glob.glob(os.path.join(
+            cap["dir"], "plugins", "profile", "*", "*.xplane.pb"))
+        with self._capture_mu:
+            self._last = {"dir": cap["dir"], "id": cap["id"],
+                          "file": (max(found, key=os.path.getmtime)
+                                   if found else None)}
+            self._capture = None
+        cap["done"].set()
+
+    @staticmethod
+    def _public(cap):
+        return {k: cap[k] for k in ("dir", "seconds", "id", "state",
+                                    "until")}
 
     def capture_state(self):
+        """{"state": "idle" | "armed" | "stopping" | "done", "dir",
+        "file"}: ``file`` is the .xplane.pb of the last finished
+        capture, whole once the state says "done"."""
         with self._capture_mu:
-            return dict(self._capture) if self._capture else None
+            cap, last = self._capture, self._last
+            if cap is not None:
+                return dict(self._public(cap), file=None)
+            if last is not None:
+                return dict(last, state="done")
+            return dict(IDLE_STATE)
+
+    def finish_capture(self, timeout=FINISH_TIMEOUT):
+        """The shutdown path: stop an armed capture now and wait for a
+        stopping one, so the interpreter never goes while the profiler
+        is tearing down (that aborted the process). True when no
+        capture is left in flight."""
+        with self._capture_mu:
+            cap = self._capture
+        if cap is None:
+            return True
+        cap["wake"].set()
+        return cap["done"].wait(timeout)
 
 
 class NopDevProfiler:
@@ -219,7 +279,10 @@ class NopDevProfiler:
         raise Unsupported("device profiling disabled")
 
     def capture_state(self):
-        return None
+        return IDLE_STATE
+
+    def finish_capture(self, timeout=0.0):
+        return True
 
 
 NOP = NopDevProfiler()

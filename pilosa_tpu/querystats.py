@@ -75,11 +75,15 @@ MAX_HEDGE_LEGS = 64
 # compressed container tier, by the format each was served in — a
 # profile shows at a glance whether a query ran compressed (array/run
 # counts dominate) or fell back dense (ops/containers.py).
+# stackBuilds counts the device stacks a query had to build (a row or
+# plane stack that the stack cache did not hold): 0 once staged.
+# oomFallbacks counts batched dispatches the device refused for memory
+# (RESOURCE_EXHAUSTED); each also leaves a ``batched:error`` hop.
 KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "cacheMisses", "deviceTransfers", "deviceTransferBytes",
         "fanoutCalls", "fanoutRetries", "planMs", "planCacheHit",
         "containerBlocksDense", "containerBlocksArray",
-        "containerBlocksRun")
+        "containerBlocksRun", "stackBuilds", "oomFallbacks")
 
 
 class QueryStats:

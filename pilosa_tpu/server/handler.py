@@ -321,6 +321,8 @@ class Handler:
             ("GET", r"^/debug/profile$", self.get_debug_profile),
             ("POST", r"^/debug/profile/device$",
              self.post_profile_device),
+            ("GET", r"^/debug/profile/device$",
+             self.get_profile_device),
             ("GET", r"^/debug/heatmap$", self.get_debug_heatmap),
             ("GET", r"^/debug/slo$", self.get_debug_slo),
             ("GET", r"^/debug/costmodel$", self.get_debug_costmodel),
@@ -332,8 +334,6 @@ class Handler:
             ("GET", r"^/metrics$", self.get_metrics),
             ("GET", r"^/cluster/metrics$", self.get_cluster_metrics),
             ("GET", r"^/debug/worker$", self.get_debug_worker),
-            ("POST", r"^/debug/profile/start$", self.post_profile_start),
-            ("POST", r"^/debug/profile/stop$", self.post_profile_stop),
             ("GET", r"^/$", self.get_webui),
             ("GET", r"^/assets/(?P<file>[^/]+)$", self.get_asset),
         ]
@@ -702,6 +702,7 @@ class Handler:
             tracer = tracing.Tracer(ring_size=1, stats=None)
         trace_id = headers.get(tracing.TRACE_HEADER)
         parent_id = headers.get(tracing.SPAN_HEADER)
+        arrived = tracing.take_arrival()
         root = tracer.start(
             "query.remote" if trace_id else "query",
             trace_id=trace_id, parent_id=parent_id,
@@ -724,6 +725,11 @@ class Handler:
         # did it COST and which tier served it" next to "where did
         # the time go".
         root.trace.resources = qs.to_dict()
+        if arrived is not None:
+            # What no span can hold, because it runs before the root
+            # exists: header parse, body read and routing, from the
+            # request line's arrival to the root's start.
+            root.tag(httpParseMs=round((root._t0 - arrived) * 1000, 3))
         if ev_wm is not None:
             ids = self.events.ids_since(ev_wm)
             if ids:
@@ -869,13 +875,15 @@ class Handler:
             return (400, "application/json",
                     json.dumps({"error": str(e)}).encode())
 
-        if (headers.get("Accept") == "application/x-protobuf"
-                or ctype == "application/x-protobuf"):
-            from pilosa_tpu.server import wireproto
-            return (200, "application/x-protobuf",
-                    wireproto.encode_query_response(results))
-        return (200, "application/json", json.dumps(
-            {"results": [result_to_json(r) for r in results]}).encode())
+        with tracing.span("encode"):
+            if (headers.get("Accept") == "application/x-protobuf"
+                    or ctype == "application/x-protobuf"):
+                from pilosa_tpu.server import wireproto
+                return (200, "application/x-protobuf",
+                        wireproto.encode_query_response(results))
+            return (200, "application/json", json.dumps(
+                {"results": [result_to_json(r)
+                             for r in results]}).encode())
 
     # ------------------------------------------------------------ schema
 
@@ -1817,6 +1825,7 @@ class Handler:
         if self._resp_cache is not None:
             data["responseCache"] = self._resp_cache.stats()
         data["widthWarmer"] = self.executor.warm_snapshot()
+        data["oomFallbacks"] = self.executor.oom_fallbacks
         if self.tracer.enabled:
             data["tracing"] = self.tracer.summary()
         # One consistent snapshot: the qos/faults/memory groups answer
@@ -1923,9 +1932,11 @@ class Handler:
         """Arm a bounded device-kernel trace capture (observe/
         devprof.py): starts a jax.profiler trace into ``?dir=`` (or
         the [profile] device-trace-dir default) and schedules its stop
-        after ``?seconds=`` (cap 30) — view in TensorBoard. 501 when
-        no profiling-capable backend is present, 409 while a capture
-        is already armed."""
+        after ``?seconds=`` (cap 30) — view in TensorBoard or
+        Perfetto, where the server's own spans sit beside the device's
+        operations as ``pilosa:<span>`` annotations. The Python tracer
+        is off. 501 when no profiling-capable backend is present, 409
+        while a capture is armed or still being written."""
         trace_dir = (qp.get("dir", [None])[0]
                      or self.device_trace_dir
                      or "/tmp/pilosa_tpu_trace")
@@ -1940,6 +1951,13 @@ class Handler:
         except RuntimeError as e:  # capture already armed
             raise HTTPError(409, str(e))
         return 200, "application/json", json.dumps(out).encode()
+
+    def get_profile_device(self, params, qp, body, headers):
+        """Where the device capture stands: ``state`` is idle, armed,
+        stopping (the profiler is writing its file) or done, with the
+        capture's ``dir`` and, once done, the ``file`` it wrote."""
+        return (200, "application/json", json.dumps(
+            devprof_mod.ACTIVE.capture_state()).encode())
 
     def get_debug_heatmap(self, params, qp, body, headers):
         """Decayed slice/row heat (observe/heatmap.py): the bounded
@@ -2377,27 +2395,6 @@ class Handler:
         return (200, "text/plain; version=0.0.4; charset=utf-8",
                 merged.encode())
 
-    def post_profile_start(self, params, qp, body, headers):
-        """Start a JAX/XPlane device trace — the TPU-native replacement
-        for /debug/pprof (ref: handler.go:102-103); view in TensorBoard."""
-        import jax
-
-        trace_dir = qp.get("dir", ["/tmp/pilosa_tpu_trace"])[0]
-        jax.profiler.start_trace(trace_dir)
-        return (200, "application/json",
-                json.dumps({"tracing": trace_dir}).encode())
-
-    def post_profile_stop(self, params, qp, body, headers):
-        """Stop the JAX/XPlane device trace post_profile_start began
-        (400 when none is running)."""
-        import jax
-
-        try:
-            jax.profiler.stop_trace()
-        except RuntimeError as e:  # not started
-            raise HTTPError(400, str(e))
-        return 200, "application/json", b"{}"
-
     def get_webui(self, params, qp, body, headers):
         from pilosa_tpu.server.webui import INDEX_HTML
         return 200, "text/html", INDEX_HTML.encode()
@@ -2517,6 +2514,7 @@ def make_http_server(handler, bind="localhost:0", reuse_port=False,
             side effect header lookups become properly
             case-insensitive downstream (dict(email.Message) used to
             preserve client casing, missing lowercase senders)."""
+            tracing.mark_arrival()
             line = str(self.raw_requestline, "iso-8859-1").rstrip("\r\n")
             words = line.split()
             if (len(words) != 3

@@ -919,6 +919,15 @@ class Server:
                     "flight, closing anyway", waited, left)
         if first:
             self.events.emit("server.stop")
+            # A device capture is the process's: stop an armed one and
+            # wait for one that is being written, or the interpreter
+            # exits under a profiler that is tearing down (exit -6).
+            from pilosa_tpu.observe import devprof as devprof_mod
+
+            if not devprof_mod.ACTIVE.finish_capture():
+                _LOG.warning("device capture still being written "
+                             "after %.0fs; closing anyway",
+                             devprof_mod.FINISH_TIMEOUT)
         self._save_path_model()  # learned minima survive the restart
         if self.worker_pool is not None:
             self.worker_pool.close()

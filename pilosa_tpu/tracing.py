@@ -27,8 +27,16 @@ Roots are opened by whoever owns a Tracer (the HTTP handler, tests);
 everything below nests automatically. Fan-out threads adopt their
 parent explicitly via ``child_of`` (thread-locals don't cross
 ``threading.Thread``).
+
+While a device capture is armed (observe/devprof.py calls
+``arm_capture``) every span is also written into the profiler's own
+trace as a ``pilosa:<name>`` annotation and keeps its start on the
+monotonic clock (``startNs``); one ``pilosa:anchor:<id>:<ns>``
+annotation, written as the capture starts, maps that clock onto the
+trace's. With no capture armed this is one module-level read in
+``Span.__enter__``.
 """
-import os
+import random
 import threading
 import time
 from collections import deque
@@ -50,9 +58,54 @@ LATENCY_BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
 
 _ACTIVE = threading.local()
 
+ANNOTATION_PREFIX = "pilosa:"
+ANCHOR_PREFIX = ANNOTATION_PREFIX + "anchor:"
+
+# (annotation class, {"dir", "id"}) while a device capture is armed,
+# else None: the one thing Span.__enter__ reads for the mirror.
+_CAPTURE = None
+
+
+def arm_capture(annotation_cls, info):
+    """Mirror spans into the profiler trace that was just started.
+    ``annotation_cls`` is ``jax.profiler.TraceAnnotation`` (handed in:
+    this module never imports jax). The anchor's name carries the
+    monotonic clock reading taken as it opens, so a reader that finds
+    it in the host plane knows the offset between the two clocks."""
+    global _CAPTURE
+    anchor = annotation_cls(
+        f"{ANCHOR_PREFIX}{info['id']}:{time.perf_counter_ns()}")
+    anchor.__enter__()
+    anchor.__exit__(None, None, None)
+    _CAPTURE = (annotation_cls, info)
+
+
+def disarm_capture():
+    global _CAPTURE
+    _CAPTURE = None
+
+
+def mark_arrival():
+    """Stamp the moment a request's first line was read (the HTTP
+    server's ``parse_request``); the query route turns it into the
+    root span's ``httpParseMs``."""
+    _ACTIVE.arrived = time.perf_counter()
+
+
+def take_arrival():
+    """The calling thread's stamp, cleared: a request that did not
+    come through the HTTP server must not inherit the last one's."""
+    t = getattr(_ACTIVE, "arrived", None)
+    _ACTIVE.arrived = None
+    return t
+
 
 def _new_id():
-    return os.urandom(8).hex()
+    """16 hex digits from the ``random`` module's generator (seeded
+    from the OS at import and again in a forked child), not
+    ``os.urandom``: that is a system call a span, 6.4 us each on a
+    sandboxed host where this takes 0.33 (PERF.md section 3)."""
+    return "%016x" % random.getrandbits(64)
 
 
 def active_span():
@@ -112,7 +165,7 @@ class Span:
     trace, and restores the previous active span."""
 
     __slots__ = ("trace", "name", "span_id", "parent_id", "tags",
-                 "start", "duration", "_t0", "_prev")
+                 "start", "duration", "_t0", "_prev", "_mirror")
 
     def __init__(self, trace, name, parent_id=None, tags=None):
         self.trace = trace
@@ -124,6 +177,7 @@ class Span:
         self.duration = None
         self._t0 = None
         self._prev = None
+        self._mirror = None
 
     def tag(self, **kw):
         self.tags.update(kw)
@@ -135,9 +189,16 @@ class Span:
         # Wall-clock anchor derived from the trace's epoch pair so all
         # of one process's spans share a consistent clock.
         self.start = self.trace.epoch0 + (self._t0 - self.trace.perf0)
+        cap = _CAPTURE
+        if cap is not None:
+            self.trace.capture = cap[1]
+            self._mirror = cap[0](ANNOTATION_PREFIX + self.name)
+            self._mirror.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        if self._mirror is not None:
+            self._mirror.__exit__(None, None, None)
         self.duration = time.perf_counter() - self._t0
         if exc is not None:
             self.tags["error"] = f"{type(exc).__name__}: {exc}"[:200]
@@ -148,7 +209,7 @@ class Span:
         return False
 
     def to_dict(self):
-        return {
+        out = {
             "name": self.name,
             "spanId": self.span_id,
             "parentId": self.parent_id,
@@ -157,6 +218,11 @@ class Span:
                            if self.duration is not None else None),
             "tags": dict(self.tags),
         }
+        if self._mirror is not None:
+            # The clock the capture's anchor names (perf_counter and
+            # perf_counter_ns read the same one).
+            out["startNs"] = int(self._t0 * 1e9)
+        return out
 
 
 class Trace:
@@ -176,6 +242,7 @@ class Trace:
         self._mu = threading.Lock()
         self.root = None
         self.dropped = 0  # folded into the tracer's total at finish
+        self.capture = None  # {"dir", "id"} once a span was mirrored
 
     def add(self, sp):
         with self._mu:
@@ -208,6 +275,10 @@ class Trace:
         profile = getattr(self, "profile", None)
         if profile:
             out["profile"] = profile
+        if self.capture:
+            # Which device trace holds this trace's spans as
+            # annotations (a reader follows ``dir`` to the .xplane.pb).
+            out["capture"] = dict(self.capture)
         return out
 
 

@@ -356,3 +356,153 @@ def test_perfwatch_direction_and_baseline_rules(tmp_path, capsys):
 
 def test_perfwatch_empty_ledger_ok(tmp_path):
     assert perfwatch.main([str(tmp_path / "absent.jsonl")]) == 0
+
+
+# ------------------------------------------------- the device capture
+
+
+def _http(method, url, body=None):
+    import urllib.error
+    import urllib.request
+
+    req = urllib.request.Request(url, data=body, method=method)
+    try:
+        with urllib.request.urlopen(req, timeout=30) as resp:
+            return resp.status, json.loads(resp.read() or b"{}")
+    except urllib.error.HTTPError as e:
+        return e.code, e.read()
+
+
+def _wait_state(url, want, seconds=60.0):
+    import time
+
+    deadline = time.monotonic() + seconds
+    while time.monotonic() < deadline:
+        _, st = _http("GET", url)
+        if st["state"] == want:
+            return st
+        time.sleep(0.05)
+    raise AssertionError(f"capture never reached {want!r}: {st}")
+
+
+def test_device_capture_starts_the_trace_with_the_python_tracer_off(
+        tmp_path, monkeypatch):
+    import jax
+
+    seen = {}
+
+    def start_trace(log_dir, *args, profiler_options=None, **kw):
+        seen["dir"] = log_dir
+        seen["python"] = profiler_options.python_tracer_level
+        seen["host"] = profiler_options.host_tracer_level
+
+    monkeypatch.setattr(jax.profiler, "start_trace", start_trace)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    dp = devprof_mod.DevProfiler()
+    out = dp.device_capture(str(tmp_path), 0.1)
+    assert out == {"dir": str(tmp_path), "seconds": 0.1, "id": 1}
+    # The Python tracer is what slowed a traced server ninefold; the
+    # host tracer has to stay on, the span mirror writes through it.
+    assert seen == {"dir": str(tmp_path), "python": 0, "host": 2}
+    assert tracing._CAPTURE[1] == {"dir": str(tmp_path), "id": 1}
+    with pytest.raises(RuntimeError, match="already armed"):
+        dp.device_capture(str(tmp_path), 0.1)
+    assert dp.finish_capture(timeout=30)
+    assert tracing._CAPTURE is None
+    assert dp.capture_state() == {"state": "done", "dir": str(tmp_path),
+                                  "id": 1, "file": None}
+    assert devprof_mod.NOP.capture_state()["state"] == "idle"
+    assert devprof_mod.NOP.finish_capture() is True
+
+
+def test_capture_on_the_cpu_holds_anchor_and_spans_and_says_its_state(
+        tmp_path):
+    """A real capture on the CPU backend: the state route walks idle,
+    armed, done; a profile served while armed names the capture and its
+    spans carry their monotonic start; the host plane of the file holds
+    the anchor and a ``pilosa:query`` annotation that the anchor puts
+    within a fraction of a millisecond of the span's own start."""
+    from perfbench.lib import spans as spans_mod
+    from perfbench.lib import xplane
+    from pilosa_tpu.server.server import Server
+
+    s = Server(str(tmp_path / "d"), bind="localhost:0").open()
+    try:
+        b = f"http://{s.host}"
+        _http("POST", f"{b}/index/i", b"{}")
+        _http("POST", f"{b}/index/i/frame/f", b"{}")
+        _http("POST", f"{b}/index/i/query",
+              b'SetBit(frame="f", rowID=1, columnID=1)')
+        route = f"{b}/debug/profile/device"
+        assert _http("GET", route) == (200, {"state": "idle", "dir": None,
+                                             "file": None})
+        trace_dir = str(tmp_path / "trace")
+        status, armed = _http("POST", f"{route}?seconds=1&dir={trace_dir}")
+        assert status == 200 and armed["id"] == 1
+        _, st = _http("GET", route)
+        assert st["state"] == "armed" and st["dir"] == trace_dir
+        assert _http("POST", f"{route}?seconds=1")[0] == 409
+        _, doc = _http("POST", f"{b}/index/i/query?profile=true",
+                       b'Count(Bitmap(frame="f", rowID=1))')
+        prof = doc["profile"]
+        assert prof["capture"] == {"dir": trace_dir, "id": 1}
+        assert all("startNs" in sp for sp in prof["spans"])
+        done = _wait_state(route, "done")
+        assert done["dir"] == trace_dir and os.path.getsize(done["file"]) > 0
+        assert done["file"] == xplane.find_xplane(trace_dir)
+        # Armed no more: the next profile is plain again.
+        _, doc = _http("POST", f"{b}/index/i/query?profile=true",
+                       b'Count(Bitmap(frame="f", rowID=1))')
+        assert "capture" not in doc["profile"]
+        assert "startNs" not in doc["profile"]["spans"][0]
+    finally:
+        s.close()
+    planes = xplane.read_planes(done["file"], prefix="/host:")
+    offset = spans_mod.anchor_offset_ps(planes, 1)
+    assert offset is not None
+    assert spans_mod.anchor_offset_ps(planes, 2) is None
+    root = next(sp for sp in prof["spans"] if sp["parentId"] is None)
+    marks = spans_mod.annotations(planes, "pilosa:query")
+    assert marks
+    mapped = root["startNs"] * 1000 + offset
+    assert min(abs(m - mapped) for m in marks) < 200e6     # 200 us, in ps
+
+
+def test_sigterm_mid_capture_exits_clean(tmp_path):
+    """A server that is told to stop while a capture is armed stops the
+    capture, lets the profiler write its file, and exits 0."""
+    import signal
+    import subprocess
+    import time
+
+    from pilosa_tpu.testing import free_ports
+
+    port = free_ports(1)[0]
+    env = dict(os.environ, PYTHONPATH=ROOT, PILOSA_DRAIN_TIMEOUT="2")
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "pilosa_tpu.cli", "server", "-d",
+         str(tmp_path / "d"), "--bind", f"127.0.0.1:{port}"],
+        env=env, stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+    try:
+        route = f"http://127.0.0.1:{port}/debug/profile/device"
+        deadline = time.monotonic() + 90
+        while True:
+            assert proc.poll() is None, "server died during boot"
+            assert time.monotonic() < deadline, "server did not come up"
+            try:
+                if _http("GET", route)[0] == 200:
+                    break
+            except OSError:
+                time.sleep(0.25)
+        trace_dir = str(tmp_path / "trace")
+        assert _http("POST", f"{route}?seconds=20&dir={trace_dir}")[0] == 200
+        time.sleep(0.3)
+        proc.send_signal(signal.SIGTERM)
+        assert proc.wait(timeout=120) == 0
+    finally:
+        if proc.poll() is None:
+            proc.kill()
+    from perfbench.lib import xplane
+
+    path = xplane.find_xplane(trace_dir)
+    assert path and os.path.getsize(path) > 0

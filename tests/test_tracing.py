@@ -412,3 +412,218 @@ def test_config_imports_and_loads_on_this_interpreter(tmp_path):
     p2.write_text(cfg.to_toml())
     rt = cfgmod.Config.load(str(p2), env={})
     assert rt.trace == cfg.trace
+
+
+# ------------------------------- spans down to dispatch / wait / fetch
+
+PAIR = 'Count(Intersect(Bitmap(frame="f", rowID={a}), Bitmap(frame="f", rowID={b})))'
+
+# parent -> children of one batched Count under ?profile=true, in
+# order; a name ending in "?" is there only where its parent's work
+# missed a cache.
+SPAN_TREE = {
+    "query": ["parse", "call:Count", "encode"],
+    "call:Count": ["count.plan", "result.memo", "costmodel.estimate",
+                   "exec.route", "plan_and_stage", "kernel:count_batched",
+                   "reduce", "costmodel.record"],
+    "plan_and_stage": ["plan.tree", "stacks.memo", "stacks.build?"],
+    "stacks.build": ["build.frags", "build.window", "build.args"],
+    "kernel:count_batched": ["kernel.fn", "kernel.dispatch", "kernel.wait",
+                             "kernel.fetch"],
+}
+
+
+def _seed_rows(s, rows=(1, 2, 3), slices=2):
+    b = base(s)
+    http("POST", f"{b}/index/i", b"{}")
+    http("POST", f"{b}/index/i/frame/f", b"{}")
+    for sl in range(slices):
+        for r in rows:
+            http("POST", f"{b}/index/i/query",
+                 f'SetBit(frame="f", rowID={r}, '
+                 f'columnID={sl * SLICE_WIDTH + r})'.encode())
+
+
+def _profiled(s, pql):
+    status, data, _ = http("POST", f"{base(s)}/index/i/query?profile=true",
+                           pql.encode())
+    assert status == 200, data
+    return json.loads(data)
+
+
+def _check_tree(node):
+    want = SPAN_TREE.get(node["name"])
+    got = [c["name"] for c in node["children"]]
+    if want is not None:
+        assert got == [w.rstrip("?") for w in want
+                       if not w.endswith("?") or w.rstrip("?") in got], \
+            (node["name"], got)
+    end = node["start"] + node["durationMs"] / 1000.0
+    for c in node["children"]:
+        # Starts are exact, durations rounded to the microsecond.
+        assert c["start"] >= node["start"], (node["name"], c["name"])
+        assert c["start"] + c["durationMs"] / 1000.0 <= end + 2e-6, \
+            (node["name"], c["name"])
+        _check_tree(c)
+
+
+def test_batched_count_span_tree_and_stack_builds(tmp_path):
+    """One Count through the batched path under ?profile=true: the
+    span tree down to dispatch / wait / fetch with every child inside
+    its parent; ``stackBuilds`` counts the stacks the first query of
+    two fresh rows builds and is 0 for the next query over them."""
+    s = Server(str(tmp_path / "d"), bind="localhost:0").open()
+    try:
+        _seed_rows(s)
+        doc = _profiled(s, PAIR.format(a=1, b=2))
+        assert doc["results"] == [0]
+        prof = doc["profile"]
+        (root,) = prof["roots"]
+        assert root["name"] == "query" and root["parentId"] is None
+        assert root["tags"]["httpParseMs"] >= 0
+        _check_tree(root)
+        by_name = {sp["name"]: sp for sp in prof["spans"]}
+        assert by_name["count.plan"]["tags"] == {"tier": "static"}
+        assert by_name["result.memo"]["tags"] == {"kind": "count_res",
+                                                  "hit": False}
+        assert by_name["exec.route"]["tags"] == {"choice": "batched"}
+        assert by_name["stacks.memo"]["tags"] == {"hit": False}
+        assert by_name["kernel.fn"]["tags"] == {"compile": True}
+        assert "stacks.build" in by_name
+        assert "startNs" not in root and "capture" not in prof
+        res = prof["resources"]
+        assert res["stackBuilds"] == 2 and res["oomFallbacks"] == 0
+        assert res["servedBy"] == {"batched": 1} and res["planMs"] > 0
+        # The same rows the other way round: another query, no build.
+        again = _profiled(s, PAIR.format(a=2, b=1))["profile"]
+        _check_tree(again["roots"][0])
+        assert again["resources"]["stackBuilds"] == 0
+        assert again["resources"]["servedBy"] == {"batched": 1}
+        # The query itself again: the result memo answers, and says so.
+        memo = _profiled(s, PAIR.format(a=1, b=2))["profile"]
+        tags = {sp["name"]: sp["tags"] for sp in memo["spans"]}
+        assert tags["result.memo"]["hit"] is True
+        assert "plan_and_stage" not in tags
+    finally:
+        s.close()
+
+
+def test_new_span_sites_cost_nothing_without_a_trace(tmp_path, monkeypatch):
+    """No trace active: every new site gets the shared no-op, the
+    dispatch / wait / fetch split is not taken, and nothing of the
+    capture mirror runs."""
+    from pilosa_tpu import executor as executor_mod
+
+    s = Server(str(tmp_path / "d"), bind="localhost:0").open()
+    try:
+        _seed_rows(s)
+        seen = []
+        real_span = tracing.span
+
+        def spy(name, **tags):
+            out = real_span(name, **tags)
+            seen.append((name, out))
+            return out
+
+        def no_split(fn, stacks):
+            raise AssertionError("the split ran without a trace")
+
+        monkeypatch.setattr(tracing, "span", spy)
+        monkeypatch.setattr(executor_mod, "_run_count_split", no_split)
+        monkeypatch.setattr(tracing.Span, "__init__", no_split)
+        assert tracing._CAPTURE is None and tracing.active_span() is None
+        status, _, payload = s.handler.dispatch(
+            "POST", "/index/i/query", {}, PAIR.format(a=1, b=3).encode(),
+            {})[:3]
+        assert status == 200 and json.loads(payload) == {"results": [0]}
+        assert all(out is tracing.NOP_SPAN for _, out in seen)
+        assert {"count.plan", "result.memo", "exec.route", "plan.tree",
+                "stacks.memo", "stacks.build", "build.frags",
+                "build.window", "build.args", "reduce", "encode"} \
+            <= {name for name, _ in seen}
+        assert not {"kernel.fn", "kernel.dispatch", "kernel.wait",
+                    "kernel.fetch"} & {name for name, _ in seen}
+    finally:
+        s.close()
+
+
+def test_resource_exhausted_counts_an_oom_fallback(tmp_path, monkeypatch):
+    """A batched call the device refuses for memory still answers (per
+    slice), still records ``batched:error``, and is counted: in the
+    query's resources and in /debug/vars."""
+    from pilosa_tpu import executor as executor_mod
+
+    s = Server(str(tmp_path / "d"), bind="localhost:0").open()
+    try:
+        _seed_rows(s)
+
+        def refuse(fn, stacks):
+            raise RuntimeError("RESOURCE_EXHAUSTED: Out of memory while "
+                               "trying to allocate 125042688 bytes.")
+
+        monkeypatch.setattr(executor_mod, "_run_count", refuse)
+        monkeypatch.setattr(executor_mod, "_run_count_split", refuse)
+        doc = _profiled(s, PAIR.format(a=1, b=2))
+        assert doc["results"] == [0]
+        res = doc["profile"]["resources"]
+        assert res["oomFallbacks"] == 1
+        assert "batched:error" in res["fallbackChain"]
+        assert res["servedBy"] == {"serial": 1}
+        assert jget(f"{base(s)}/debug/vars")["oomFallbacks"] == 1
+
+        def other(fn, stacks):
+            raise RuntimeError("INTERNAL: something else")
+
+        monkeypatch.setattr(executor_mod, "_run_count_split", other)
+        res = _profiled(s, PAIR.format(a=1, b=3))["profile"]["resources"]
+        assert res["oomFallbacks"] == 0
+        assert "batched:error" in res["fallbackChain"]
+        assert jget(f"{base(s)}/debug/vars")["oomFallbacks"] == 1
+    finally:
+        s.close()
+
+
+def test_program_names_say_tier_and_operands():
+    from pilosa_tpu import executor as executor_mod
+
+    assert executor_mod.program_name("count_batched", 3) \
+        == "pilosa_count_batched_k3"
+    assert executor_mod.program_name("sum_batched", 0) \
+        == "pilosa_sum_batched_k0"
+    assert executor_mod.program_name("topn_rows", 64) \
+        == "pilosa_topn_rows_k16p"
+    three = ("Intersect", [("leaf", 0), ("Difference",
+                                         [("leaf", 1), ("leaf", 2)])])
+    assert executor_mod._plan_operands(three) == 3
+    assert executor_mod._plan_operands(("empty",)) == 0
+    assert executor_mod._plan_operands(None) == 0
+    assert executor_mod._plan_operands(
+        ("Union", [("leaf", 0), ("bsi", 1, (2, 3), "between", None, 4)])) == 4
+
+
+def test_batched_count_program_carries_its_name(tmp_path):
+    """What the device trace shows for a two- and a three-operand
+    Count: the lowered module's name."""
+    s = Server(str(tmp_path / "d"), bind="localhost:0").open()
+    try:
+        _seed_rows(s)
+        _profiled(s, PAIR.format(a=1, b=2))
+        _profiled(s, 'Count(Intersect(Bitmap(frame="f", rowID=1), '
+                     'Difference(Bitmap(frame="f", rowID=2), '
+                     'Bitmap(frame="f", rowID=3))))')
+        import jax
+        import jax.numpy as jnp
+
+        modules = set()
+        for key, fn in s.executor._batched_cache.items():
+            if len(key) != 3:
+                continue              # another tier's program
+            _, padded_n, width32 = key
+            operands = int(fn.__wrapped__.__name__.rsplit("_k", 1)[1])
+            stack = jax.ShapeDtypeStruct((padded_n, width32), jnp.uint32)
+            text = fn.lower(*[stack] * operands).as_text()
+            modules.add(text.split("module @", 1)[1].split()[0])
+        assert modules == {"jit_pilosa_count_batched_k2",
+                           "jit_pilosa_count_batched_k3"}
+    finally:
+        s.close()
