@@ -50,7 +50,8 @@ from pilosa_tpu.observe import devprof as devprof_mod
 from pilosa_tpu.observe import heatmap as heatmap_mod
 from pilosa_tpu.observe import kerneltime as kerneltime_mod
 from pilosa_tpu.ops import containers as containers_mod
-from pilosa_tpu.plancache import PlanCache, as_slice_list, slice_key
+from pilosa_tpu.plancache import (FragList, PlanCache, as_slice_list,
+                                  slice_key)
 from pilosa_tpu import planner as planner_mod
 from pilosa_tpu.pql import Condition, Query
 from pilosa_tpu.utils import fanpool as fanpool_mod
@@ -302,6 +303,10 @@ class Executor:
         # (RESOURCE_EXHAUSTED) and that fell to the per-slice path:
         # /debug/vars ``oomFallbacks``, beside the per-query key.
         self.oom_fallbacks = 0
+        # Fragment lists served from the plan cache's "leaf" entries
+        # against lists walked (_frag_list), process lifetime, under
+        # _cache_mu: /debug/vars, beside the per-query keys.
+        self.leaf_memo = {"leafMemoHits": 0, "leafMemoMisses": 0}
         # Hinted handoff: writes skipped because a replica was DOWN,
         # keyed by host, replayed on rejoin (anti-entropy remains the
         # backstop for hints lost to a coordinator restart).
@@ -3224,7 +3229,7 @@ class Executor:
         per-slice lane members."""
         _, fname, rid, view = spec
         key = ("lanerow", index, fname, view, rid, slice_key(slices))
-        tokens = self._frag_tokens(frags)
+        tokens = frags.tokens
         with self._cache_mu:
             hit = self._lane_rows.get(key)
             if hit is not None and hit[0] == tokens:
@@ -3668,10 +3673,10 @@ class Executor:
 
         base32, width32 = win if win is not None else (0, WORDS_PER_SLICE)
         if frags is None:
-            frags = self.holder.fragments(index, frame_name, view, slices)
+            frags = self._frag_list(index, frame_name, view, slices)
         key = ("row", index, frame_name, view, row_id,
                slice_key(slices), n_dev, base32, width32)
-        tokens = self._frag_tokens(frags)
+        tokens = frags.tokens
         hit, stale = self._stack_cache_lookup(key, tokens)
         if hit is not None:
             return hit
@@ -3732,10 +3737,10 @@ class Executor:
         base32, width32 = win if win is not None else (0, WORDS_PER_SLICE)
         view = view_field_name(field_name)
         if frags is None:
-            frags = self.holder.fragments(index, frame_name, view, slices)
+            frags = self._frag_list(index, frame_name, view, slices)
         key = ("planes", index, frame_name, field_name, depth,
                slice_key(slices), n_dev, base32, width32)
-        tokens = self._frag_tokens(frags)
+        tokens = frags.tokens
         stack, stale = self._stack_cache_lookup(key, tokens)
         if stack is not None:
             return stack
@@ -3800,41 +3805,95 @@ class Executor:
         kernels. Any dense row — and any BSI plane leaf, planes are
         dense by design — keeps the batched path: the dense hot path
         is byte-identical to before, and mixed dense×compressed pairs
-        are still bit-exact there via the densify fallback."""
+        are still bit-exact there via the densify fallback. A row
+        found dense is remembered on its fragment list
+        (``FragList.dense``), so one known-dense leaf settles a plan
+        with no probe at all."""
         if not containers_mod.enabled():
             return False
-        saw_row = False
+        rows = []
         for sp in leaves:
+            if sp[0] == "planes":
+                return False
             if sp[0] != "row":
-                if sp[0] == "planes":
-                    return False
                 continue
-            saw_row = True
             _, fname, rid, view = sp
-            for frag in frag_map.get((fname, view), ()):
+            frags = frag_map[(fname, view)]
+            if rid in frags.dense:
+                return False
+            rows.append((rid, frags))
+        for rid, frags in rows:
+            for frag in frags:
                 if frag is None:
                     continue
                 if probe is None:
-                    if not frag.row_compressed(rid):
-                        return False
-                    continue
-                # Tick-shared probe memo: a coalesced group's members
-                # share rows, so the per-(fragment, row) density
-                # checks dedupe across the whole group.
-                pkey = (id(frag), rid)
-                hit = probe.get(pkey)
-                if hit is None:
-                    hit = probe[pkey] = frag.row_compressed(rid)
+                    hit = frag.row_compressed(rid)
+                else:
+                    # Tick-shared probe memo: a coalesced group's
+                    # members share rows, so the per-(fragment, row)
+                    # density checks dedupe across the whole group.
+                    pkey = (id(frag), rid)
+                    hit = probe.get(pkey)
+                    if hit is None:
+                        hit = probe[pkey] = frag.row_compressed(rid)
                 if not hit:
+                    frags.dense.add(rid)
                     return False
-        return saw_row
+        return bool(rows)
 
-    def _leaf_frags(self, index, leaves, slices, shared=None):
-        """One holder lookup per (frame, view) × slice: the fragment
-        lists shared by window negotiation and stack builds, so the
-        batched prelude doesn't fetch every fragment twice. ``shared``
-        (a coalescer-tick cache) dedupes the holder walks ACROSS a
-        fused group's requests too — same lists, one walk."""
+    @staticmethod
+    def _leaf_key(index, frame_name, view, slices):
+        """Plan-cache key of one (frame, view)'s ``FragList``."""
+        return ("leaf", index, frame_name, view, slice_key(slices))
+
+    def _frag_list(self, index, frame_name, view, slices, walked=None):
+        """The ``FragList`` of one (frame, view) over ``slices``: from
+        the plan cache while the index's mutation epoch stands, else
+        one holder walk (a locked ``view.fragment`` per slice, then
+        ``win32`` and a token per fragment) whose key is appended to
+        ``walked``. The epoch is read BEFORE the walk, so a racing
+        write makes the entry stale on arrival, never wrong."""
+        from pilosa_tpu.storage import fragment as _frag
+
+        epoch = _frag.mutation_epoch(index)
+        key = self._leaf_key(index, frame_name, view, slices)
+        frags = self.plans.get(key, epoch)
+        stat = "leafMemoHits"
+        if frags is None:
+            stat = "leafMemoMisses"
+            frags = FragList(
+                self.holder.fragments(index, frame_name, view, slices))
+            frags.tokens = self._frag_tokens(frags)
+            frags.extent = self._list_extent(frags)
+            frags.dense = set()
+            self.plans.put(key, epoch, frags)
+            if walked is not None:
+                walked.append(key)
+        querystats.add(stat)
+        with self._cache_mu:
+            self.leaf_memo[stat] += 1
+        return frags
+
+    def _known_dense(self, index, frame_name, view, row_id, slices):
+        """True when the memoised facts of the row's fragment list say
+        some fragment serves it dense. A pure read (no walk, no LRU
+        refresh, no counter), for the planner's sampled probe and its
+        explain-only mode."""
+        from pilosa_tpu.storage import fragment as _frag
+
+        frags = self.plans.peek(
+            self._leaf_key(index, frame_name, view, slices),
+            _frag.mutation_epoch(index))
+        return frags is not None and row_id in frags.dense
+
+    def _leaf_frags(self, index, leaves, slices, shared=None, walked=None):
+        """(frame, view) -> ``FragList`` for every row and plane leaf:
+        the lists shared by the compressed-tier probe, window
+        negotiation and stack builds. One _frag_list lookup per
+        distinct (frame, view); a list is walked only when the epoch
+        has moved since it was last read. ``shared`` (a coalescer-tick
+        cache) dedupes even the lookups ACROSS a fused group's
+        requests."""
         frag_map = {}
         for sp in leaves:
             if sp[0] == "row":
@@ -3845,33 +3904,44 @@ class Executor:
             else:
                 continue
             key = (fname, view)
-            if key not in frag_map:
-                if shared is None:
-                    frag_map[key] = self.holder.fragments(
-                        index, fname, view, slices)
-                    continue
-                lst = shared.get(key)
-                if lst is None:
-                    lst = shared[key] = self.holder.fragments(
-                        index, fname, view, slices)
-                frag_map[key] = lst
+            if key in frag_map:
+                continue
+            lst = shared.get(key) if shared is not None else None
+            if lst is None:
+                lst = self._frag_list(index, fname, view, slices, walked)
+                if shared is not None:
+                    shared[key] = lst
+            frag_map[key] = lst
         return frag_map
+
+    @staticmethod
+    def _list_extent(frags):
+        """(lo, hi) in uint32 device words covering the column window
+        of every fragment in the list, or None when none holds a row."""
+        lo = hi = None
+        for f in frags:
+            if f is None:
+                continue
+            win = f.win32()
+            if win is None:
+                continue
+            b, w = win
+            lo = b if lo is None else min(lo, b)
+            hi = b + w if hi is None else max(hi, b + w)
+        return None if lo is None else (lo, hi)
 
     def _union_window(self, frag_map):
         """Common column window (base, width in uint32 device words)
         covering every fragment a batched plan touches, so device
         stacks allocate HBM for the data's span instead of the full
         32,768-word slice (narrow/clustered data would otherwise pay
-        up to 256× its host bytes in HBM). Width is bucketed to powers
-        of FOUR with a width-aligned base (see the comment at the
-        walk below), so the device window covers every fragment's
-        power-of-two host window at ≤2× its bytes while capping the
-        number of distinct compiled widths. Full slice width when the
-        data really spans it.
+        up to 256× its host bytes in HBM): the union of the lists'
+        memoised extents (``FragList.extent``, no fragment is touched
+        here), bucketed by _bucket_window.
         ``frag_map`` comes from _leaf_frags; callers with fragments
-        outside the leaf specs (TopN candidate rows) insert them into
-        the map first. Ref contrast: containers never materialize
-        empty space (roaring.go:1011-1024)."""
+        outside the leaf specs (TopN candidate rows) insert their
+        _frag_list into the map first. Ref contrast: containers never
+        materialize empty space (roaring.go:1011-1024)."""
         from pilosa_tpu import WORDS_PER_SLICE
 
         if self._fixed_full_window:
@@ -3880,19 +3950,19 @@ class Executor:
             # one fixed width means one compiled program per shape,
             # at the cost of full-slice HBM stacks.
             return 0, WORDS_PER_SLICE
-        lo = hi = None
-        for frags in frag_map.values():
-            for f in frags:
-                if f is None:
-                    continue
-                win = f.win32()
-                if win is None:
-                    continue
-                b, w = win
-                lo = b if lo is None else min(lo, b)
-                hi = b + w if hi is None else max(hi, b + w)
-        if lo is None:
+        extents = [frags.extent for frags in frag_map.values()
+                   if frags.extent is not None]
+        if not extents:
             return 0, self.MIN_WIN32
+        return self._bucket_window(min(lo for lo, _ in extents),
+                                   max(hi for _, hi in extents))
+
+    def _bucket_window(self, lo, hi):
+        """Smallest width-aligned power-of-FOUR window (base, width)
+        covering device words [lo, hi); full slice width when the data
+        really spans it."""
+        from pilosa_tpu import WORDS_PER_SLICE
+
         # Width buckets are powers of FOUR (128, 512, 2048, 8192,
         # 32768): every distinct width is a distinct XLA program, and a
         # mixed read/write load whose writes keep nudging some
@@ -3913,13 +3983,13 @@ class Executor:
             return 0, WORDS_PER_SLICE
         return b, w
 
-    # Epoch-validated prelude memo: a warm repeated query's prelude
-    # (fragment fetches, window negotiation, stack-cache lookups with
-    # per-fragment version tokens) costs O(slices) Python per leaf —
-    # at 10k-slice scale that dwarfs the device work. Epoch equality
+    # Epoch-validated prelude memo: a repeated query's whole prelude
+    # (plan, window, stack-cache keys) under one key. Epoch equality
     # (no fragment of THIS index mutated/opened/closed since the memo)
-    # is an O(1) sufficient condition for validity; any write falls
-    # back to the precise token path and refreshes the memo. Storage
+    # is an O(1) sufficient condition for validity. A miss composes
+    # the prelude from the per-list facts (_frag_list, same epoch
+    # rule), O(leaves) lookups; only a list whose epoch moved pays the
+    # O(slices) walk and the precise per-fragment tokens. Storage
     # lives in the plan cache (plancache.py): real LRU, configurable
     # capacity, shared hit/miss/invalidation counters.
 
@@ -4032,10 +4102,12 @@ class Executor:
 
     def _build_stacks(self, index, plan, leaves, slices, extra_rows, pkey,
                       qs):
-        """The prelude's miss path: fragment lists, the compressed-tier
-        and budget gates, the column window, one device stack per leaf
-        (from the stack cache where it holds one), and the memo entry
-        for the next query of this plan."""
+        """The prelude of a plan the memo does not hold, composed from
+        the per-list facts (_frag_list): the compressed-tier and budget
+        gates, the column window, one device stack per leaf (from the
+        stack cache where it holds one), and the memo entry for the
+        next query of this plan. O(leaves) while the index's epoch
+        stands; a list whose epoch moved is walked once."""
         import jax
 
         from pilosa_tpu.storage import fragment as _frag
@@ -4044,8 +4116,12 @@ class Executor:
         # during the build make the memo stale-on-arrival, not wrong)
         n_dev = len(jax.devices())
         pad = (-len(slices)) % n_dev
-        with tracing.span("build.frags"):
-            frag_map = self._leaf_frags(index, leaves, slices)
+        with tracing.span("build.frags") as fsp:
+            walked = []
+            frag_map = self._leaf_frags(index, leaves, slices,
+                                        walked=walked)
+            if fsp is not tracing.NOP_SPAN:
+                fsp.tag(walked=len(walked))
         with tracing.span("build.window"):
             if self._compressed_plan(leaves, frag_map):
                 if qs is not None:
@@ -4176,7 +4252,7 @@ class Executor:
             # the filter plan's leaves (one shared stack width).
             frag_map = self._leaf_frags(index, leaves, slices)
             if (frame_name, view) not in frag_map:
-                frag_map[(frame_name, view)] = self.holder.fragments(
+                frag_map[(frame_name, view)] = self._frag_list(
                     index, frame_name, view, slices)
             colwin = self._union_window(frag_map)
             cand_frags = frag_map[(frame_name, view)]
@@ -4416,8 +4492,7 @@ class Executor:
         # longer faults every fragment in just to read candidate ids.
         ent_sets = [
             frag.cache_entry_ids() if frag is not None else frozenset()
-            for frag in self.holder.fragments(index, frame_name, view,
-                                              slices)]
+            for frag in self._frag_list(index, frame_name, view, slices)]
         allowed = self._topn_attr_allowed(index, call, frame_name)
         if allowed is not None:
             ent_sets = [es & allowed for es in ent_sets]
@@ -4699,10 +4774,14 @@ class Executor:
             hit = self._stack_cache.get(key)
             if hit is None:
                 return None, None
-            if hit[0] == tokens:
+            if hit[0] is tokens or hit[0] == tokens:
                 # LRU: a hit refreshes recency so hot stacks survive
-                # eviction pressure.
-                self._stack_cache[key] = self._stack_cache.pop(key)
+                # eviction pressure. Re-stamped with the caller's
+                # tuple: a stack validated against a FragList's tokens
+                # revalidates by identity, not by comparing one pair
+                # per slice, until the list itself is walked again.
+                del self._stack_cache[key]
+                self._stack_cache[key] = (tokens, hit[1], hit[2])
                 return hit[1], None
             return None, (hit[0], hit[1])
 

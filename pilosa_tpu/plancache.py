@@ -16,7 +16,8 @@ One cache, one validity protocol:
 
 - **Keys** are ``(kind, index, slice-key, ...call shape)`` tuples.
   Kinds are caller-defined and need no registration here — the
-  executor's memos ("plan", "row", "bsi", "topn1", ...)
+  executor's memos ("plan", "row", "bsi", "topn1", ...), its
+  per-(frame, view, slice list) fragment facts ("leaf" -> ``FragList``),
   and the adaptive planner's ``("planner", index, ast, slice-key)``
   decision memos (planner.py) share one LRU and show up separately
   in the snapshot's ``entriesByKind``. The slice-key is COMPACT: a verified-contiguous slice list keys as
@@ -75,6 +76,31 @@ class SliceList(list):
     mutating, as ``_map_reduce`` always has)."""
 
     __slots__ = ("skey",)
+
+
+class FragList(list):
+    """One (frame, view)'s fragments over a slice list (``None`` where
+    a slice has none), with the facts every batched prelude derives
+    from them — none depends on the query, none can change without
+    the index's mutation epoch moving, so the executor stores the
+    list under kind ``"leaf"`` and composes a never-seen query's
+    prelude from a few lookups instead of O(slices) walks:
+
+    - ``tokens``: the stack cache's validity tuple, one ``(uid,
+      version)`` per fragment. Stacks built from this list are stamped
+      with this very object, so revalidating one is an identity check.
+    - ``extent``: ``(lo, hi)`` in uint32 device words covering every
+      fragment's column window, or None when no fragment holds a row.
+    - ``dense``: row ids some fragment of the list serves dense (the
+      negative outcome of the compressed-tier probe). Only that
+      outcome is kept: dense -> compressed needs an eviction or a
+      mutation (both bump the epoch), compressed -> dense does not (a
+      fault-in), so a compressed-everywhere row is probed every time.
+
+    Shared across concurrent queries and immutable by convention, as
+    ``SliceList``; ``dense`` only grows, and goes with its entry."""
+
+    __slots__ = ("tokens", "extent", "dense")
 
 
 def slice_key(slices):

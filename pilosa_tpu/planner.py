@@ -369,21 +369,25 @@ class Planner:
 
         if not containers_mod.enabled() or not slices:
             return False
-        saw_row = False
+        rows = []
         for sp in leaves:
             if sp[0] == "planes":
                 return False
-            if sp[0] != "row":
-                continue
-            saw_row = True
-            _, fname, rid, view = sp
+            if sp[0] == "row":
+                rows.append(sp)
+        # One leaf the executor already found dense settles it, as in
+        # its own gate, without touching a fragment.
+        if any(ex._known_dense(index, fname, view, rid, slices)
+               for _, fname, rid, view in rows):
+            return False
+        for _, fname, rid, view in rows:
             for s in (slices[0], slices[len(slices) // 2]):
                 frag = ex.holder.fragment(index, fname, view, s)
                 if frag is not None:
                     if not frag.row_compressed(rid):
                         return False
                     break
-        return saw_row
+        return bool(rows)
 
     # -------------------------------------------------- tier choice
 

@@ -79,11 +79,16 @@ MAX_HEDGE_LEGS = 64
 # plane stack that the stack cache did not hold): 0 once staged.
 # oomFallbacks counts batched dispatches the device refused for memory
 # (RESOURCE_EXHAUSTED); each also leaves a ``batched:error`` hop.
+# leafMemoHits / leafMemoMisses count the (frame, view) fragment lists
+# a prelude took from the plan cache's "leaf" entries against the
+# lists it had to walk, O(slices) each (executor._frag_list): a
+# never-seen query over a quiet index reads misses 0.
 KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "cacheMisses", "deviceTransfers", "deviceTransferBytes",
         "fanoutCalls", "fanoutRetries", "planMs", "planCacheHit",
         "containerBlocksDense", "containerBlocksArray",
-        "containerBlocksRun", "stackBuilds", "oomFallbacks")
+        "containerBlocksRun", "stackBuilds", "oomFallbacks",
+        "leafMemoHits", "leafMemoMisses")
 
 
 class QueryStats:

@@ -1826,6 +1826,7 @@ class Handler:
             data["responseCache"] = self._resp_cache.stats()
         data["widthWarmer"] = self.executor.warm_snapshot()
         data["oomFallbacks"] = self.executor.oom_fallbacks
+        data.update(self.executor.leaf_memo)
         if self.tracer.enabled:
             data["tracing"] = self.tracer.summary()
         # One consistent snapshot: the qos/faults/memory groups answer

@@ -1849,9 +1849,10 @@ class Fragment:
         roaring.go:1011-1024).
 
         Version-keyed memo, read without the lock: batched executors
-        call this once per (fragment, query) — 954 locked window
-        computations per query measured as ~half of a billion-column
-        count's latency. A racing mutation serves the consistent
+        call this once per fragment whenever they re-read a fragment
+        list (executor._list_extent) — 954 locked window computations
+        measured as ~half of a billion-column count's latency when
+        every query paid them. A racing mutation serves the consistent
         pre-write snapshot (same linearizability as the stack caches'
         token race)."""
         memo = self._win32_memo

@@ -598,8 +598,9 @@ class Holder:
     def fragments(self, index, frame, view, slices):
         """Bulk accessor: resolve index→frame→view ONCE, then one
         lookup per slice. Batched executors fetch whole slice lists
-        (1B columns = 954 fragments per leaf per query); the per-call
-        chain walk was a measurable slice of query latency."""
+        (1B columns = 954 fragments per list) and keep each until the
+        index's mutation epoch moves (executor._frag_list); the
+        per-call chain walk was a measurable slice of query latency."""
         idx = self.index(index)
         fr = idx.frame(frame) if idx is not None else None
         v = fr.view(view) if fr is not None else None
