@@ -1,0 +1,8 @@
+"""Layer: tier choice. Source: program_span: ``result.memo`` (the TopN
+result memo's lookup) + ``exec.route`` (node partition and the path model's
+choice, once a phase) of a request, median. Moves query_p50_ms."""
+from perfbench.lib import spans
+
+
+def read(ctx):
+    return spans.median_span_ms(ctx, ("result.memo", "exec.route"))

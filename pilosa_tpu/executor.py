@@ -17,7 +17,7 @@ Sum, the BSI plane stack) compiles to ONE fused XLA program over
 (stacks are cached, byte-bounded LRU, version-invalidated). Time
 Ranges batch (view-cover expansion) and BSI conditions batch (vmapped
 plane descents); TopN batches both phases incl. the Tanimoto variant
-(fused intersect/row/src popcounts, host-side ceil threshold); inverse
+(fused intersect/row/src popcounts and the integer gate); inverse
 orientation batches through inverse-view leaf stacks. In multi-node
 map/reduce each node — coordinator included — runs its own slice set
 through the batched path (the TPU answer to the reference's
@@ -50,6 +50,7 @@ from pilosa_tpu.observe import devprof as devprof_mod
 from pilosa_tpu.observe import heatmap as heatmap_mod
 from pilosa_tpu.observe import kerneltime as kerneltime_mod
 from pilosa_tpu.ops import containers as containers_mod
+from pilosa_tpu.ops.bitops import program_name
 from pilosa_tpu.plancache import (FragList, PlanCache, as_slice_list,
                                   slice_key)
 from pilosa_tpu import planner as planner_mod
@@ -71,21 +72,6 @@ KNOWN_CALLS = frozenset({
 })
 
 logger = logging.getLogger("pilosa_tpu.executor")
-
-
-# Operand counts past this share one program name: names stay few.
-PROGRAM_NAME_MAX_OPERANDS = 16
-
-
-def program_name(tier, operands):
-    """The name a jitted program of this executor carries in a device
-    trace (``jit_<name>`` on the ``XLA Modules`` line): the tier that
-    built it and how many operand stacks it reads, as
-    ``pilosa_count_batched_k3``. No hash and no row id, so a trace's
-    gaps and device times can be put to a query shape by name."""
-    k = (f"k{operands}" if operands <= PROGRAM_NAME_MAX_OPERANDS
-         else f"k{PROGRAM_NAME_MAX_OPERANDS}p")
-    return f"pilosa_{tier}_{k}"
 
 
 def _plan_operands(node):
@@ -116,6 +102,27 @@ def _run_count_split(fn, stacks):
         out.block_until_ready()
     with tracing.span("kernel.fetch"):
         return np.asarray(out)
+
+
+def _popcounts(x):
+    """int32 popcount over the last (word) axis."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    return jnp.sum(lax.population_count(x).astype(jnp.int32), axis=-1)
+
+
+def _per_candidate(f, src, rows, gathered):
+    """int32[R, S] of ``f(rows, src)`` a TopN candidate, inside a
+    jitted program. ``rows`` holds the one gathered ``[S, R, W]``
+    operand (``_rows_stack``), or a ``[S, W]`` leaf stack a candidate:
+    those are reduced one by one, because stacking them inside the
+    program materialises the stack (2 GB at 954 slices x 16 rows)."""
+    import jax.numpy as jnp
+
+    if gathered:
+        return f(rows[0], None if src is None else src[:, None, :]).T
+    return jnp.stack([f(r, src) for r in rows])
 
 
 # Sentinel a batch_fn returns for "ran, and the answer is empty" — as
@@ -1007,6 +1014,12 @@ class Executor:
         entry; a src-filtered TopN does not."""
         return (call.name, tuple(sorted(call.args)),
                 tuple(cls._call_shape(c) for c in call.children))
+
+    @staticmethod
+    def _candidate_bucket(n_ids):
+        """Candidate counts bucket to a power of two: the jitted TopN
+        evaluator re-traces O(log R) times, not per candidate set."""
+        return 1 << max(n_ids - 1, 0).bit_length()
 
     def _serial_exec(self, node_slices, map_fn, reduce_fn, deadline=None):
         """Per-slice loop. With ``deadline`` (a perf_counter instant,
@@ -3761,6 +3774,25 @@ class Executor:
         self._stack_cache_put(key, tokens, stack)
         return stack
 
+    def _rows_stack(self, frags, row_ids, n_rows, pad, n_dev, win):
+        """Sharded ``uint32[S+pad, n_rows, width]`` stack of TopN's
+        candidate rows (zero rows after ``row_ids``) across ``frags``
+        at the plan's column window: one gather a fragment
+        (``Fragment.device_rows_win``) and one operand of the candidate
+        program, whatever the number of candidates. Built, used and
+        dropped: no cache holds it (a repeated TopN is answered by the
+        ``topnc`` memo before it reaches a stack)."""
+        import jax.numpy as jnp
+
+        querystats.add("stackBuilds")
+        mats = [None if f is None else
+                f.device_rows_win(row_ids, n_rows, win[0], win[1])
+                for f in frags] + [None] * pad
+        if any(m is None for m in mats):
+            zero = jnp.zeros((n_rows, win[1]), jnp.uint32)
+            mats = [zero if m is None else m for m in mats]  # count 0
+        return self._shard_stack(jnp.stack(mats), n_dev, 3)
+
     @staticmethod
     def _spec_rows(spec):
         """Row-equivalents a spec's arg occupies on device (budgeting)."""
@@ -4193,12 +4225,23 @@ class Executor:
         return {rid for rid in store.ids()
                 if store.attrs(rid).get(attr_name) in filters}
 
+    # Candidate sets past this are served by the per-fragment path: the
+    # candidate stack holds its bucket of rows for every slice.
+    TOPN_CANDIDATES_MAX = 1024
+    # The candidates are gathered afresh into ONE operand (a gather a
+    # fragment, held by no cache) while that operand is at most this
+    # many slice-rows (128 MiB at the full width): few slices, rows
+    # from a large universe that rarely repeat. Past it a stack a
+    # candidate, kept in the stack cache and shared with Count's: at
+    # 954 slices a 16-row bucket would be 2 GB of fresh stack a query.
+    TOPN_GATHER_MAX_ROWS = 1024
+
     def _topn_candidate_counts(self, index, frame_name, view, row_ids,
                                slices, tanimoto, plan, leaves,
                                candidates_shrink=False):
         """Per-(candidate, slice) count matrix [len(row_ids),
         len(slices)] in one fused XLA program: |row ∩ src| (zeroed by
-        the Tanimoto ceil gate when requested) or |row| without a plan.
+        the Tanimoto gate when requested) or |row| without a plan.
         The single device path under both batched TopN phases. None
         when the candidate set exceeds the jit-arity bucket or the
         device budget."""
@@ -4222,14 +4265,10 @@ class Executor:
 
         n_dev = len(jax.devices())
         pad = (-len(slices)) % n_dev
-        # Bucket the candidate count to a power of two so the jitted
-        # evaluator re-traces O(log R) times, not per candidate set.
-        r_pad = 1
-        while r_pad < len(row_ids):
-            r_pad *= 2
+        r_pad = self._candidate_bucket(len(row_ids))
         # Candidate sets are data-dependent: above the device budget
-        # (or a sane jit arity) the serial per-slice matrix path wins.
-        if r_pad > 1024 and not candidates_shrink:
+        # (or TOPN_CANDIDATES_MAX) the serial per-slice matrix path wins.
+        if r_pad > self.TOPN_CANDIDATES_MAX and not candidates_shrink:
             # Explicit-ids candidate sets don't shrink with the window:
             # decline immediately so no halving recursion probes this.
             return None
@@ -4238,14 +4277,18 @@ class Executor:
         # revalidation are O(slices) Python per query — at 10k slices
         # that dwarfed the phase-2 kernel itself. Stacks resolve from
         # the byte-budgeted stack cache; eviction falls back here.
+        # A gathered operand is rebuilt a query: its prelude has no
+        # stack-cache keys to keep.
+        padded_n = len(slices) + pad
+        gathered = padded_n * r_pad <= self.TOPN_GATHER_MAX_ROWS
         pkey2 = ("topnp", index, frame_name, view, tuple(row_ids),
                  slice_key(slices),
                  str(plan) if plan is not None else None,
                  tuple(leaves) if leaves else ())
-        hit2 = self._prelude_memo_get(pkey2)
+        hit2 = None if gathered else self._prelude_memo_get(pkey2)
         if hit2 is not None:
             (colwin,), all_stacks, _ = hit2
-            stacks = list(all_stacks[: len(row_ids)])
+            rows = list(all_stacks[: len(row_ids)])
             leaf_stacks = list(all_stacks[len(row_ids):])
         else:
             # Column window: the candidate rows' own fragments plus
@@ -4258,60 +4301,71 @@ class Executor:
             cand_frags = frag_map[(frame_name, view)]
             if not self._fits_device_budget(
                     r_pad + sum(self._spec_rows(sp) for sp in leaves),
-                    len(slices) + pad, width32=colwin[1]):
+                    padded_n, width32=colwin[1]):
                 return BATCH_OVER_BUDGET
-            if r_pad > 1024:
+            if r_pad > self.TOPN_CANDIDATES_MAX:
                 # Phase 1's candidate set is the window's cache union,
                 # so smaller windows can fit.
                 return BATCH_OVER_BUDGET
-            stacks = [self._leaf_stack(index, frame_name, rid, slices,
-                                       pad, n_dev, view=view,
-                                       win=colwin, frags=cand_frags)
-                      for rid in row_ids]
+            with tracing.span("topn.stacks", candidates=len(row_ids)):
+                if gathered:
+                    rows = [self._rows_stack(cand_frags, row_ids, r_pad,
+                                             pad, n_dev, colwin)]
+                else:
+                    rows = [self._leaf_stack(index, frame_name, rid, slices,
+                                             pad, n_dev, view=view,
+                                             win=colwin, frags=cand_frags)
+                            for rid in row_ids]
             leaf_stacks = []
             if plan is not None:
                 leaf_stacks = [self._spec_arg(index, sp, slices, pad,
                                               n_dev, colwin, frag_map)
                                for sp in leaves]
-            # Candidate rows as ("row", ...) leaf specs so the ONE
-            # key-layout authority (_prelude_specs) builds every
-            # descriptor — an inline copy would silently drift if the
-            # stack-cache key ever changes shape.
-            cand_leaves = [("row", frame_name, rid, view)
-                           for rid in row_ids]
-            specs = self._prelude_specs(
-                index, cand_leaves + list(leaves),
-                stacks + leaf_stacks, slices, n_dev, colwin)
-            self._prelude_memo_put(pkey2, (colwin,), specs, None, epoch)
-        zero = None
-        while len(stacks) < r_pad:
-            if zero is None:
-                zero = jnp.zeros_like(stacks[0])
-            stacks.append(zero)
+            if not gathered:
+                # Candidate rows as ("row", ...) leaf specs so the ONE
+                # key-layout authority (_prelude_specs) builds every
+                # descriptor — an inline copy would silently drift if
+                # the stack-cache key ever changes shape.
+                cand_leaves = [("row", frame_name, rid, view)
+                               for rid in row_ids]
+                specs = self._prelude_specs(
+                    index, cand_leaves + list(leaves),
+                    rows + leaf_stacks, slices, n_dev, colwin)
+                self._prelude_memo_put(pkey2, (colwin,), specs, None, epoch)
+        if not gathered:
+            rows += [jnp.zeros_like(rows[0])] * (r_pad - len(rows))
         src_stack = None
         if plan is not None:
             src_stack = self._batched_src_fn(
-                str(plan), plan, len(slices) + pad,
-                colwin[1])(*leaf_stacks)
+                str(plan), plan, padded_n, colwin[1])(*leaf_stacks)
 
+        querystats.add("topnRowsScanned", len(row_ids) * len(slices))
         if tanimoto and src_stack is not None:
             # One fused program yields per-(candidate, slice) |row∩src|
-            # and the score (computed on device through the same traced
-            # formula the serial path uses, so the two paths agree per
-            # backend); the ceil gate runs on the small host matrices.
-            from pilosa_tpu.ops import topn as topn_ops
-
-            fn = self._batched_topn_tanimoto_fn(r_pad, len(slices) + pad)
-            inter, scores = (np.asarray(x) for x in fn(src_stack, *stacks))
-            inter = inter[: len(row_ids), : len(slices)]
-            scores = scores[: len(row_ids), : len(slices)]
-            out = np.where(
-                topn_ops.tanimoto_keep(scores, tanimoto), inter, 0)
-            return self._topn_counts_memoize(pkey, out, epoch)
-        fn = self._batched_topn_fn(src_stack is not None, r_pad,
-                                   len(slices) + pad)
-        counts = np.asarray(fn(src_stack, *stacks)
-                            if src_stack is not None else fn(*stacks))
+            # already zeroed by the gate (ops.topn.tanimoto_keep, the
+            # rule the per-fragment program applies): popcounts to
+            # keep/drop in integers, on the device. The threshold is
+            # a traced operand: one executable for every threshold.
+            op = "topn_tanimoto"
+            fn, hit = self._batched_topn_tanimoto_fn(r_pad, padded_n,
+                                                     gathered)
+            args = [src_stack, np.int32(tanimoto)] + rows
+        else:
+            op = "topn_src_rows" if src_stack is not None else "topn_rows"
+            fn, hit = self._batched_topn_fn(src_stack is not None, r_pad,
+                                            padded_n, gathered)
+            args = ([src_stack] if src_stack is not None else []) + rows
+        t0 = time.perf_counter()
+        run = (_run_count if tracing.active_span() is None
+               else _run_count_split)
+        counts = run(fn, args)
+        if not hit and kerneltime_mod.ACTIVE.enabled:
+            # The fn-cache miss is this program's XLA compile: counted
+            # where /debug/kernels counts the Count programs'.
+            kerneltime_mod.ACTIVE.note(
+                op, "dense*dense",
+                kerneltime_mod.shape_bucket(rows[0].nbytes * len(rows)),
+                time.perf_counter() - t0, compiled=True, device=True)
         out = counts[: len(row_ids), : len(slices)]
         return self._topn_counts_memoize(pkey, out, epoch)
 
@@ -4435,7 +4489,7 @@ class Executor:
         """Exact TopN re-query (phase 2): per-candidate popcounts over
         slice stacks in one fused XLA program, mirroring the serial
         per-slice threshold-then-sum semantics — including the Tanimoto
-        ceil-threshold variant. None when ineligible (unbatchable src
+        threshold variant. None when ineligible (unbatchable src
         tree / candidate set too large / empty)."""
         row_ids, has_ids = call.uint_slice_arg("ids")
         if not slices or not has_ids or not row_ids:
@@ -4487,13 +4541,25 @@ class Executor:
         if plan is None:
             return None
 
+        frags = self._frag_list(index, frame_name, view, slices)
+        allowed = self._topn_attr_allowed(index, call, frame_name)
+        if allowed is None and any(
+                frag is not None
+                and frag.cache_entry_count() > self.TOPN_CANDIDATES_MAX
+                for frag in frags):
+            # One slice's own candidates already pass what the
+            # candidate program takes, so no window of the slices
+            # fits: decline before the union below is built. (At
+            # 500,000 cached rows in one fragment that union cost the
+            # path model's every-64th retry of this shape ~100 ms
+            # only to be declined at the bucket check.)
+            return None
         # cache_entry_ids serves evicted fragments from the sidecar
         # through the lazy path — phase 1 over a cold slice list no
         # longer faults every fragment in just to read candidate ids.
         ent_sets = [
             frag.cache_entry_ids() if frag is not None else frozenset()
-            for frag in self._frag_list(index, frame_name, view, slices)]
-        allowed = self._topn_attr_allowed(index, call, frame_name)
+            for frag in frags]
         if allowed is not None:
             ent_sets = [es & allowed for es in ent_sets]
 
@@ -4538,54 +4604,47 @@ class Executor:
         return self._cached_fn(("src", tree_key, padded_n, width32),
                                build, "topn_src", _plan_operands(plan))
 
-    def _batched_topn_fn(self, has_src, r_pad, padded_n):
-        import jax
-        import jax.numpy as jnp
-        from jax import lax
-
+    def _batched_topn_fn(self, has_src, r_pad, padded_n, gathered):
+        """(program, whether the fn cache already held it): int32
+        [r_pad, padded_n] popcounts of the candidates' rows (one
+        ``_rows_stack`` or ``r_pad`` leaf stacks), each intersected
+        with its slice's src where there is one."""
         def build():
-            if has_src:
-                def fn(src, *rows):
-                    outs = [jnp.sum(lax.population_count(
-                        lax.bitwise_and(r, src)).astype(jnp.int32), axis=1)
-                        for r in rows]
-                    return jnp.stack(outs)
-            else:
-                def fn(*rows):
-                    outs = [jnp.sum(
-                        lax.population_count(r).astype(jnp.int32), axis=1)
-                        for r in rows]
-                    return jnp.stack(outs)
+            def fn(*args):
+                src, rows = (args[0], args[1:]) if has_src else (None, args)
+                return _per_candidate(
+                    lambda r, s: _popcounts(r if s is None else r & s),
+                    src, rows, gathered)
             return fn
 
+        key = ("topn", has_src, r_pad, padded_n, gathered)
+        hit = key in self._batched_cache
         return self._cached_fn(
-            ("topn", has_src, r_pad, padded_n), build,
-            "topn_src_rows" if has_src else "topn_rows", r_pad)
+            key, build, "topn_src_rows" if has_src else "topn_rows",
+            r_pad), hit
 
-    def _batched_topn_tanimoto_fn(self, r_pad, padded_n):
-        import jax
+    def _batched_topn_tanimoto_fn(self, r_pad, padded_n, gathered):
+        """(program, whether the fn cache already held it): the
+        per-(candidate, slice) |row ∩ src| of the candidates' rows,
+        zeroed where the integer gate drops the pair."""
         import jax.numpy as jnp
-        from jax import lax
 
         from pilosa_tpu.ops import topn as topn_ops
 
         def build():
-            def fn(src, *rows):
-                src_n = jnp.sum(
-                    lax.population_count(src).astype(jnp.int32), axis=1)
-                inter = jnp.stack([jnp.sum(lax.population_count(
-                    lax.bitwise_and(r, src)).astype(jnp.int32), axis=1)
-                    for r in rows])
-                row_n = jnp.stack([jnp.sum(
-                    lax.population_count(r).astype(jnp.int32), axis=1)
-                    for r in rows])
-                scores = topn_ops.tanimoto_score_counts(
-                    inter, row_n, src_n[None, :])
-                return inter, scores
+            def fn(src, threshold, *rows):
+                inter = _per_candidate(lambda r, s: _popcounts(r & s),
+                                       src, rows, gathered)
+                row_n = _per_candidate(lambda r, s: _popcounts(r),
+                                       src, rows, gathered)
+                keep = topn_ops.tanimoto_keep(
+                    inter, row_n, _popcounts(src)[None, :], threshold)
+                return jnp.where(keep, inter, 0)
             return fn
 
-        return self._cached_fn(("topn_tan", r_pad, padded_n), build,
-                               "topn_tanimoto", r_pad)
+        key = ("topn_tan", r_pad, padded_n, gathered)
+        hit = key in self._batched_cache
+        return self._cached_fn(key, build, "topn_tanimoto", r_pad), hit
 
     def _batched_sum(self, index, call, slices):
         """Sum over the local slice list as one sharded XLA program:
@@ -5243,17 +5302,35 @@ class Executor:
         ids_arg, has_ids = call.uint_slice_arg("ids")
         n, _ = call.uint_arg("n")
 
+        def phase(name, phase_call):
+            """One pass over the slices under its span, tagged with
+            the path that served it (``serial`` / ``batched``), the
+            ids it was given and their bucket."""
+            with tracing.span(name) as sp:
+                qs = querystats.active()
+                traced = sp is not tracing.NOP_SPAN and qs is not None
+                mark = qs.mark() if traced else None
+                pairs = self._execute_topn_slices(index, phase_call,
+                                                  slices, opt)
+                if traced:
+                    ids = phase_call.args.get("ids") or ()
+                    sp.tag(path=qs.served_since(mark),
+                           candidates=len(ids),
+                           bucket=self._candidate_bucket(len(ids)))
+            return pairs
+
         def compute():
-            pairs = self._execute_topn_slices(index, call, slices, opt)
-            if not pairs or has_ids or opt.remote:
-                return pairs
-            other = call.clone()
-            other.args["ids"] = sorted(rid for rid, _ in pairs)
-            trimmed = self._execute_topn_slices(index, other, slices,
-                                                opt)
-            if n:
-                trimmed = trimmed[:n]
-            return trimmed
+            pairs = phase("topn.phase2" if has_ids else "topn.phase1",
+                          call)
+            if pairs and not has_ids and not opt.remote:
+                other = call.clone()
+                other.args["ids"] = sorted(rid for rid, _ in pairs)
+                querystats.add("topnCandidates", len(other.args["ids"]))
+                pairs = phase("topn.phase2", other)
+                if n:
+                    pairs = pairs[:n]
+            querystats.add("topnKept", len(pairs or ()))
+            return pairs
 
         if has_ids:
             return compute()

@@ -138,6 +138,21 @@ def _kernel_hist(name):
     return child
 
 
+# Operand counts past this share one program name: names stay few.
+PROGRAM_NAME_MAX_OPERANDS = 16
+
+
+def program_name(tier, operands):
+    """The name a jitted program of the served path carries in a device
+    trace (``jit_<name>`` on the ``XLA Modules`` line): the tier that
+    built it and how many operand stacks it reads, as
+    ``pilosa_count_batched_k3``. No hash and no row id, so a trace's
+    gaps and device times can be put to a query shape by name."""
+    k = (f"k{operands}" if operands <= PROGRAM_NAME_MAX_OPERANDS
+         else f"k{PROGRAM_NAME_MAX_OPERANDS}p")
+    return f"pilosa_{tier}_{k}"
+
+
 def _traced_dispatch(name, fn, *args):
     """Dispatch a jitted kernel under the active trace span; a plain
     call when no trace is active (one attribute read of overhead).

@@ -8,11 +8,17 @@ matrix is one fused kernel, so the primary path is: popcount all rows
 is kept host-side for API parity and warm-start, but correctness does
 not depend on it.
 """
+import time
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+
+from pilosa_tpu import tracing
+from pilosa_tpu.observe import kerneltime
+from pilosa_tpu.ops import bitops
 
 
 @partial(jax.jit, static_argnames=("k",))
@@ -35,41 +41,62 @@ def top_k_rows_src(matrix, src, k):
     return lax.top_k(counts, k)
 
 
-def tanimoto_score_counts(inter, row_n, src_n):
-    """Traceable Tanimoto ×100 from popcount triples (ref:
-    fragment.go:850-858): 100·|A∩B| / (|A|+|B|−|A∩B|), 0 when the
-    denominator is 0. The single source of the score formula — both the
-    per-fragment path and the executor's batched phase-2 kernel trace
-    through here, so their float32 arithmetic is identical per backend.
-    """
+def tanimoto_keep(inter, row_n, src_n, threshold):
+    """The Tanimoto gate, in integers (ref: fragment.go:850-858 and
+    :908-918 keep a row when ceil(100*|A∩B| / (|A|+|B|−|A∩B|)) is
+    STRICTLY greater than the threshold): for an integer threshold that
+    is ``100*inter > threshold*denom``, and false where the denominator
+    is 0. The one place that holds the rule: the per-fragment program,
+    the executor's batched program and any host-side caller pass their
+    popcounts through here, as jax or NumPy integer arrays alike. int32
+    holds it: a fragment's row has at most 2^20 bits, so 100*inter and
+    100*denom stay under 2^31. No float stands between the popcounts
+    and keep/drop: the v5e's float32 division is not correctly rounded
+    and kept rows that lie exactly on a threshold (PR 23)."""
     denom = row_n + src_n - inter
-    return jnp.where(
-        denom > 0, 100.0 * inter.astype(jnp.float32) / denom.astype(jnp.float32), 0.0
-    )
+    return (100 * inter > threshold * denom) & (denom > 0)
 
 
-@jax.jit
-def tanimoto_masked_counts(matrix, src, row_n, src_n, threshold):
+def _tanimoto_masked_counts(matrix, src, row_n, src_n, threshold):
     """Fused per-fragment Tanimoto path: src-intersection popcounts,
-    scores, ceil-gate and mask in ONE device program — a single host
-    fetch of the final masked counts where the unfused pipeline paid
-    ~4 host↔device round trips per query; the score/gate semantics
-    are exactly
-    tanimoto_score_counts + the ceil(score) > threshold rule of
-    fragment.go:908-918, evaluated on device."""
-    from pilosa_tpu.ops import bitops
-
+    the integer gate (``tanimoto_keep``) and the mask in ONE device
+    program; a single host fetch of the final masked counts.
+    ``threshold`` is traced, so one executable serves every
+    threshold."""
     inter = bitops.count_and_rows(matrix, src)
-    scores = tanimoto_score_counts(inter, row_n, src_n)
-    keep = jnp.ceil(scores) > threshold
-    return jnp.where(keep, inter, 0)
+    return jnp.where(tanimoto_keep(inter, row_n, src_n, threshold), inter, 0)
 
 
-def tanimoto_keep(scores, threshold):
-    """Host-side threshold gate (ref: fragment.go:908-918): keep rows
-    whose ceil(score) is STRICTLY greater than the threshold."""
-    import numpy as np
+# The per-fragment program's name in a device trace (``jit_<name>`` on
+# the ``XLA Modules`` line): one probe row against the whole matrix.
+TANIMOTO_FRAGMENT_PROGRAM = bitops.program_name("topn_tanimoto_frag", 1)
+_tanimoto_masked_counts.__name__ = TANIMOTO_FRAGMENT_PROGRAM
+_tanimoto_masked_counts.__qualname__ = TANIMOTO_FRAGMENT_PROGRAM
+tanimoto_masked_counts = jax.jit(_tanimoto_masked_counts)
 
-    return np.ceil(np.asarray(scores)) > threshold
 
-
+def fetch_counts(fn, matrix, *args, op=None):
+    """The per-fragment TopN call as ``Fragment.top`` makes it: enqueue,
+    device wait and the copy of the counts to the host in one
+    expression; under a trace cut where the time can hide, into
+    ``top.kernel`` (the jitted call until it returns), ``top.wait``
+    (``block_until_ready``) and ``top.fetch`` (``np.asarray``). With
+    ``op``, a dispatch that grew ``fn``'s executable cache is noted as
+    that op's compile in the kernel observatory (``/debug/kernels``)."""
+    t0 = time.perf_counter()
+    if tracing.active_span() is None:
+        counts = np.asarray(fn(matrix, *args))
+    else:
+        with tracing.span("top.kernel"):
+            out = fn(matrix, *args)
+        with tracing.span("top.wait"):
+            out.block_until_ready()
+        with tracing.span("top.fetch"):
+            counts = np.asarray(out)
+    obs = kerneltime.ACTIVE
+    if (op is not None and obs.enabled
+            and obs.note_jit_cache(op, fn._cache_size())):
+        obs.note(op, bitops.FMT_DENSE,
+                 kerneltime.shape_bucket(matrix.nbytes),
+                 time.perf_counter() - t0, compiled=True, device=True)
+    return counts

@@ -83,12 +83,17 @@ MAX_HEDGE_LEGS = 64
 # a prelude took from the plan cache's "leaf" entries against the
 # lists it had to walk, O(slices) each (executor._frag_list): a
 # never-seen query over a quiet index reads misses 0.
+# topnRowsScanned counts the rows whose popcounts a TopN program took
+# on the device (a fragment's rows a scan of it, candidates x slices
+# in the batched program); topnCandidates the ids TopN's phase 1 gave
+# its exact re-query; topnKept the pairs a TopN call returned.
 KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "cacheMisses", "deviceTransfers", "deviceTransferBytes",
         "fanoutCalls", "fanoutRetries", "planMs", "planCacheHit",
         "containerBlocksDense", "containerBlocksArray",
         "containerBlocksRun", "stackBuilds", "oomFallbacks",
-        "leafMemoHits", "leafMemoMisses")
+        "leafMemoHits", "leafMemoMisses", "topnRowsScanned",
+        "topnCandidates", "topnKept")
 
 
 class QueryStats:

@@ -414,6 +414,8 @@ class Fragment:
         self._row_counts = np.zeros(0, dtype=np.int64)
         self._row_index = {}      # rowID -> physical row
         self._phys_rows = []      # physical row -> rowID
+        self._phys_arr = None     # (that list, its uint64 array)
+        self._cache_mask = None   # (that array, cache ids, rows in cache)
         self.max_row_id = 0
 
         self.op_n = 0
@@ -1064,6 +1066,14 @@ class Fragment:
                 return out
         with self.mu:
             return frozenset(self.cache.entries)
+
+    def cache_entry_count(self):
+        """How many ids ``cache_entry_ids`` holds, without building
+        the set where the fragment is resident."""
+        if self._resident or not self._opened:
+            with self.mu:
+                return len(self.cache)
+        return len(self.cache_entry_ids())
 
     def _lazy_cache_ids_locked(self):
         if self._cache_loaded:
@@ -1991,6 +2001,34 @@ class Fragment:
                 self._row_dev.clear()
             self._row_dev[key] = (self._version, row)
             return row
+
+    def device_rows_win(self, row_ids, n_rows, base32, width32):
+        """uint32[n_rows, width32]: ``device_row_win`` of each of
+        ``row_ids`` in order, zero rows after them. Where the HBM
+        mirror holds the fragment clean at the requested window this
+        is ONE gather, whatever the number of rows (a row the fragment
+        lacks reads as zero): TopN's exact re-query used to pay a
+        device slice and a stack per candidate. Elsewhere (no mirror,
+        a dirty row, another window, an evicted fragment) row by row,
+        with ``device_row_win``'s own care not to force a refresh."""
+        if self._resident:
+            with self.mu:
+                dev = self._dev
+                if (dev is not None and not self._dirty
+                        and dev.shape == (self._cap, self._w64 * 2)
+                        and (self._w64_base * 2, self._w64 * 2)
+                        == (base32, width32)):
+                    querystats.add("blocks", len(row_ids))
+                    # Past the last row: gathers as the fill value.
+                    idx = np.full(n_rows, self._cap, dtype=np.int32)
+                    idx[: len(row_ids)] = [
+                        self._row_index.get(r, self._cap) for r in row_ids]
+                    return dev.at[jnp.asarray(idx)].get(
+                        mode="fill", fill_value=0)
+        rows = [self.device_row_win(r, base32, width32) for r in row_ids]
+        rows.extend([jnp.zeros(width32, dtype=jnp.uint32)]
+                    * (n_rows - len(rows)))
+        return jnp.stack(rows)
 
     # ---------------------------------------------------------- mutations
 
@@ -2959,60 +2997,100 @@ class Fragment:
                 # matrix; building (and slicing) it for the src-less
                 # cache walk cost a device upload + dispatch per
                 # fragment per query for data the counts never touch.
-                matrix = self.device_matrix()[:n_phys]
-                # The matrix may be narrower than the full slice; bits
-                # beyond its width are zero, so trimming src to the
-                # matrix width preserves every intersection count. The
-                # Tanimoto denominator's |src| must still come from the
-                # FULL src bitmap.
-                src_words = np.ascontiguousarray(opt.src)
-                base = self._w64_base
-                src32 = jnp.asarray(np.ascontiguousarray(
-                    src_words[base : base + self._w64]).view(np.uint32))
+                with tracing.span("top.src", rows=n_phys):
+                    matrix = self.device_matrix()[:n_phys]
+                    # The matrix may be narrower than the full slice;
+                    # bits beyond its width are zero, so trimming src
+                    # to the matrix width preserves every intersection
+                    # count. The Tanimoto denominator's |src| must
+                    # still come from the FULL src bitmap.
+                    src_words = np.ascontiguousarray(opt.src)
+                    base = self._w64_base
+                    src32 = jnp.asarray(np.ascontiguousarray(
+                        src_words[base : base + self._w64]).view(np.uint32))
+                querystats.add("topnRowsScanned", n_phys)
                 if opt.tanimoto_threshold:
-                    counts = np.asarray(topn_ops.tanimoto_masked_counts(
-                        matrix, src32, self._row_counts_device(n_phys),
+                    counts = topn_ops.fetch_counts(
+                        topn_ops.tanimoto_masked_counts, matrix, src32,
+                        self._row_counts_device(n_phys),
                         int(np.bitwise_count(src_words).sum()),
-                        opt.tanimoto_threshold))
+                        opt.tanimoto_threshold, op="topn_tanimoto_frag")
                 else:
-                    counts = np.asarray(bitops.count_and_rows(matrix, src32))
+                    counts = topn_ops.fetch_counts(bitops.count_and_rows,
+                                                   matrix, src32)
             else:
                 counts = self._row_counts[:n_phys].copy()
 
-            row_ids = np.asarray(self._phys_rows, dtype=np.uint64)
-            counts_np = np.asarray(counts, dtype=np.int64)
-            # Vectorized eligibility + selection: at the chem-showcase
-            # shape (500k cached rows in one fragment) the per-row
-            # Python loop + full sort this replaces was ~300 ms/query —
-            # most of the measured TopN latency on an accelerator.
-            mask = counts_np > 0
-            if opt.min_threshold:
-                mask &= counts_np >= opt.min_threshold
-            if opt.row_ids is not None:
-                mask &= np.isin(row_ids, np.fromiter(
-                    opt.row_ids, dtype=np.uint64))
-            elif not isinstance(self.cache, NopCache):
-                mask &= np.isin(row_ids, self.cache.ids_arr())
-            if opt.filter_row_ids is not None:
-                mask &= np.isin(row_ids, np.fromiter(
-                    opt.filter_row_ids, dtype=np.uint64))
-            idx = np.nonzero(mask)[0]
-            # Explicit row ids (the TopN phase-2 exact re-query) are
-            # never truncated per slice — trimming happens only after
-            # the cross-slice merge (ref: fragment.go:835-838
-            # "If row ids are provided, we don't want to truncate").
-            truncate = bool(opt.n) and opt.row_ids is None
-            if truncate and idx.size > opt.n:
-                # Exact top-n: nth-largest count bounds the candidate
-                # set (count ties straddling the cut stay in and are
-                # broken by row id in the final sort).
-                c = counts_np[idx]
-                nth = c[np.argpartition(-c, opt.n - 1)[opt.n - 1]]
-                idx = idx[c >= nth]
-            order = np.lexsort((row_ids[idx], -counts_np[idx]))
-            sel = idx[order[: opt.n]] if truncate else idx[order]
-            return [(int(r), int(c))
-                    for r, c in zip(row_ids[sel], counts_np[sel])]
+            with tracing.span("top.select", rows=n_phys):
+                return self._top_select(opt, counts)
+
+    def _phys_row_ids(self):
+        """uint64 array of the physical rows' ids (read only), kept
+        while the list is the same list at the same length: rows are
+        only ever appended, and a reload starts a new list. Converting
+        500,000 Python ints cost every ``top`` 18 of its 30 ms of
+        selection (PR 26's spans)."""
+        memo = self._phys_arr
+        if (memo is None or memo[0] is not self._phys_rows
+                or len(memo[1]) != len(self._phys_rows)):
+            memo = self._phys_arr = (self._phys_rows, np.asarray(
+                self._phys_rows, dtype=np.uint64))
+        return memo[1]
+
+    def _cached_rows_mask(self):
+        """bool[rows] (read only): the physical row is in the ranked
+        cache, TopN's candidate rule. Kept while the rows' id array and
+        the cache's (``cache.ids_arr()``) are the same objects: each is
+        built anew when its membership changes. The ``isin`` it saves
+        sorted a million ids a query, and its 30 MB of temporaries were
+        where the selection's slow requests came from (PR 26's spans:
+        ``top.select`` 6.7 ms in the median, 22-28 in one of twenty)."""
+        ids, cached = self._phys_row_ids(), self.cache.ids_arr()
+        memo = self._cache_mask
+        if memo is None or memo[0] is not ids or memo[1] is not cached:
+            memo = self._cache_mask = (ids, cached, np.isin(ids, cached))
+        return memo[2]
+
+    def _top_select(self, opt, counts):
+        """Eligibility and selection over one count per physical row,
+        on the host (caller holds ``mu``): (row id, count) pairs in
+        (-count, id) order."""
+        from pilosa_tpu.storage.cache import NopCache
+
+        row_ids = self._phys_row_ids()
+        counts_np = np.asarray(counts, dtype=np.int64)
+        # Vectorized eligibility + selection: at the chem-500k shape
+        # (500k cached rows in one fragment) the per-row Python loop +
+        # full sort this replaces was ~300 ms/query — most of the
+        # measured TopN latency on an accelerator.
+        mask = counts_np > 0
+        if opt.min_threshold:
+            mask &= counts_np >= opt.min_threshold
+        if opt.row_ids is not None:
+            mask &= np.isin(row_ids, np.fromiter(
+                opt.row_ids, dtype=np.uint64))
+        elif not isinstance(self.cache, NopCache):
+            mask &= self._cached_rows_mask()
+        if opt.filter_row_ids is not None:
+            mask &= np.isin(row_ids, np.fromiter(
+                opt.filter_row_ids, dtype=np.uint64))
+        idx = np.nonzero(mask)[0]
+        # Explicit row ids (the TopN phase-2 exact re-query) are
+        # never truncated per slice — trimming happens only after
+        # the cross-slice merge (ref: fragment.go:835-838
+        # "If row ids are provided, we don't want to truncate").
+        truncate = bool(opt.n) and opt.row_ids is None
+        if truncate and idx.size > opt.n:
+            # Exact top-n: nth-largest count bounds the candidate
+            # set (count ties straddling the cut stay in and are
+            # broken by row id in the final sort).
+            c = counts_np[idx]
+            nth = c[np.argpartition(-c, opt.n - 1)[opt.n - 1]]
+            idx = idx[c >= nth]
+        order = np.lexsort((row_ids[idx], -counts_np[idx]))
+        sel = idx[order[: opt.n]] if truncate else idx[order]
+        return [(int(r), int(c))
+                for r, c in zip(row_ids[sel], counts_np[sel])]
 
     # -------------------------------------------------------------- backup
 

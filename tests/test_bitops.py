@@ -177,11 +177,17 @@ def test_top_k_src_and_tanimoto(rng):
     row_n = jnp.sum(
         jax.lax.population_count(jnp.asarray(m)).astype(jnp.int32), axis=-1)
     src_n = jnp.sum(jax.lax.population_count(jnp.asarray(src)).astype(jnp.int32))
-    scores = topn_ops.tanimoto_score_counts(inter, row_n, src_n)
     for i in range(3):
         a, b, x = np_count(m[i]), np_count(src), np_count(m[i] & src)
-        assert abs(float(scores[i]) - 100.0 * x / (a + b - x)) < 1e-3
         assert int(inter[i]) == x
+        # The gate in integers: ceil(100x / (a+b-x)) > t.
+        for t in (1, 100 * x // (a + b - x), 100):
+            want = 100 * x > t * (a + b - x)
+            assert bool(topn_ops.tanimoto_keep(inter, row_n, src_n, t)[i]) \
+                == want
+            masked = topn_ops.tanimoto_masked_counts(
+                jnp.asarray(m), jnp.asarray(src), row_n, src_n, t)
+            assert int(masked[i]) == (x if want else 0)
 
 
 def test_range_mutation(rng):

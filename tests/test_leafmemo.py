@@ -54,6 +54,9 @@ def env(tmp_path):
     idx = seed(holder)
     e = Executor(holder)
     e._force_path = "batched"
+    # The index stands for segmentation-1b's 954 slices, where TopN's
+    # candidates are staged a cached stack a row, not gathered afresh.
+    e.TOPN_GATHER_MAX_ROWS = 0
     serial = Executor(holder)
     serial._force_path = "serial"
     yield holder, idx, e, serial
