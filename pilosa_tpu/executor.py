@@ -5298,7 +5298,14 @@ class Executor:
     def _execute_topn(self, index, call, slices, opt):
         """Two-phase TopN (ref: executeTopN executor.go:369-406):
         approximate per-slice candidates, then exact re-query of the
-        merged id set."""
+        merged id set. The re-query exists because a row that made one
+        slice's top ``n`` may have been cut from another's; over ONE
+        slice phase 1's pairs are the totals already (exact counts
+        under the same gate, threshold and filter, in ``(-count, id)``
+        order, cut at ``n``), so they are the answer and the re-query
+        is skipped: a stated departure from ``executeTopN``, whose
+        result it equals (PARITY.md). A call that arrives with
+        ``ids`` is phase 2 by definition and always runs."""
         ids_arg, has_ids = call.uint_slice_arg("ids")
         n, _ = call.uint_arg("n")
 
@@ -5323,10 +5330,20 @@ class Executor:
             pairs = phase("topn.phase2" if has_ids else "topn.phase1",
                           call)
             if pairs and not has_ids and not opt.remote:
-                other = call.clone()
-                other.args["ids"] = sorted(rid for rid, _ in pairs)
-                querystats.add("topnCandidates", len(other.args["ids"]))
-                pairs = phase("topn.phase2", other)
+                if len(slices) > 1:
+                    other = call.clone()
+                    other.args["ids"] = sorted(rid for rid, _ in pairs)
+                    querystats.add("topnCandidates",
+                                   len(other.args["ids"]))
+                    pairs = phase("topn.phase2", other)
+                else:
+                    # The span stays where the second pass would have
+                    # been, so a trace reads what it costs a request.
+                    with tracing.span(
+                            "topn.phase2", path="skipped",
+                            candidates=len(pairs),
+                            bucket=self._candidate_bucket(len(pairs))):
+                        querystats.add("topnRecountsSkipped")
                 if n:
                     pairs = pairs[:n]
             querystats.add("topnKept", len(pairs or ()))

@@ -86,14 +86,16 @@ MAX_HEDGE_LEGS = 64
 # topnRowsScanned counts the rows whose popcounts a TopN program took
 # on the device (a fragment's rows a scan of it, candidates x slices
 # in the batched program); topnCandidates the ids TopN's phase 1 gave
-# its exact re-query; topnKept the pairs a TopN call returned.
+# its exact re-query; topnKept the pairs a TopN call returned;
+# topnRecountsSkipped is 1 for a TopN over one slice, answered from
+# phase 1 alone (its pairs are the totals: executor._execute_topn).
 KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "cacheMisses", "deviceTransfers", "deviceTransferBytes",
         "fanoutCalls", "fanoutRetries", "planMs", "planCacheHit",
         "containerBlocksDense", "containerBlocksArray",
         "containerBlocksRun", "stackBuilds", "oomFallbacks",
         "leafMemoHits", "leafMemoMisses", "topnRowsScanned",
-        "topnCandidates", "topnKept")
+        "topnCandidates", "topnKept", "topnRecountsSkipped")
 
 
 class QueryStats:

@@ -1,7 +1,9 @@
 """TopN on the served path (PR 26): the batched re-query costs the
 same for one candidate and for fifty, so the path model's one entry a
 shape holds, and a ``?profile=true`` TopN answer carries the phases'
-spans, tags and counters."""
+spans, tags and counters. Over one slice phase 1's pairs are the
+answer and the re-query is skipped (PR 27): every form equals the
+two-phase result, and two slices still run both phases."""
 import json
 import threading
 import time
@@ -10,10 +12,12 @@ import urllib.request
 import numpy as np
 import pytest
 
-from pilosa_tpu import querystats, tracing
-from pilosa_tpu.executor import Executor
+from pilosa_tpu import SLICE_WIDTH, querystats, tracing
+from pilosa_tpu.executor import ExecOptions, Executor
 from pilosa_tpu.pql import parse
 from pilosa_tpu.server.server import Server
+from pilosa_tpu.storage.holder import Holder
+from pilosa_tpu.storage.index import FrameOptions
 
 TOPN = ('TopN(Bitmap(frame="f", rowID={p}), frame="f", n=50, '
         'tanimotoThreshold={t})')
@@ -129,7 +133,10 @@ def _post(s, path, body):
 
 
 @pytest.fixture
-def server(tmp_path):
+def server(tmp_path, request):
+    """Forty rows of sixty columns, rows 0..19 near copies of row 0;
+    ``indirect`` parametrisation gives the number of slices that hold
+    them (the same rows again, a slice further on)."""
     s = Server(str(tmp_path / "d"), bind="localhost:0").open()
     _post(s, "/index/i", "{}")
     _post(s, "/index/i/frame/f", "{}")
@@ -138,7 +145,9 @@ def server(tmp_path):
     for row in range(40):             # rows 0..19 near copies of row 0
         cols = (np.arange(60) if row < 20
                 else rng.choice(4096, 60, replace=False))
-        frame.import_bits([row] * 58, cols[rng.permutation(60)[:58]])
+        cols = cols[rng.permutation(60)[:58]]
+        for sl in range(getattr(request, "param", 1)):
+            frame.import_bits([row] * 58, cols + sl * SLICE_WIDTH)
     yield s
     s.close()
 
@@ -148,8 +157,9 @@ PHASE_SPANS = {"serial": {"top.src", "top.kernel", "top.wait", "top.fetch",
                "batched": {"kernel.dispatch", "kernel.wait", "kernel.fetch"}}
 
 
-@pytest.mark.parametrize("path", ["serial", "batched"])
-def test_a_profiled_topn_carries_phases_spans_and_counters(server, path):
+def _profiled(server, path):
+    """One profiled TopN under a pinned path: (pairs, spans, the two
+    phase spans by name, an ``under(span, phase)`` test, resources)."""
     server.executor._force_path = path
     doc = _post(server, "/index/i/query?profile=true", TOPN.format(p=0, t=70))
     pairs = doc["results"][0]
@@ -164,16 +174,25 @@ def test_a_profiled_topn_carries_phases_spans_and_counters(server, path):
         assert sp["parentId"] == call["spanId"]
     assert phases["topn.phase1"]["tags"] == {
         "path": path, "candidates": 0, "bucket": 1}
-    n = len(pairs)
-    assert phases["topn.phase2"]["tags"] == {
-        "path": path, "candidates": n,
-        "bucket": Executor._candidate_bucket(n)}
 
     def under(sp, phase):
         while sp is not None and sp is not phase:
             sp = by_id.get(sp["parentId"])
         return sp is phase
 
+    return pairs, spans, phases, under, doc["profile"]["resources"]
+
+
+@pytest.mark.parametrize("server", [2], indirect=True)
+@pytest.mark.parametrize("path", ["serial", "batched"])
+def test_a_profiled_topn_carries_phases_spans_and_counters(server, path):
+    """Two slices: both phases run, each tagged with the path that
+    served it."""
+    pairs, spans, phases, under, res = _profiled(server, path)
+    n = len(pairs)
+    assert phases["topn.phase2"]["tags"] == {
+        "path": path, "candidates": n,
+        "bucket": Executor._candidate_bucket(n)}
     for phase in phases.values():
         names = {sp["name"] for sp in spans if under(sp, phase)}
         assert PHASE_SPANS[path] <= names, (phase["name"], names)
@@ -183,18 +202,45 @@ def test_a_profiled_topn_carries_phases_spans_and_counters(server, path):
     else:
         stacks = [sp for sp in spans if sp["name"] == "topn.stacks"]
         assert [sp["tags"]["candidates"] for sp in stacks] == [40, n]
-    res = doc["profile"]["resources"]
     assert res["topnCandidates"] == n and res["topnKept"] == n
-    # A scan reads every row of the fragment; the batched program the
+    assert res["topnRecountsSkipped"] == 0
+    # A scan reads every row of a fragment; the batched program the
     # candidates it was given, a slice each.
-    assert res["topnRowsScanned"] == (80 if path == "serial" else 40 + n)
+    assert res["topnRowsScanned"] == 2 * (80 if path == "serial"
+                                          else 40 + n)
     assert res["servedBy"] == {path: 2}
 
 
+@pytest.mark.parametrize("path", ["serial", "batched"])
+def test_a_profiled_one_slice_topn_skips_the_recount(server, path):
+    """One slice: phase 1's pairs are the answer. The ``topn.phase2``
+    span stays where the second pass would have been, tagged
+    ``skipped``, with nothing under it; a pinned path changes nothing
+    about that."""
+    pairs, spans, phases, under, res = _profiled(server, path)
+    n = len(pairs)
+    skipped = phases["topn.phase2"]
+    assert skipped["tags"] == {"path": "skipped", "candidates": n,
+                               "bucket": Executor._candidate_bucket(n)}
+    assert [sp["name"] for sp in spans if under(sp, skipped)] \
+        == ["topn.phase2"]
+    names = {sp["name"] for sp in spans
+             if under(sp, phases["topn.phase1"])}
+    assert PHASE_SPANS[path] <= names
+    stacks = [sp for sp in spans if sp["name"] == "topn.stacks"]
+    assert [sp["tags"]["candidates"] for sp in stacks] \
+        == ([40] if path == "batched" else [])
+    assert res["topnRecountsSkipped"] == 1 and res["topnCandidates"] == 0
+    assert res["topnKept"] == n and res["topnRowsScanned"] == 40
+    assert res["servedBy"] == {path: 1}
+
+
+@pytest.mark.parametrize("server", [1, 2], indirect=True)
 def test_unprofiled_topn_pays_for_none_of_it(server, monkeypatch):
     """With no trace active every span of the TopN path is the shared
     no-op, no counter is kept, and the fragment's call stays the one
-    expression (no split into wait and fetch)."""
+    expression (no split into wait and fetch): whether the second
+    phase runs (two slices) or is skipped (one)."""
     made, split = [], []
     real_span, real_init = tracing.span, tracing.Span.__init__
 
@@ -245,3 +291,197 @@ def test_the_selection_memos_follow_rows_and_cache(tmp_path):
         assert f._cache_mask[2] is not mask
     finally:
         f.close()
+
+
+# ------------------------- one slice: phase 1's pairs are the answer
+
+ROWS = 64          # row d of frame "f" holds columns 0..d-1
+PROBE = 20         # so against the probe: inter min(d, 20), denom max(d, 20)
+SRC = f'Bitmap(frame="f", rowID={PROBE})'
+INV_SRC = 'Bitmap(frame="inv", columnID=5)'
+
+
+@pytest.fixture(scope="module")
+def one_slice(tmp_path_factory):
+    """(executor, {frame: {row: columns}}, {row: cat}): prefix rows
+    1..64, rows 100 and 101 copies of row 64 (a count tie at the top
+    of a src-less ranking), row 200 a copy of row 20 (the probe's own
+    twin), an attribute on every third row, and an inverse-enabled
+    frame whose inverse view has rows 0..63."""
+    h = Holder(str(tmp_path_factory.mktemp("one_slice") / "d")).open()
+    idx = h.create_index("i")
+    idx.create_frame("f")
+    idx.create_frame("inv", FrameOptions(inverse_enabled=True))
+    rows = {d: set(range(d)) for d in range(1, ROWS + 1)}
+    rows.update({100: set(range(64)), 101: set(range(64)),
+                 200: set(range(PROBE))})
+    for name in ("f", "inv"):
+        idx.frame(name).import_bits(
+            [r for r, cs in rows.items() for _ in cs],
+            [c for cs in rows.values() for c in cs])
+    ex = Executor(h)
+    cats = {}
+    for r in rows:
+        if r % 3 == 0:
+            cats[r] = "x" if r % 2 else "y"
+            ex.execute("i", f'SetRowAttrs(frame="f", rowID={r}, '
+                            f'cat="{cats[r]}")')
+    inverse = {}
+    for r, cs in rows.items():
+        for c in cs:
+            inverse.setdefault(c, set()).add(r)
+    assert idx.max_slice() == 0 and idx.max_inverse_slice() == 0
+    yield ex, {"f": rows, "inv": inverse}, cats
+    h.close()
+
+
+def brute_topn(rows, src=None, n=0, tanimoto=0, threshold=0, allowed=None):
+    """TopN's semantics on Python sets: exact counts, the integer
+    Tanimoto gate, ``threshold``, the attribute filter, ``(-count,
+    id)`` order, cut at ``n``."""
+    pairs = []
+    for rid, cs in rows.items():
+        cnt = len(cs & src) if src is not None else len(cs)
+        if cnt < max(threshold, 1):
+            continue
+        if allowed is not None and rid not in allowed:
+            continue
+        if tanimoto and src is not None and not (
+                100 * cnt > tanimoto * (len(cs) + len(src) - cnt)):
+            continue
+        pairs.append((rid, cnt))
+    pairs.sort(key=lambda rc: (-rc[1], rc[0]))
+    return pairs[:n] if n else pairs
+
+
+# (id, PQL with {ids} where an ``ids=[...], `` argument goes, n, the
+# brute-force arguments, a row that must NOT be in the answer though it
+# lies exactly on the gate, the ids of the count tie the cut at n splits)
+FORMS = [
+    ("tanimoto50", f'TopN({SRC}, frame="f", {{ids}}n=10, '
+                   'tanimotoThreshold=50)',
+     10, dict(src=PROBE, tanimoto=50), (10, 40), range(20, 40)),
+    ("tanimoto70", f'TopN({SRC}, frame="f", {{ids}}n=5, '
+                   'tanimotoThreshold=70)',
+     5, dict(src=PROBE, tanimoto=70), (14,), range(20, 29)),
+    ("tanimoto90", f'TopN({SRC}, frame="f", {{ids}}n=2, '
+                   'tanimotoThreshold=90)',
+     2, dict(src=PROBE, tanimoto=90), (18,), (20, 21, 22, 200)),
+    ("src", f'TopN({SRC}, frame="f", {{ids}}n=7)',
+     7, dict(src=PROBE), (), range(20, 65)),
+    ("srcless", 'TopN(frame="f", {ids}n=2)', 2, dict(), (), (64, 100, 101)),
+    ("threshold", 'TopN(frame="f", {ids}n=4, threshold=60)',
+     4, dict(threshold=60), (59,), ()),
+    ("src_threshold", f'TopN({SRC}, frame="f", {{ids}}n=40, threshold=18)',
+     40, dict(src=PROBE, threshold=18), (17,), ()),
+    ("filters", f'TopN({SRC}, frame="f", {{ids}}n=6, field="cat", '
+                'filters=["x"])',
+     6, dict(src=PROBE, allowed="x"), (24,), (21, 27, 33, 39, 45, 51, 57)),
+    ("n0", f'TopN({SRC}, frame="f", {{ids}}tanimotoThreshold=50)',
+     0, dict(src=PROBE, tanimoto=50), (10, 40), ()),
+    ("inverse", 'TopN(frame="inv", {ids}n=3, inverse=true)',
+     3, dict(frame="inv"), (), ()),
+    ("inverse_src", f'TopN({INV_SRC}, frame="inv", {{ids}}n=4, '
+                    'inverse=true)',
+     4, dict(frame="inv", src="inv5"), (), range(6)),
+    ("empty", f'TopN({SRC}, frame="f", {{ids}}n=5, tanimotoThreshold=100, '
+              'threshold=21)',
+     5, dict(src=PROBE, tanimoto=100, threshold=21), (20, 200), ()),
+    ("empty_probe", 'TopN(Bitmap(frame="f", rowID=999), frame="f", '
+                    '{ids}n=5)',
+     5, dict(src=999), (), ()),
+]
+
+
+@pytest.mark.parametrize("path", [None, "serial", "batched"])
+@pytest.mark.parametrize("name, pql, n, ref, on_gate, tie", FORMS,
+                         ids=[f[0] for f in FORMS])
+def test_one_slice_answers_equal_the_two_phase_result(
+        one_slice, name, pql, n, ref, on_gate, tie, path):
+    """Every form TopN takes, on one slice: the answer (phase 1's
+    pairs) equals the brute-force list, and equals what the same call
+    followed by an explicit ``ids=`` re-query of its ids gives (the
+    two-phase result, which ran until PR 27), as lists."""
+    ex, data, cats = one_slice
+    ex._force_path = path
+    ref = dict(ref)
+    rows = data[ref.pop("frame", "f")]
+    if "src" in ref:
+        # A probe row of "f"; or, on the inverse view, its row 5 (the
+        # standard rows that hold column 5).
+        ref["src"] = (data["inv"][5] if ref["src"] == "inv5"
+                      else data["f"].get(ref["src"], set()))
+    if "allowed" in ref:
+        ref["allowed"] = {r for r, c in cats.items() if c == ref["allowed"]}
+    want = brute_topn(rows, n=n, **ref)
+
+    with querystats.scope(querystats.QueryStats()) as qs:
+        got = ex.execute("i", pql.format(ids=""))[0]
+        stats = qs.to_dict()
+    assert got == want
+    assert stats["topnCandidates"] == 0
+    assert stats["topnRecountsSkipped"] == (1 if want else 0)
+    assert stats["topnKept"] == len(want)
+
+    ids = sorted(rid for rid, _ in got)
+    two_phase = []
+    if ids:
+        two_phase = ex.execute("i", pql.format(ids=f"ids={ids}, "))[0]
+        two_phase = two_phase[:n] if n else two_phase
+    assert got == two_phase
+
+    # What the case is there for: rows exactly on the gate are out, the
+    # cut at n falls inside a count tie, the empty cases are empty.
+    got_ids = [rid for rid, _ in got]
+    assert not set(on_gate) & set(got_ids)
+    if tie:
+        kept = set(tie) & set(got_ids)
+        assert kept and kept != set(tie)
+        assert got_ids[-1] == sorted(tie)[len(kept) - 1]
+    assert (got == []) == name.startswith("empty")
+
+
+def test_two_slices_keep_the_exact_requery(tmp_path):
+    """The condition is the slice count: over two slices a row cut from
+    one slice's top ``n`` comes back with its whole total, which only
+    phase 2 can know. Row 2 is fourth of slice 0 (cut at n=3) and
+    second of slice 1: 1 + 3 puts it ahead of row 0's 3 + 0."""
+    h = Holder(str(tmp_path / "d")).open()
+    try:
+        h.create_index("i").create_frame("f")
+        frame = h.index("i").frame("f")
+        w = SLICE_WIDTH
+        frame.import_bits([9] * 8, [0, 1, 2, 3, w, w + 1, w + 2, w + 3])
+        frame.import_bits([0] * 3, [0, 1, 2])
+        frame.import_bits([1] * 2, [0, 1])
+        frame.import_bits([2] * 4, [0, w, w + 1, w + 2])
+        ex = Executor(h)
+        q = 'TopN(Bitmap(frame="f", rowID=9), frame="f", n=3)'
+        for path in ("serial", "batched", None):
+            ex._force_path = path
+            with querystats.scope(querystats.QueryStats()) as qs:
+                got = ex.execute("i", q)[0]
+                stats = qs.to_dict()
+            assert got == [(9, 8), (2, 4), (0, 3)], path
+            assert stats["topnRecountsSkipped"] == 0
+            # slice 0 gave {9, 0, 1}, slice 1 {9, 2}
+            assert stats["topnCandidates"] == 4
+        # Phase 1 alone has row 2 short (3 of its 4): what a skip here
+        # would have answered.
+        ex._force_path = "serial"
+        short = ex.execute("i", q, opt=ExecOptions(remote=True))[0]
+        assert dict(short)[2] == 3 and dict(short)[1] == 2
+        # The slice list is the one of the view the call reads: the
+        # same index's inverse view has one slice (rows 3 and 4 as its
+        # columns), so an inverse TopN is answered by phase 1.
+        h.index("i").create_frame("inv", FrameOptions(inverse_enabled=True))
+        h.index("i").frame("inv").import_bits([3, 3, 4], [0, w + 7, w + 7])
+        for pql, want, skipped in (
+                ('TopN(frame="inv", n=2, inverse=true)',
+                 [(w + 7, 2), (0, 1)], 1),
+                ('TopN(frame="inv", n=2)', [(3, 2), (4, 1)], 0)):
+            with querystats.scope(querystats.QueryStats()) as qs:
+                assert ex.execute("i", pql)[0] == want
+                assert qs.to_dict()["topnRecountsSkipped"] == skipped
+    finally:
+        h.close()
