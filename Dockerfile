@@ -7,7 +7,7 @@ RUN apt-get update && apt-get install -y --no-install-recommends g++ \
 
 WORKDIR /pilosa-tpu
 COPY pilosa_tpu ./pilosa_tpu
-COPY bench.py chip_smoke.py Makefile ./
+COPY chip_smoke.py Makefile ./
 
 RUN pip install --no-cache-dir numpy jax \
     && make native

@@ -65,11 +65,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 from pilosa_tpu import SLICE_WIDTH  # noqa: E402
 from pilosa_tpu.testing import free_ports  # noqa: E402
 
-try:
-    from benchmarks import _ledger  # noqa: E402
-except ImportError:  # pragma: no cover — ledger is best-effort
-    _ledger = None
-
 PROBE_TTL = "0.4"          # children's PILOSA_EPOCH_PROBE_TTL
 COUNT_Q = 'Count(Bitmap(frame="f", rowID=1))'
 # p99 ratios never divide by a sub-jitter baseline: loopback HTTP on a
@@ -161,12 +156,6 @@ class HedgeTail:
     def metric(self, name, value, unit):
         print(json.dumps({"metric": name, "value": value, "unit": unit}),
               flush=True)
-        if _ledger is not None:
-            _ledger.record("hedge_tail", name, value, unit,
-                           knobs={"slices": self.opts.slices,
-                                  "delay": self.opts.delay,
-                                  "hedge_delay_ms":
-                                      self.opts.hedge_delay_ms})
 
     def boot(self, label, routing):
         hedge_env = {

@@ -26,11 +26,28 @@ _lib = None
 _tried = False
 
 
+def _own(path, suffix):
+    """A sibling of ``path`` that only this call writes. Processes
+    that meet a tree with no library at the same moment (xdist workers,
+    the worker tier's first boot) each compile into a file of their own
+    and install it by rename, so ``path`` is only ever absent or
+    whole. The random part covers containers that share a checkout
+    and a pid."""
+    return f"{path}.{os.getpid()}.{os.urandom(4).hex()}{suffix}"
+
+
+def _unlink(path):
+    try:
+        os.unlink(path)
+    except OSError:
+        pass
+
+
 def build(out=_SO):
     """Compile roaring.cpp into ``out``; raise RuntimeError with the
     compiler's own words when g++ is missing or refuses. Installed by
     rename, so a process that has the old object mapped keeps it."""
-    tmp = out + ".tmp"
+    tmp = _own(out, ".tmp")
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
            "-o", tmp, _SRC]
     try:
@@ -39,6 +56,7 @@ def build(out=_SO):
     except FileNotFoundError as exc:
         raise RuntimeError(f"native build impossible: {exc}") from exc
     except subprocess.CalledProcessError as exc:
+        _unlink(tmp)  # whatever the compiler got as far as writing
         raise RuntimeError(
             f"native build failed (rc={exc.returncode}): "
             f"{exc.stderr.strip()[-2000:]}") from exc
@@ -67,17 +85,14 @@ def load():
             # dlopen dedups by path against the stale handle already
             # mapped above, so the rebuild must load from a fresh
             # path; the fresh build also replaces _SO for next time.
-            rebuilt = _SO + ".rebuild.so"
+            rebuilt = _own(_SO, ".rebuild.so")
             try:
                 build(rebuilt)
                 lib = ctypes.CDLL(rebuilt)
                 lib.pn_serialize_w
                 os.replace(rebuilt, _SO)
             except (OSError, RuntimeError, AttributeError) as exc:
-                try:
-                    os.unlink(rebuilt)
-                except OSError:
-                    pass
+                _unlink(rebuilt)
                 return _unavailable(exc)
         except (OSError, RuntimeError) as exc:
             return _unavailable(exc)

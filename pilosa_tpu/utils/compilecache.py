@@ -2,10 +2,9 @@
 
 Every entry point that initializes JAX (``Server``, and through it the
 CLI's ``server`` command; the worker executor; ``chip_smoke.py``'s
-kernels child; ``bench.py``; ``benchmarks/*``) calls :func:`enable`
-before its first jit, so all processes of a deployment share one
-on-disk cache and a restarted server skips the compiles its
-predecessor already paid.
+kernels child) calls :func:`enable` before its first jit, so all
+processes of a deployment share one on-disk cache and a restarted
+server skips the compiles its predecessor already paid.
 
 The directory is part of the cache key, so it must not move between
 runs: ``JAX_COMPILATION_CACHE_DIR`` (which JAX reads itself) wins when

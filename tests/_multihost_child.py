@@ -6,7 +6,8 @@ virtual CPU devices, stages ONLY the slice rows it owns
 runs the sharded Count(Intersect) kernel — the cross-host path of
 parallel/distributed.py that single-process tests cannot reach.
 
-Spawned by tests/test_multihost.py; prints "COUNT <n>" on success.
+Spawned by tests/test_multihost.py; writes the line "COUNT <n>" on
+success.
 Exits 77 (the autotools skip convention) when the pinned jaxlib's CPU
 backend refuses multiprocess computations at this topology — a
 platform capability gap, not a code failure; the parent skips.
@@ -96,7 +97,12 @@ def main():
     assert count2 == expect, (count2, expect)
     assert eng2.replicas_consistent(rows2)  # cross-host all_gather
 
-    print(f"COUNT {count}")
+    # One write, starting a line of its own: Gloo reports every
+    # context it connects ("[Gloo] Rank 0 is connected to ...") on this
+    # same stdout, from the device threads and a piece at a time, and
+    # print() under PYTHONUNBUFFERED is two writes. A pipe keeps one
+    # small write whole.
+    os.write(1, f"\nCOUNT {count}\n".encode())
 
 
 if __name__ == "__main__":

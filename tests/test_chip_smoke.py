@@ -1,8 +1,8 @@
 """Bring-up surfaces (ISSUE 21): chip_smoke.py's rehearsal and refusal
 paths, the compile-cache helper, the device block, the visible
-``batched:error`` hop, the strict native build, one process per chip,
-and bench.py's refusal to time a CPU. The chip side of all of this is
-``chip_smoke.py`` itself, run through the chip tool."""
+``batched:error`` hop, the strict native build and one process per
+chip. The chip side of all of this is ``chip_smoke.py`` itself, run
+through the chip tool."""
 import json
 import os
 import subprocess
@@ -307,19 +307,3 @@ def test_worker_children_are_pinned_to_the_host_backend(monkeypatch):
     assert len(seen) == 2
     assert all(e["JAX_PLATFORMS"] == "cpu" for e in seen)
     assert not any("PILOSA_TPU_PLATFORM" in e for e in seen)
-
-
-def test_bench_refuses_to_time_a_cpu():
-    r = _run([os.path.join(ROOT, "bench.py")])
-    assert r.returncode != 0
-    assert r.stdout == ""
-    assert "no accelerator" in r.stderr
-
-
-def test_ledger_default_is_not_the_drivers_file(monkeypatch):
-    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
-    import _ledger
-
-    monkeypatch.delenv("PILOSA_PERF_LEDGER", raising=False)
-    assert _ledger.ledger_path() == os.path.join(
-        ROOT, "benchmarks", "ledger.jsonl")

@@ -1,6 +1,15 @@
 """Native C++ runtime parity tests: the ctypes-loaded codec/hashing must
 be bit-identical to the pure-Python implementations, and every consumer
-must work with the native layer force-disabled (fallback coverage)."""
+must work with the native layer force-disabled (fallback coverage).
+The loader itself is tested under concurrent first use, on a copy."""
+import ctypes
+import os
+import shutil
+import subprocess
+import sys
+import textwrap
+import time
+
 import numpy as np
 import pytest
 
@@ -8,8 +17,20 @@ from pilosa_tpu import native
 from pilosa_tpu.roaring import codec
 from pilosa_tpu.utils.xxhash import _xxhash64_py, xxhash64
 
-pytestmark = pytest.mark.skipif(not native.available(),
-                                reason="native toolchain unavailable")
+pytestmark = pytest.mark.skipif(shutil.which("g++") is None,
+                                reason="no g++ on this host")
+
+
+@pytest.fixture(autouse=True)
+def _library_loaded():
+    """With a compiler on the host the library must load: a module
+    that skipped itself when it did not is how seventeen tests once
+    vanished from a clean checkout's count. Fail with the reason."""
+    if not native.available():
+        native.build()            # raises with the compiler's words
+        ctypes.CDLL(native._SO)   # or with the dynamic loader's
+        pytest.fail("the library builds and loads now, but load() gave "
+                    "up earlier in this process: see its warning")
 
 
 def test_xxhash_parity(rng):
@@ -188,3 +209,90 @@ def test_scatter_or_wrong_dtype_falls_back():
     assert not native.scatter_or(m32, np.array([0]),
                                  np.array([0], dtype=np.uint64))
     assert native.popcount_rows(m32, [0]) is None
+
+
+# ------------------------------------------------ the loader, concurrently
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# One first user of a copied loader: import, wait for the common start,
+# load twice (the second must be the cached answer, and silent).
+_FIRST_USER = textwrap.dedent("""
+    import logging, sys, time
+    logging.basicConfig(stream=sys.stderr, format="LOG %(message)s")
+    sys.path[:0] = [sys.argv[1], sys.argv[2]]
+    import native_copy
+    time.sleep(max(0.0, float(sys.argv[3]) - time.monotonic()))
+    print(native_copy.available(), native_copy.available(),
+          native_copy.xxhash64(b"one buffer", 7))
+""")
+
+# Stands in for g++ on PATH: gets as far as writing its output file,
+# then fails, like a compiler that is killed or runs out of disk.
+_BROKEN_GXX = textwrap.dedent("""\
+    #!/bin/sh
+    while [ $# -gt 0 ]; do
+        [ "$1" = -o ] && echo half > "$2"
+        shift
+    done
+    echo "stub: no space left on device" >&2
+    exit 1
+""")
+
+
+@pytest.mark.parametrize("case", ["cold", "stale", "broken-compiler"])
+def test_concurrent_first_use(tmp_path, case):
+    """Six processes meet a copy of pilosa_tpu/native/ at once. With no
+    library (cold), or one from before pn_serialize_w (stale: all six
+    take the rebuild branch), every one of them ends up serving from
+    the native library and nothing but it is left beside the source.
+    With a compiler that fails, every one logs once and falls back,
+    and neither a library nor a temporary is left."""
+    pkg = tmp_path / "native_copy"
+    pkg.mkdir()
+    for name in ("__init__.py", "roaring.cpp"):
+        shutil.copy2(os.path.join(os.path.dirname(native.__file__), name),
+                     pkg / name)
+    env = dict(os.environ)
+    if case == "stale":
+        so = pkg / "libpilosa_native.so"
+        old = tmp_path / "old.cpp"
+        old.write_text('extern "C" long pn_popcount() { return 0; }\n')
+        subprocess.run(["g++", "-shared", "-fPIC", "-o", str(so), str(old)],
+                       check=True)
+        assert so.stat().st_mtime >= (pkg / "roaring.cpp").stat().st_mtime
+    elif case == "broken-compiler":
+        stub = tmp_path / "bin" / "g++"
+        stub.parent.mkdir()
+        stub.write_text(_BROKEN_GXX)
+        stub.chmod(0o755)
+        env["PATH"] = f"{stub.parent}{os.pathsep}{env['PATH']}"
+
+    start = time.monotonic() + 1.0  # one clock for every process
+    procs = [subprocess.Popen(
+        [sys.executable, "-c", _FIRST_USER, str(tmp_path), _REPO,
+         repr(start)], env=env, text=True,
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE) for _ in range(6)]
+    try:
+        outs = [p.communicate(timeout=120) + (p.returncode,) for p in procs]
+    finally:
+        for p in procs:
+            if p.poll() is None:
+                p.kill()
+
+    left = sorted(p.name for p in pkg.iterdir() if p.name != "__pycache__")
+    for out, err, rc in outs:
+        assert rc == 0, err
+        warned = err.count("LOG native runtime unavailable")
+        if case == "broken-compiler":
+            assert out.split() == ["False", "False", "None"], (out, err)
+            assert warned == 1 and "no space left" in err, err
+        else:
+            assert out.split() == ["True", "True",
+                                   str(_xxhash64_py(b"one buffer", 7))], \
+                (out, err)
+            assert warned == 0, err
+    want = ["__init__.py", "roaring.cpp"]
+    if case != "broken-compiler":
+        want.insert(1, "libpilosa_native.so")
+    assert left == want

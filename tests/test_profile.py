@@ -1,10 +1,8 @@
-"""Continuous profiler, analytic device cost attribution, and the
-perf-regression ledger (PR 19): trie bounds + two-generation decay
-under fake clocks, folded-format golden, subsystem classification,
-the NOP single-attribute-read contract through tracing._finish, XLA
-cost_analysis capture/fold on the CPU backend, ledger schema
-round-trips, and perfwatch catching an injected regression while
-staying green (and deterministic) on a stable ledger."""
+"""Continuous profiler and analytic device cost attribution (PR 19):
+trie bounds + two-generation decay under fake clocks, folded-format
+golden, subsystem classification, the NOP single-attribute-read
+contract through tracing._finish, and XLA cost_analysis capture/fold
+on the CPU backend."""
 import json
 import os
 import sys
@@ -17,12 +15,6 @@ from pilosa_tpu.observe import kerneltime as kt
 from pilosa_tpu.observe import profiler as profiler_mod
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-for p in (ROOT, os.path.join(ROOT, "benchmarks")):
-    if p not in sys.path:
-        sys.path.insert(0, p)
-
-import _ledger  # noqa: E402 — benchmarks/_ledger.py (path above)
-from tools import perfwatch  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -272,90 +264,6 @@ def test_kernel_snapshot_carries_analytic():
     assert row["analyticBytes"] == 5.0
     assert row["arithmeticIntensity"] == 2.0
     assert snap["analytic"]["captured"] == 1
-
-
-# ------------------------------------------------------------- ledger
-
-
-def test_ledger_round_trip(tmp_path, monkeypatch):
-    path = str(tmp_path / "ledger.jsonl")
-    monkeypatch.setenv("PILOSA_PERF_LEDGER", path)
-    assert _ledger.ledger_path() == path
-    row = _ledger.record("b1", "warm_qps", 120.5, "q/s",
-                         knobs={"slices": 8})
-    assert row is not None and _ledger.validate_row(row) == []
-    n = _ledger.record_rows("b1", [
-        {"metric": "p99_ms", "value": 3.5, "unit": "ms"},
-        {"bad": "row"},
-        {"metric": "x", "value": 1, "unit": "u"}])
-    assert n == 2
-    rows, skipped = _ledger.read_rows()
-    assert skipped == 0
-    assert [r["metric"] for r in rows] == ["warm_qps", "p99_ms", "x"]
-    assert rows[0]["value"] == 120.5
-    assert rows[0]["knobs"] == {"slices": 8}
-    assert rows[0]["bench"] == "b1"
-    assert "t" in rows[0] and "backend" in rows[0]
-
-
-def test_ledger_skips_invalid_rows(tmp_path):
-    path = str(tmp_path / "ledger.jsonl")
-    good = _ledger.make_row("b", "m", 1.0, "u", backend="cpu")
-    with open(path, "w") as f:
-        f.write(json.dumps(good) + "\n")
-        f.write("not json\n")
-        f.write(json.dumps({"t": "x", "bench": "b"}) + "\n")  # missing
-        f.write(json.dumps(dict(good, value="high")) + "\n")  # type
-        f.write(json.dumps(dict(good, extra=1)) + "\n")       # unknown
-    rows, skipped = _ledger.read_rows(path)
-    assert len(rows) == 1 and skipped == 4
-
-
-def _write_series(path, values, metric="warm_qps", unit="q/s"):
-    with open(path, "a") as f:
-        for v in values:
-            f.write(json.dumps(_ledger.make_row(
-                "benchx", metric, v, unit, backend="cpu",
-                commit="abc1234")) + "\n")
-
-
-def test_perfwatch_catches_injected_regression(tmp_path, capsys):
-    path = str(tmp_path / "ledger.jsonl")
-    _write_series(path, [100.0, 101.0, 99.0, 100.0, 60.0])
-    assert perfwatch.main([path]) == 1
-    out = capsys.readouterr().out
-    assert "REGRESSION" in out and "benchx/warm_qps[cpu]" in out
-
-
-def test_perfwatch_green_and_deterministic(tmp_path, capsys):
-    path = str(tmp_path / "ledger.jsonl")
-    _write_series(path, [100.0, 101.0, 99.0, 100.0, 98.0])
-    assert perfwatch.main([path]) == 0
-    # Unmodified re-run stays green (deterministic by construction).
-    assert perfwatch.main([path]) == 0
-    out = capsys.readouterr().out
-    assert "perfwatch: ok" in out
-
-
-def test_perfwatch_direction_and_baseline_rules(tmp_path, capsys):
-    path = str(tmp_path / "ledger.jsonl")
-    # Latency regresses UPWARD: a big drop must NOT flag.
-    _write_series(path, [10.0, 10.5, 9.8, 10.1, 2.0],
-                  metric="p99_ms", unit="ms")
-    assert perfwatch.main([path]) == 0
-    # ... and a big rise must flag.
-    _write_series(path, [30.0], metric="p99_ms", unit="ms")
-    assert perfwatch.main([path]) == 1
-    # Too little history never gates.
-    path2 = str(tmp_path / "ledger2.jsonl")
-    _write_series(path2, [100.0, 10.0])
-    assert perfwatch.main([path2]) == 0
-    out = capsys.readouterr().out
-    assert "no baseline yet" in out
-
-
-def test_perfwatch_empty_ledger_ok(tmp_path):
-    assert perfwatch.main([str(tmp_path / "absent.jsonl")]) == 0
 
 
 # ------------------------------------------------- the device capture

@@ -6,7 +6,6 @@ answer and the re-query is skipped (PR 27): every form equals the
 two-phase result, and two slices still run both phases."""
 import json
 import threading
-import time
 import urllib.request
 
 import numpy as np

@@ -1,8 +1,8 @@
 """Flight-recorder + replica-vitals smoke (PR 16), wired into
 ``make test`` as ``make eventcheck``.
 
-Phase 1 (surfaces, HTTP): boot a real-socket 2-node cluster with the
-recorder and vitals on, and assert the surfaces are genuinely live:
+Boot a real-socket 2-node cluster with the recorder and vitals on,
+and assert over HTTP that the surfaces are genuinely live:
 
 - each node's ``/debug/events`` journals its own boot and the control
   transitions driven here (a full breaker open→half-open→close cycle
@@ -16,16 +16,13 @@ recorder and vitals on, and assert the surfaces are genuinely live:
 - the full ``/metrics`` exposition (``pilosa_events_total``,
   ``pilosa_replica_*`` included) passes promlint.
 
-Phase 2 (overhead, in-process dispatch): warm serving-path QPS with
-recorder+vitals ON must be within 2% of the SAME measurement with
-them OFF — the instrumentation-creep gate, obscheck's paired
-interleaved-A/B method (median-of-round ratios, noisy-box retries).
+What recorder and vitals cost a request is not measured here: a
+timing from this sandbox's CPU backend is not a speed (see PERF.md).
 
 Small and CPU-only by design.
 """
 import json
 import os
-import statistics
 import sys
 import tempfile
 import time
@@ -35,10 +32,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 from pilosa_tpu import SLICE_WIDTH  # noqa: E402
-
-OVERHEAD_BAR = 0.02          # on-QPS may lag off-QPS by at most 2%
-ROUNDS = 7                   # A/B rounds per arm (median taken)
-ATTEMPTS = 3                 # noisy-box retries before failing
 
 
 def post(base, path, body):
@@ -194,122 +187,10 @@ def phase_surfaces(fails):
                 s.close()
 
 
-def _build_serving(tmp):
-    """Warm single-node serving path (handler dispatch, no sockets)
-    sized so a warm query costs enough for a 2% delta to be
-    measurable above timer noise."""
-    import numpy as np
-
-    from pilosa_tpu.executor import Executor
-    from pilosa_tpu.server.handler import Handler
-    from pilosa_tpu.storage.holder import Holder
-
-    holder = Holder(os.path.join(tmp, "ov")).open()
-    idx = holder.create_index("ov")
-    idx.create_frame("d")
-    rng = np.random.default_rng(3)
-    for s in range(8):
-        b = s * SLICE_WIDTH
-        for rid in range(1, 9):
-            cols = rng.choice(50_000, size=2000, replace=False)
-            idx.frame("d").import_bits([rid] * len(cols),
-                                       (b + cols).tolist())
-    e = Executor(holder)
-    e._force_path = "batched"
-    e._result_memo_off = True  # every query must reach the engine
-    return holder, Handler(holder, e)
-
-
-def _qps(handler, queries, seconds=0.6):
-    t_end = time.perf_counter() + seconds
-    n = 0
-    while time.perf_counter() < t_end:
-        status, _, _ = handler.dispatch(
-            "POST", "/index/ov/query", {},
-            queries[n % len(queries)], {})[:3]
-        if status != 200:
-            raise RuntimeError(f"query failed: HTTP {status}")
-        n += 1
-    return n / seconds
-
-
-def _measure(handler, holder, queries, seconds=0.6):
-    """Median warm QPS for recorder+vitals ON and OFF, interleaved
-    with alternating arm order per round; paired per-round ratios
-    cancel slow thermal/GC drift."""
-    from pilosa_tpu.observe import events as events_mod
-    from pilosa_tpu.observe import replica as replica_mod
-
-    rec = events_mod.EventRecorder(host="ov")
-    vt = replica_mod.ReplicaVitals()
-
-    def run_on():
-        handler.events = rec
-        handler.vitals = vt
-        holder.events = rec
-        holder.governor.events = rec
-        return _qps(handler, queries, seconds)
-
-    def run_off():
-        handler.events = events_mod.NOP
-        handler.vitals = replica_mod.NOP
-        holder.events = None
-        holder.governor.events = None
-        return _qps(handler, queries, seconds)
-
-    on, off, ratios = [], [], []
-    for i in range(ROUNDS):
-        if i % 2:
-            a = run_on()
-            b = run_off()
-        else:
-            b = run_off()
-            a = run_on()
-        on.append(a)
-        off.append(b)
-        ratios.append(a / b)
-    return (statistics.median(on), statistics.median(off),
-            statistics.median(ratios))
-
-
-def phase_overhead(fails):
-    with tempfile.TemporaryDirectory(prefix="eventcheck-ov-") as tmp:
-        holder, handler = _build_serving(tmp)
-        try:
-            queries = [
-                (f'Count(Intersect(Bitmap(frame="d", rowID={a}), '
-                 f'Bitmap(frame="d", rowID={b})))').encode()
-                for a in range(1, 9) for b in range(a + 1, 9)]
-            # Warm plan/compile tiers before any timed round.
-            for q in queries:
-                handler.dispatch("POST", "/index/ov/query", {}, q, {})
-                handler.dispatch("POST", "/index/ov/query", {}, q, {})
-            best = on_qps = off_qps = None
-            for attempt in range(ATTEMPTS):
-                on_qps, off_qps, ratio = _measure(handler, holder,
-                                                  queries)
-                best = max(best or 0.0, ratio)
-                if ratio >= 1.0 - OVERHEAD_BAR:
-                    break
-            print(f"  serving: warm on={on_qps:,.0f} q/s "
-                  f"off={off_qps:,.0f} q/s "
-                  f"overhead={100 * (1 - best):.2f}% "
-                  f"(bar {100 * OVERHEAD_BAR:.0f}%)")
-            if best < 1.0 - OVERHEAD_BAR:
-                fails.append(
-                    f"recorder+vitals overhead {100 * (1 - best):.2f}% "
-                    f"exceeds {100 * OVERHEAD_BAR:.0f}% "
-                    f"(on={on_qps:.0f}, off={off_qps:.0f})")
-        finally:
-            holder.close()
-
-
 def main():
     fails = []
-    print("eventcheck phase 1: flight recorder + vitals (2-node live)")
+    print("eventcheck: flight recorder + vitals (2-node live)")
     phase_surfaces(fails)
-    print("eventcheck phase 2: serving-path overhead gate")
-    phase_overhead(fails)
     if fails:
         print("\neventcheck: FAIL")
         for f in fails:

@@ -22,18 +22,15 @@ Phase 2 (two nodes, in-process pod): the mesh-served and mesh-declined
 hop, then (after node b's plane unregisters) ``servedBy: http`` with a
 ``mesh:not_resident`` fallback hop, bit-exact across both.
 
-Phase 3 (overhead): warm engine Count QPS with the inspector's
-serving-path machinery (cost-model sampling + tier stamps) ON must be
-within 2% of OFF when explain is NOT requested — the same interleaved
-paired-A/B method as obscheck.
+What the inspector's serving-path machinery costs a request with
+explain off is not measured here: a timing from this sandbox's CPU
+backend is not a speed (see PERF.md).
 """
 import json
 import os
-import statistics
 import sys
 import tempfile
 import threading
-import time
 import urllib.request
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -49,8 +46,6 @@ if "xla_force_host_platform_device_count" not in flags:
 
 from pilosa_tpu import SLICE_WIDTH  # noqa: E402
 
-OVERHEAD_BAR = 0.02
-ROUNDS = 7
 ATTEMPTS = 3
 ERROR_FACTOR_BAR = 2.0
 
@@ -371,102 +366,12 @@ def phase_mesh_tiers():
                 s.close()
 
 
-def _build_engine(tmp):
-    import numpy as np
-
-    from pilosa_tpu.executor import Executor
-    from pilosa_tpu.storage.holder import Holder
-
-    holder = Holder(os.path.join(tmp, "ov")).open()
-    idx = holder.create_index("ov")
-    idx.create_frame("d")
-    rng = np.random.default_rng(3)
-    for s in range(16):
-        b = s * SLICE_WIDTH
-        for rid in range(1, 9):
-            cols = rng.choice(50_000, size=2000, replace=False)
-            idx.frame("d").import_bits([rid] * len(cols),
-                                       (b + cols).tolist())
-    e = Executor(holder)
-    e._force_path = "batched"
-    e._result_memo_off = True
-    return holder, e
-
-
-def _qps(e, queries, seconds=0.6):
-    t_end = time.perf_counter() + seconds
-    n = 0
-    while time.perf_counter() < t_end:
-        e.execute("ov", queries[n % len(queries)])
-        n += 1
-    return n / seconds
-
-
-def phase_overhead():
-    from pilosa_tpu.observe import costmodel as cm
-    from pilosa_tpu.observe import kerneltime as kt
-
-    with tempfile.TemporaryDirectory(prefix="explaincheck-ov-") as tmp:
-        holder, e = _build_engine(tmp)
-        try:
-            queries = [
-                (f'Count(Intersect(Bitmap(frame="d", rowID={a}), '
-                 f'Bitmap(frame="d", rowID={b})))')
-                for a in range(1, 9) for b in range(a + 1, 9)]
-            # The observatory runs in BOTH arms (its own overhead is
-            # obscheck's gate); only the inspector machinery differs.
-            kt.enable(sample_rate=4)
-            for q in queries:
-                e.execute("ov", q)
-                e.execute("ov", q)
-
-            def run_off():
-                cm.disable()
-                return _qps(e, queries)
-
-            def run_on():
-                cm.enable()
-                return _qps(e, queries)
-
-            best = None
-            for attempt in range(ATTEMPTS):
-                on, off, ratios = [], [], []
-                for i in range(ROUNDS):
-                    if i % 2:
-                        a = run_on()
-                        b = run_off()
-                    else:
-                        b = run_off()
-                        a = run_on()
-                    on.append(a)
-                    off.append(b)
-                    ratios.append(a / b)
-                ratio = statistics.median(ratios)
-                best = max(best or 0.0, ratio)
-                if ratio >= 1.0 - OVERHEAD_BAR:
-                    break
-            print(f"[explaincheck] warm engine on="
-                  f"{statistics.median(on):,.0f} q/s off="
-                  f"{statistics.median(off):,.0f} q/s overhead="
-                  f"{100 * (1 - best):.2f}% "
-                  f"(bar {100 * OVERHEAD_BAR:.0f}%)")
-            check(best >= 1.0 - OVERHEAD_BAR,
-                  f"inspector overhead {100 * (1 - best):.2f}% within "
-                  f"{100 * OVERHEAD_BAR:.0f}% with explain off")
-        finally:
-            cm.disable()
-            kt.disable()
-            holder.close()
-
-
 def main():
     print("explaincheck phase 1: single-node tiers + cost model "
           "(live server)")
     phase_single_node()
     print("explaincheck phase 2: mesh-served / mesh-declined tiers")
     phase_mesh_tiers()
-    print("explaincheck phase 3: warm-engine overhead gate")
-    phase_overhead()
     if FAILURES:
         print("\nexplaincheck: FAIL")
         for f in FAILURES:

@@ -20,19 +20,16 @@ zero container blocks — and a runtime-killed Intersect branch must
 leave its remaining siblings' containers unfetched (the ?profile=true
 block counters prove it).
 
-Phase 4 (overhead): warm QPS on ALREADY-OPTIMAL queries with the
-planner ON must be within 2% of OFF — the same interleaved paired-A/B
-method as obscheck/explaincheck.
-
-Phase 5 (exposition): /metrics promlint-clean both ways with the
+Phase 4 (exposition): /metrics promlint-clean both ways with the
 ``pilosa_plan_*`` planner families live.
+
+What planning costs an already-optimal query is not measured here: a
+timing from this sandbox's CPU backend is not a speed (see PERF.md).
 """
 import json
 import os
-import statistics
 import sys
 import tempfile
-import time
 import urllib.request
 from datetime import datetime
 
@@ -42,8 +39,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 from pilosa_tpu import SLICE_WIDTH  # noqa: E402
 
-OVERHEAD_BAR = 0.02
-ROUNDS = 7
 ATTEMPTS = 3
 N_SLICES = 4
 
@@ -286,86 +281,6 @@ def phase_short_circuit(base, server):
           f"(off={off_blocks} on={on_blocks})")
 
 
-def _build_engine(tmp):
-    from benchmarks import planner_ab as pab
-    from pilosa_tpu.executor import Executor
-    from pilosa_tpu.storage.holder import Holder
-
-    holder = Holder(os.path.join(tmp, "ov")).open()
-    pab.build(holder, 8)
-    e = Executor(holder)
-    e._result_memo_off = True
-    return holder, e
-
-
-def _qps(e, queries, seconds=0.5):
-    t_end = time.perf_counter() + seconds
-    n = 0
-    while time.perf_counter() < t_end:
-        e.execute("pa", queries[n % len(queries)])
-        n += 1
-    return n / seconds
-
-
-def phase_overhead():
-    with tempfile.TemporaryDirectory(prefix="plannercheck-ov-") as tmp:
-        holder, e = _build_engine(tmp)
-        pl = e.planner
-        try:
-            # Already-optimal query: smallest operand already first,
-            # two operands (nothing to reorder, no short-circuit gain
-            # possible — the final operand already reduces through the
-            # count-only kernel), so the planner's warm memo hit is
-            # PURE overhead. Deeper chains are excluded on purpose:
-            # their intermediates can genuinely short-circuit, and a
-            # win would mask the overhead this gate exists to bound.
-            queries = [
-                ('Count(Intersect(Bitmap(frame="f", rowID=8), '
-                 'Bitmap(frame="f", rowID=1)))'),
-            ]
-            for q in queries:
-                e.execute("pa", q)
-                e.execute("pa", q)
-
-            def run_on():
-                pl.set_config(enabled=True)
-                return _qps(e, queries)
-
-            def run_off():
-                pl.set_config(enabled=False)
-                return _qps(e, queries)
-
-            best = None
-            for _attempt in range(ATTEMPTS):
-                on, off, ratios = [], [], []
-                for i in range(ROUNDS):
-                    if i % 2:
-                        a = run_on()
-                        b = run_off()
-                    else:
-                        b = run_off()
-                        a = run_on()
-                    on.append(a)
-                    off.append(b)
-                    ratios.append(a / b)
-                ratio = statistics.median(ratios)
-                best = max(best or 0.0, ratio)
-                if ratio >= 1.0 - OVERHEAD_BAR:
-                    break
-            print(f"[plannercheck] already-optimal on="
-                  f"{statistics.median(on):,.0f} q/s off="
-                  f"{statistics.median(off):,.0f} q/s overhead="
-                  f"{100 * (1 - best):.2f}% "
-                  f"(bar {100 * OVERHEAD_BAR:.0f}%)")
-            check(best >= 1.0 - OVERHEAD_BAR,
-                  f"planning overhead {100 * (1 - best):.2f}% within "
-                  f"{100 * OVERHEAD_BAR:.0f}% on already-optimal "
-                  f"queries")
-        finally:
-            pl.set_config(enabled=True)
-            holder.close()
-
-
 def phase_metrics(base, server):
     from tools.promlint import lint_text
 
@@ -395,7 +310,8 @@ def phase_metrics(base, server):
 def main():
     from pilosa_tpu.server.server import Server
 
-    print("plannercheck phase 1-3,5: live server")
+    print("plannercheck phase 1: bit-exact planner on vs off "
+          "(live server)")
     with tempfile.TemporaryDirectory(prefix="plannercheck-") as tmp:
         server = Server(os.path.join(tmp, "d"), bind="127.0.0.1:0",
                         observe={"kernel-sample-rate": 4}).open()
@@ -412,12 +328,10 @@ def main():
             phase_explain(base, server)
             print("plannercheck phase 3: short-circuit counters")
             phase_short_circuit(base, server)
-            print("plannercheck phase 5: exposition")
+            print("plannercheck phase 4: exposition")
             phase_metrics(base, server)
         finally:
             server.close()
-    print("plannercheck phase 4: already-optimal overhead gate")
-    phase_overhead()
     if FAILURES:
         print("\nplannercheck: FAIL")
         for f in FAILURES:

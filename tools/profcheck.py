@@ -1,11 +1,10 @@
 """Continuous-profiler smoke (PR 19), wired into ``make test`` as
 ``make profcheck``.
 
-Phase 1 (surfaces, HTTP): boot a server with the profiler sampling at
-97 Hz (prime — the anti-phase-lock discipline — and fast enough that a
-short driven load yields hundreds of samples) plus the observatory,
-drive concurrent query load, and assert the surfaces are genuinely
-live:
+Boot a server with the profiler sampling at 97 Hz (prime — the
+anti-phase-lock discipline — and fast enough that a short driven load
+yields hundreds of samples) plus the observatory, drive concurrent
+query load over HTTP, and assert the surfaces are genuinely live:
 
 - ``GET /debug/profile`` reports samples with at least three
   subsystems nonzero under load (serving + device-dispatch +
@@ -20,16 +19,13 @@ live:
   backend (the XLA cost_analysis capture), and the live ``/metrics``
   exposition (``pilosa_profile_*`` included) passes promlint.
 
-Phase 2 (overhead, in-process engine): warm engine Count QPS with the
-sampler ON must be within 2% of the SAME measurement with it OFF —
-the always-on claim, gated the obscheck way (interleaved arm order,
-paired per-round ratios, median-of-rounds, best-of-attempts).
+What the sampler costs a request is not measured here: a timing from
+this sandbox's CPU backend is not a speed (see PERF.md).
 
 Small and CPU-only by design.
 """
 import json
 import os
-import statistics
 import sys
 import tempfile
 import threading
@@ -43,9 +39,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 from pilosa_tpu import SLICE_WIDTH  # noqa: E402
 
 SAMPLE_HZ = 97               # prime; ~10 ms between sweeps
-OVERHEAD_BAR = 0.02          # on-QPS may lag off-QPS by at most 2%
-ROUNDS = 7                   # A/B rounds per arm (median taken)
-ATTEMPTS = 3                 # noisy-box retries before failing
 
 
 def post(base, path, body):
@@ -228,109 +221,11 @@ def phase_surfaces(fails):
             server.close()
 
 
-def _build_engine(tmp):
-    """Dense frame sized so a warm engine query costs enough for a 2%
-    delta to measure instrumentation, not loop constants."""
-    import numpy as np
-
-    from pilosa_tpu.executor import Executor
-    from pilosa_tpu.storage.holder import Holder
-
-    holder = Holder(os.path.join(tmp, "ov")).open()
-    idx = holder.create_index("ov")
-    idx.create_frame("d")
-    rng = np.random.default_rng(3)
-    for s in range(16):
-        b = s * SLICE_WIDTH
-        for rid in range(1, 9):
-            cols = rng.choice(50_000, size=2000, replace=False)
-            idx.frame("d").import_bits([rid] * len(cols),
-                                       (b + cols).tolist())
-    e = Executor(holder)
-    e._force_path = "batched"
-    e._result_memo_off = True  # every query must reach the kernels
-    return holder, e
-
-
-def _qps(e, queries, seconds=0.6):
-    t_end = time.perf_counter() + seconds
-    n = 0
-    while time.perf_counter() < t_end:
-        e.execute("ov", queries[n % len(queries)])
-        n += 1
-    return n / seconds
-
-
-def _measure(e, queries, seconds=0.6):
-    """Median warm QPS for profiler-ON and OFF, interleaved with
-    alternating arm order per round; paired per-round ratios cancel
-    slow thermal/GC drift."""
-    from pilosa_tpu.observe import profiler as prof_mod
-
-    def run_off():
-        prof_mod.disable()
-        return _qps(e, queries, seconds)
-
-    def run_on():
-        prof_mod.enable(sample_hz=SAMPLE_HZ)
-        return _qps(e, queries, seconds)
-
-    on, off, ratios = [], [], []
-    for i in range(ROUNDS):
-        if i % 2:
-            a = run_on()
-            b = run_off()
-        else:
-            b = run_off()
-            a = run_on()
-        on.append(a)
-        off.append(b)
-        ratios.append(a / b)
-    prof_mod.disable()
-    return (statistics.median(on), statistics.median(off),
-            statistics.median(ratios))
-
-
-def phase_overhead(fails):
-    from pilosa_tpu.observe import profiler as prof_mod
-
-    with tempfile.TemporaryDirectory(prefix="profcheck-ov-") as tmp:
-        holder, e = _build_engine(tmp)
-        try:
-            queries = [
-                (f'Count(Intersect(Bitmap(frame="d", rowID={a}), '
-                 f'Bitmap(frame="d", rowID={b})))')
-                for a in range(1, 9) for b in range(a + 1, 9)]
-            for q in queries:  # warm plan/stack tiers off the clock
-                e.execute("ov", q)
-                e.execute("ov", q)
-            best = None
-            for _attempt in range(ATTEMPTS):
-                on_qps, off_qps, ratio = _measure(e, queries)
-                best = max(best or 0.0, ratio)
-                if ratio >= 1.0 - OVERHEAD_BAR:
-                    break
-            print(f"  warm engine on={on_qps:,.0f} q/s "
-                  f"off={off_qps:,.0f} q/s "
-                  f"overhead={100 * (1 - best):.2f}% "
-                  f"(bar {100 * OVERHEAD_BAR:.0f}%)")
-            if best < 1.0 - OVERHEAD_BAR:
-                fails.append(
-                    f"profiler overhead {100 * (1 - best):.2f}% "
-                    f"exceeds {100 * OVERHEAD_BAR:.0f}% "
-                    f"(on={on_qps:.0f}, off={off_qps:.0f})")
-        finally:
-            prof_mod.disable()
-            holder.close()
-
-
 def main():
     fails = []
-    print(f"profcheck phase 1: profiler surfaces (live server, "
+    print(f"profcheck: profiler surfaces (live server, "
           f"{SAMPLE_HZ} Hz)")
     phase_surfaces(fails)
-    print("profcheck phase 2: sampler overhead gate")
-    phase_overhead(fails)
     if fails:
         print("\nprofcheck: FAIL")
         for f in fails:
