@@ -314,6 +314,10 @@ class Executor:
         # against lists walked (_frag_list), process lifetime, under
         # _cache_mu: /debug/vars, beside the per-query keys.
         self.leaf_memo = {"leafMemoHits": 0, "leafMemoMisses": 0}
+        # Per-fragment TopN scans with a src, by where the probe came
+        # from (_execute_topn_slice), process lifetime, under
+        # _cache_mu: /debug/vars, beside the per-query keys.
+        self.topn_probe = {"topnProbeFromMirror": 0, "topnProbeFromHost": 0}
         # Hinted handoff: writes skipped because a replica was DOWN,
         # keyed by host, replayed on rejoin (anti-entropy remains the
         # backstop for hints lost to a coordinator restart).
@@ -1589,8 +1593,11 @@ class Executor:
             return out
         raise ValueError(f"unknown call: {name}")
 
-    def _execute_bitmap_slice(self, index, call, slice_num):
-        """(ref: executeBitmapSlice executor.go:523-568)."""
+    def _bitmap_row(self, index, call):
+        """(frame name, view, row id in that view) a ``Bitmap()`` call
+        names: ``rowID`` reads the standard view, ``columnID`` the
+        inverse one. Raises what the reference raises for a call it
+        refuses (executeBitmapSlice executor.go:523-568)."""
         idx = self.holder.index(index)
         frame_name = call.args.get("frame") or DEFAULT_FRAME
         frame = idx.frame(frame_name)
@@ -1610,9 +1617,12 @@ class Executor:
             if not frame.inverse_enabled:
                 raise ValueError("Bitmap() cannot retrieve columns unless "
                                  "inverse storage enabled")
-            view, id_ = VIEW_INVERSE, col_id
-        else:
-            view, id_ = VIEW_STANDARD, row_id
+            return frame_name, VIEW_INVERSE, col_id
+        return frame_name, VIEW_STANDARD, row_id
+
+    def _execute_bitmap_slice(self, index, call, slice_num):
+        """(ref: executeBitmapSlice executor.go:523-568)."""
+        frame_name, view, id_ = self._bitmap_row(index, call)
         frag = self.holder.fragment(index, frame_name, view, slice_num)
         if frag is None:
             return Bitmap()
@@ -5495,8 +5505,26 @@ class Executor:
                                 batch_fn=self._windowed_batch(batch_fn,
                                                               pairs_add))
 
+    def _topn_probe_row(self, index, call, frame_name, view):
+        """The row id of a TopN's probe where its one child is a plain
+        ``Bitmap`` of a row of the very fragments the TopN scans (the
+        TopN's frame and view: ``rowID`` with a standard-view TopN,
+        ``columnID`` with ``inverse=true``), else None. Read from the
+        call alone; a ``Bitmap`` the executor refuses raises here what
+        executing it would have raised."""
+        child = call.children[0]
+        if child.name != "Bitmap" or child.children:
+            return None
+        child_frame, child_view, row_id = self._bitmap_row(index, child)
+        return (row_id if (child_frame, child_view) == (frame_name, view)
+                else None)
+
     def _execute_topn_slice(self, index, call, slice_num):
-        """(ref: executeTopNSlice executor.go:433-500)."""
+        """(ref: executeTopNSlice executor.go:433-500). Where the src
+        is a row of the fragment the scan reads (``_topn_probe_row``)
+        the child is not executed: the fragment is given the row's id
+        and its program takes the probe from the HBM mirror. Any other
+        child is executed to host words, as in the reference."""
         frame_name = call.args.get("frame") or DEFAULT_FRAME
         inverse = call.args.get("inverse") is True
         n, _ = call.uint_arg("n")
@@ -5508,15 +5536,17 @@ class Executor:
         if tanimoto > 100:
             raise ValueError("Tanimoto Threshold is from 1 to 100 only")
 
-        src = None
+        view = VIEW_INVERSE if inverse else VIEW_STANDARD
+        src = src_row = None
         if len(call.children) == 1:
-            bm = self._execute_bitmap_call_slice(index, call.children[0],
-                                                 slice_num)
-            src = bm.host_words(slice_num)
+            src_row = self._topn_probe_row(index, call, frame_name, view)
+            if src_row is None:
+                bm = self._execute_bitmap_call_slice(
+                    index, call.children[0], slice_num)
+                src = bm.host_words(slice_num)
         elif len(call.children) > 1:
             raise ValueError("TopN() can only have one input bitmap")
 
-        view = VIEW_INVERSE if inverse else VIEW_STANDARD
         frag = self.holder.fragment(index, frame_name, view, slice_num)
         if frag is None:
             return []
@@ -5528,9 +5558,16 @@ class Executor:
                 rid for rid in frame.row_attr_store.ids()
                 if frame.row_attr_store.attrs(rid).get(attr_name) in filters]
 
+        if call.children:
+            stat = ("topnProbeFromHost" if src_row is None
+                    else "topnProbeFromMirror")
+            querystats.add(stat)
+            with self._cache_mu:
+                self.topn_probe[stat] += 1
         return frag.top(TopOptions(
             n=int(n),
             src=src,
+            src_row=src_row,
             row_ids=row_ids if has_ids else None,
             filter_row_ids=filter_row_ids,
             min_threshold=max(int(min_threshold), MIN_THRESHOLD),

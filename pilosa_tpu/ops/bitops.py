@@ -346,6 +346,27 @@ def count_and_rows(m, filt):
     return _traced_dispatch("count_and_rows", _count_and_rows_impl, m, filt)
 
 
+def row_at(m, i):
+    """Row ``i`` of ``m``, read inside the program that scans ``m``:
+    ``i`` is a traced int32 scalar, so one executable serves every
+    row. Where a scan's filter is a row of the matrix it scans (TopN's
+    probe, a ``Bitmap`` of the same fragment) this is the whole of its
+    staging: no device slice, no trip to the host and back."""
+    return lax.dynamic_index_in_dim(m, i, axis=0, keepdims=False)
+
+
+@jax.jit
+def _count_and_rows_at_impl(m, i):
+    return _count_and_rows_impl(m, row_at(m, i))
+
+
+def count_and_rows_at(m, i):
+    """``count_and_rows`` with row ``i`` of ``m`` itself as the filter:
+    uint32[R, W], int32 scalar -> int32[R]."""
+    return _traced_dispatch("count_and_rows_at", _count_and_rows_at_impl,
+                            m, i)
+
+
 # ---------------------------------------------------------------------------
 # Bit-range masking. Ref: CountRange (roaring.go:214-285) walks containers;
 # here a mask vector is built from iota and fused into the popcount.

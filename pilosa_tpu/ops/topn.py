@@ -75,6 +75,27 @@ _tanimoto_masked_counts.__qualname__ = TANIMOTO_FRAGMENT_PROGRAM
 tanimoto_masked_counts = jax.jit(_tanimoto_masked_counts)
 
 
+def _tanimoto_masked_counts_at(matrix, phys, row_n, threshold):
+    """``_tanimoto_masked_counts`` where the probe is row ``phys`` of
+    the matrix it scans: the program takes the src from the matrix
+    and ``|src|`` from the row counts it is given anyway (a row's bits
+    all lie inside its own fragment's window, so that count is the
+    full one). ``phys`` is traced like ``threshold``: one executable
+    for every probe and every threshold."""
+    return _tanimoto_masked_counts(matrix, bitops.row_at(matrix, phys),
+                                   row_n, bitops.row_at(row_n, phys),
+                                   threshold)
+
+
+# Starts with TANIMOTO_FRAGMENT_PROGRAM's tier name, so a reader of the
+# trace that matches ``jit_pilosa_topn_tanimoto_frag*`` reads both.
+TANIMOTO_FRAGMENT_PROBE_PROGRAM = bitops.program_name(
+    "topn_tanimoto_frag_probe", 1)
+_tanimoto_masked_counts_at.__name__ = TANIMOTO_FRAGMENT_PROBE_PROGRAM
+_tanimoto_masked_counts_at.__qualname__ = TANIMOTO_FRAGMENT_PROBE_PROGRAM
+tanimoto_masked_counts_at = jax.jit(_tanimoto_masked_counts_at)
+
+
 def fetch_counts(fn, matrix, *args, op=None):
     """The per-fragment TopN call as ``Fragment.top`` makes it: enqueue,
     device wait and the copy of the counts to the host in one

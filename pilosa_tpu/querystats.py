@@ -89,13 +89,19 @@ MAX_HEDGE_LEGS = 64
 # its exact re-query; topnKept the pairs a TopN call returned;
 # topnRecountsSkipped is 1 for a TopN over one slice, answered from
 # phase 1 alone (its pairs are the totals: executor._execute_topn).
+# topnProbeFromMirror / topnProbeFromHost count the per-fragment TopN
+# scans with a src by where the probe came from: read inside the
+# scan's program from the fragment's HBM mirror (the child is a plain
+# Bitmap of a row of the fragment the TopN scans), or executed to host
+# words and uploaded (any other child): executor._execute_topn_slice.
 KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "cacheMisses", "deviceTransfers", "deviceTransferBytes",
         "fanoutCalls", "fanoutRetries", "planMs", "planCacheHit",
         "containerBlocksDense", "containerBlocksArray",
         "containerBlocksRun", "stackBuilds", "oomFallbacks",
         "leafMemoHits", "leafMemoMisses", "topnRowsScanned",
-        "topnCandidates", "topnKept", "topnRecountsSkipped")
+        "topnCandidates", "topnKept", "topnRecountsSkipped",
+        "topnProbeFromMirror", "topnProbeFromHost")
 
 
 class QueryStats:

@@ -1827,6 +1827,7 @@ class Handler:
         data["widthWarmer"] = self.executor.warm_snapshot()
         data["oomFallbacks"] = self.executor.oom_fallbacks
         data.update(self.executor.leaf_memo)
+        data.update(self.executor.topn_probe)
         if self.tracer.enabled:
             data["tracing"] = self.tracer.summary()
         # One consistent snapshot: the qos/faults/memory groups answer

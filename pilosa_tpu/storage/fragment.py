@@ -327,12 +327,17 @@ def mutation_epoch(index=None):
 
 
 class TopOptions:
-    """TopN options (ref: fragment.go:1004-1021)."""
+    """TopN options (ref: fragment.go:1004-1021). The filter bitmap
+    comes as ``src`` (host words, whatever built them) or, where it is
+    a row of the very fragment ``top`` scans, as that row's id in
+    ``src_row``: the scan's program then reads the probe from the HBM
+    mirror by itself. At most one of the two is given."""
 
     def __init__(self, n=0, src=None, row_ids=None, filter_row_ids=None,
-                 min_threshold=0, tanimoto_threshold=0):
+                 min_threshold=0, tanimoto_threshold=0, src_row=None):
         self.n = n
         self.src = src                      # np.uint64[WORDS64] filter bitmap
+        self.src_row = src_row              # or: the id of this fragment's row
         self.row_ids = row_ids              # explicit candidate rows
         self.filter_row_ids = filter_row_ids  # attr-filtered allowed rows
         self.min_threshold = min_threshold
@@ -1933,6 +1938,21 @@ class Fragment:
             self._rc_dev = rc = (self._version, arr)
         return rc[1]
 
+    def _note_row_read(self, row_id, width32):
+        """One row-block read of ``width32`` device words, for the
+        query's ``blocks`` and the heatmap."""
+        querystats.add("blocks", 1)
+        hm = heatmap_mod.ACTIVE
+        if hm.enabled:
+            # Per-slice/per-row heat from the read layer: only work
+            # that touches INDIVIDUAL slices reaches here (serial
+            # loops, stack-cache misses, lane builds) — the uniform
+            # batched warm path never does, by design. Stride-sampled
+            # inside touch_read so the hottest read loops pay one
+            # counter increment per call, not decay math.
+            hm.touch_read(self.index, self.frame, row_id, self.slice,
+                          weight=width32 * 4)
+
     def device_row(self, row_id):
         """uint32[32768] device bitmap for one row (full slice width —
         the window-agnostic API; batched executors use device_row_win
@@ -1954,17 +1974,7 @@ class Fragment:
         reader — O(row) containers decoded, no fault-in — so batched
         executor stacks over cold fragments never pull whole matrices
         into host memory."""
-        querystats.add("blocks", 1)  # one row-block read per call
-        hm = heatmap_mod.ACTIVE
-        if hm.enabled:
-            # Per-slice/per-row heat from the read layer: only work
-            # that touches INDIVIDUAL slices reaches here (serial
-            # loops, stack-cache misses, lane builds) — the uniform
-            # batched warm path never does, by design. Stride-sampled
-            # inside touch_read so the hottest read loops pay one
-            # counter increment per call, not decay math.
-            hm.touch_read(self.index, self.frame, row_id, self.slice,
-                          weight=width32 * 4)
+        self._note_row_read(row_id, width32)
         lazy = self._lazy_serve(
             lambda r: jnp.asarray(
                 self._lazy_row64_span(r, row_id, base32 // 2,
@@ -2974,12 +2984,22 @@ class Fragment:
         are preserved: with no explicit row_ids, only rows present in the
         cache are eligible (ref: topBitmapPairs fragment.go:965), and a
         ``none``-cache frame yields no TopN results, as in the reference.
+
+        The filter bitmap: ``opt.src`` (host words: trimmed to the
+        window, uploaded, ``|src|`` popcounted here) or ``opt.src_row``,
+        the id of one of this fragment's own rows. Then the scan's
+        program gathers the probe from the HBM mirror by its physical
+        index (a traced scalar) and takes ``|src|`` from the row
+        counts: nothing of the probe crosses to the host. The ``top.src``
+        span is tagged ``probe`` = ``mirror`` | ``host`` accordingly.
         """
         from pilosa_tpu.ops import topn as topn_ops
         from pilosa_tpu.storage.cache import NopCache
 
         opt = opt or TopOptions()
-        if opt.src is None:
+        from_mirror = opt.src_row is not None
+        has_src = from_mirror or opt.src is not None
+        if not has_src:
             # Src-less TopN is a cache walk + exact counts — both
             # available on an EVICTED fragment (cache sidecar + header
             # cardinalities), so don't fault the matrix in for it.
@@ -2992,32 +3012,53 @@ class Fragment:
                 return []
             if opt.row_ids is None and isinstance(self.cache, NopCache):
                 return []
-            if opt.src is not None:
+            if has_src:
                 # Only the src-intersection path reads the device
                 # matrix; building (and slicing) it for the src-less
                 # cache walk cost a device upload + dispatch per
                 # fragment per query for data the counts never touch.
-                with tracing.span("top.src", rows=n_phys):
+                with tracing.span("top.src", rows=n_phys,
+                                  probe="mirror" if from_mirror else "host"):
+                    if from_mirror:
+                        # A row the fragment lacks is an empty src:
+                        # nothing intersects it. A row written a moment
+                        # ago reaches the mirror in ``device_matrix``'s
+                        # refresh, as the rows the scan reads do.
+                        self._note_row_read(opt.src_row, 2 * self._w64)
+                        phys = self._row_index.get(opt.src_row)
+                        if phys is None:
+                            return []
+                        probe = np.int32(phys)
+                    else:
+                        # The matrix may be narrower than the full
+                        # slice; bits beyond its width are zero, so
+                        # trimming src to the matrix width preserves
+                        # every intersection count. The Tanimoto
+                        # denominator's |src| must still come from
+                        # the FULL src bitmap.
+                        src_words = np.ascontiguousarray(opt.src)
+                        base = self._w64_base
+                        probe = jnp.asarray(np.ascontiguousarray(
+                            src_words[base : base + self._w64]
+                        ).view(np.uint32))
                     matrix = self.device_matrix()[:n_phys]
-                    # The matrix may be narrower than the full slice;
-                    # bits beyond its width are zero, so trimming src
-                    # to the matrix width preserves every intersection
-                    # count. The Tanimoto denominator's |src| must
-                    # still come from the FULL src bitmap.
-                    src_words = np.ascontiguousarray(opt.src)
-                    base = self._w64_base
-                    src32 = jnp.asarray(np.ascontiguousarray(
-                        src_words[base : base + self._w64]).view(np.uint32))
                 querystats.add("topnRowsScanned", n_phys)
-                if opt.tanimoto_threshold:
+                if not opt.tanimoto_threshold:
                     counts = topn_ops.fetch_counts(
-                        topn_ops.tanimoto_masked_counts, matrix, src32,
+                        bitops.count_and_rows_at if from_mirror
+                        else bitops.count_and_rows, matrix, probe)
+                elif from_mirror:
+                    counts = topn_ops.fetch_counts(
+                        topn_ops.tanimoto_masked_counts_at, matrix, probe,
+                        self._row_counts_device(n_phys),
+                        opt.tanimoto_threshold,
+                        op="topn_tanimoto_frag_probe")
+                else:
+                    counts = topn_ops.fetch_counts(
+                        topn_ops.tanimoto_masked_counts, matrix, probe,
                         self._row_counts_device(n_phys),
                         int(np.bitwise_count(src_words).sum()),
                         opt.tanimoto_threshold, op="topn_tanimoto_frag")
-                else:
-                    counts = topn_ops.fetch_counts(bitops.count_and_rows,
-                                                   matrix, src32)
             else:
                 counts = self._row_counts[:n_phys].copy()
 

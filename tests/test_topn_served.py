@@ -3,8 +3,12 @@ same for one candidate and for fifty, so the path model's one entry a
 shape holds, and a ``?profile=true`` TopN answer carries the phases'
 spans, tags and counters. Over one slice phase 1's pairs are the
 answer and the re-query is skipped (PR 27): every form equals the
-two-phase result, and two slices still run both phases."""
+two-phase result, and two slices still run both phases. A probe that
+is a row of the fragment the TopN scans is read from the HBM mirror
+inside the scan's program (PR 29): every form equals the brute-force
+list and the same query served through host words."""
 import json
+import re
 import threading
 import urllib.request
 
@@ -300,20 +304,22 @@ SRC = f'Bitmap(frame="f", rowID={PROBE})'
 INV_SRC = 'Bitmap(frame="inv", columnID=5)'
 
 
-@pytest.fixture(scope="module")
-def one_slice(tmp_path_factory):
-    """(executor, {frame: {row: columns}}, {row: cat}): prefix rows
-    1..64, rows 100 and 101 copies of row 64 (a count tie at the top
-    of a src-less ranking), row 200 a copy of row 20 (the probe's own
-    twin), an attribute on every third row, and an inverse-enabled
-    frame whose inverse view has rows 0..63."""
-    h = Holder(str(tmp_path_factory.mktemp("one_slice") / "d")).open()
+def _prefix_rows(path, slices=1):
+    """(holder, executor, {frame: {row: columns}}, {row: cat}): prefix
+    rows 1..64, rows 100 and 101 copies of row 64 (a count tie at the
+    top of a src-less ranking), row 200 a copy of row 20 (the probe's
+    own twin), an attribute on every third row, and an inverse-enabled
+    frame whose inverse view has rows 0..63; with ``slices`` > 1 the
+    same columns again in each further slice of the standard view."""
+    h = Holder(str(path / "d")).open()
     idx = h.create_index("i")
     idx.create_frame("f")
     idx.create_frame("inv", FrameOptions(inverse_enabled=True))
     rows = {d: set(range(d)) for d in range(1, ROWS + 1)}
     rows.update({100: set(range(64)), 101: set(range(64)),
                  200: set(range(PROBE))})
+    rows = {r: {c + s * SLICE_WIDTH for c in cs for s in range(slices)}
+            for r, cs in rows.items()}
     for name in ("f", "inv"):
         idx.frame(name).import_bits(
             [r for r, cs in rows.items() for _ in cs],
@@ -329,8 +335,14 @@ def one_slice(tmp_path_factory):
     for r, cs in rows.items():
         for c in cs:
             inverse.setdefault(c, set()).add(r)
-    assert idx.max_slice() == 0 and idx.max_inverse_slice() == 0
-    yield ex, {"f": rows, "inv": inverse}, cats
+    assert idx.max_slice() == slices - 1 and idx.max_inverse_slice() == 0
+    return h, ex, {"f": rows, "inv": inverse}, cats
+
+
+@pytest.fixture(scope="module")
+def one_slice(tmp_path_factory):
+    h, *rest = _prefix_rows(tmp_path_factory.mktemp("one_slice"))
+    yield rest
     h.close()
 
 
@@ -484,3 +496,228 @@ def test_two_slices_keep_the_exact_requery(tmp_path):
                 assert qs.to_dict()["topnRecountsSkipped"] == skipped
     finally:
         h.close()
+
+
+# ------------- the probe is a row of the matrix the scan reads (PR 29)
+
+@pytest.fixture(scope="module", params=[1, 3], ids=["1slice", "3slices"])
+def prefix_index(request, tmp_path_factory):
+    """``_prefix_rows`` on one fragment and on three slices."""
+    h, *rest = _prefix_rows(tmp_path_factory.mktemp("prefix_index"),
+                            request.param)
+    yield (*rest, request.param)
+    h.close()
+
+
+def _host_twin(pql, other_frame=None):
+    """The same TopN with its child dressed so that it is executed to
+    host words: a ``Union`` of the one ``Bitmap``, or the ``Bitmap`` of
+    another frame that holds the same row."""
+    child = re.match(r"TopN\((Bitmap\([^)]*\))", pql).group(1)
+    twin = (f"Union({child})" if other_frame is None
+            else child.replace('frame="f"', f'frame="{other_frame}"'))
+    assert twin != child
+    return pql.replace(child, twin, 1)
+
+
+def _run(ex, pql):
+    with querystats.scope(querystats.QueryStats()) as qs:
+        return ex.execute("i", pql)[0], qs.to_dict()
+
+
+def _check_probe_counters(path, scans, new, twin, present=True):
+    """``scans`` per-fragment scans a query: on the serial path each
+    took its probe from the mirror (the twin: from host words) and read
+    as many row blocks either way; a probe row the fragment lacks is
+    looked up and nothing is scanned for it (through host words: every
+    row against zeros). No query of the new shape ever builds host
+    words, whatever path served it."""
+    assert new["topnProbeFromHost"] == 0 and twin["topnProbeFromMirror"] == 0
+    if path == "serial":
+        assert new["topnProbeFromMirror"] == twin["topnProbeFromHost"] == scans
+        assert new["blocks"] == twin["blocks"] == scans
+        assert twin["topnRowsScanned"] > 0
+        assert new["topnRowsScanned"] == (twin["topnRowsScanned"]
+                                          if present else 0)
+    elif path == "batched":
+        assert new["topnProbeFromMirror"] == twin["topnProbeFromHost"] == 0
+
+
+SRC_FORMS = [f for f in FORMS if "Bitmap(" in f[1]]
+
+
+@pytest.mark.parametrize("path", [None, "serial", "batched"])
+@pytest.mark.parametrize("name, pql, n, ref, on_gate, tie", SRC_FORMS,
+                         ids=[f[0] for f in SRC_FORMS])
+def test_a_probe_from_the_mirror_equals_the_host_path(
+        prefix_index, name, pql, n, ref, on_gate, tie, path):
+    """Every form of TopN with a src whose child is a ``Bitmap`` of the
+    fragment it scans: the answer equals the brute-force list, the same
+    query with the child wrapped in a ``Union`` and, where a second
+    frame holds the same row, with that frame's ``Bitmap`` (both
+    executed to host words, as every child was until PR 29)."""
+    ex, data, cats, slices = prefix_index
+    ex._force_path = path
+    ref = dict(ref)
+    inverse = ref.pop("frame", "f") == "inv"
+    if inverse:
+        # One inverse slice, whose rows are the columns of every slice.
+        rows, copies = data["inv"], 1
+        ref["src"] = data["inv"][5]
+    else:
+        # The slices hold the same columns, so each gives the same
+        # pairs under its own gate and threshold, and the totals are
+        # those pairs' counts times the slices.
+        rows = {r: {c for c in cs if c < SLICE_WIDTH}
+                for r, cs in data["f"].items()}
+        copies = slices
+        ref["src"] = rows.get(ref["src"], set())
+    if "allowed" in ref:
+        ref["allowed"] = {r for r, c in cats.items() if c == ref["allowed"]}
+    want = [(r, c * copies) for r, c in brute_topn(rows, n=n, **ref)]
+
+    pql = pql.format(ids="")
+    got, new = _run(ex, pql)
+    assert got == want
+    via_host, twin = _run(ex, _host_twin(pql))
+    assert via_host == want
+    # phase 1 scans each fragment once; over several slices the exact
+    # re-query scans each again
+    scans = copies * (2 if want and copies > 1 else 1)
+    _check_probe_counters(path, scans, new, twin,
+                          present=name != "empty_probe")
+    if 'frame="f", rowID=20' in pql:
+        other, stats = _run(ex, _host_twin(pql, other_frame="inv"))
+        assert other == want
+        _check_probe_counters(path, scans, new, stats)
+
+    got_ids = [rid for rid, _ in got]
+    assert not set(on_gate) & set(got_ids)
+    if tie:
+        kept = set(tie) & set(got_ids)
+        assert kept and kept != set(tie)
+
+
+def _small_index(tmp_path, cols_of):
+    h = Holder(str(tmp_path / "d")).open()
+    h.create_index("i").create_frame("f")
+    h.index("i").frame("f").import_bits(
+        [r for r, cs in cols_of.items() for _ in cs],
+        [c for cs in cols_of.values() for c in cs])
+    return h, Executor(h)
+
+
+def _write(ex, rows, kind, row, col):
+    ex.execute("i", f'{kind}(frame="f", rowID={row}, columnID={col})')
+    (rows.setdefault(row, set()).add if kind == "SetBit"
+     else rows[row].discard)(col)
+
+
+def _probe_grows(ex, rows, frag):
+    _write(ex, rows, "SetBit", 20, 40)
+
+
+def _probe_shrinks(ex, rows, frag):
+    _write(ex, rows, "ClearBit", 20, 3)
+
+
+def _probe_is_new(ex, rows, frag):
+    for col in range(12):
+        _write(ex, rows, "SetBit", 77, col)
+
+
+def _probe_widens_the_window(ex, rows, frag):
+    # the last column of the slice: the window grows to full width
+    assert frag._w64 < SLICE_WIDTH // 64
+    _write(ex, rows, "SetBit", 20, SLICE_WIDTH - 1)
+    assert (frag._w64_base, frag._w64) == (0, SLICE_WIDTH // 64)
+
+
+def _fragment_is_evicted(ex, rows, frag):
+    frag.unload()
+    assert not frag._resident
+
+
+@pytest.mark.parametrize("path", [None, "serial", "batched"])
+@pytest.mark.parametrize("change, probe", [
+    (_probe_grows, 20), (_probe_shrinks, 20), (_probe_is_new, 77),
+    (_probe_widens_the_window, 20), (_fragment_is_evicted, 20)],
+    ids=["setbit", "clearbit", "new_row", "full_width", "evicted"])
+def test_the_mirror_probe_follows_the_fragments_state(tmp_path, change,
+                                                      probe, path):
+    """What the scan's program reads is the row as it stands: a probe
+    written or cleared a moment ago (dirty in the mirror until
+    ``device_matrix`` refreshes it), a probe row that did not exist, a
+    window grown from narrow to the slice's full width, a fragment the
+    governor had evicted. Before and after, mirror and host words give
+    the brute-force list, gated and ungated."""
+    rows = {d: set(range(d)) for d in range(1, 41)}
+    h, ex = _small_index(tmp_path, rows)
+    try:
+        ex._force_path = path
+        frag = h.fragment("i", "f", "standard", 0)
+        for step in (None, change):
+            if step is not None:
+                step(ex, rows, frag)
+            for tail, t in (("n=6", 0), ("n=6, tanimotoThreshold=60", 60)):
+                pql = (f'TopN(Bitmap(frame="f", rowID={probe}), '
+                       f'frame="f", {tail})')
+                want = brute_topn(rows, src=rows.get(probe, set()), n=6,
+                                  tanimoto=t)
+                assert bool(want) == (probe in rows)
+                got, new = _run(ex, pql)
+                via_host, twin = _run(ex, _host_twin(pql))
+                assert got == via_host == want, (step, tail)
+                _check_probe_counters(path, 1, new, twin,
+                                      present=probe in rows)
+    finally:
+        h.close()
+
+
+def test_one_program_for_every_probe_and_threshold(one_slice):
+    """The probe's physical index and the threshold are traced: after
+    the first gated and the first ungated scan of a fragment no probe
+    and no threshold compiles again."""
+    from pilosa_tpu.ops import bitops
+    from pilosa_tpu.ops import topn as topn_ops
+
+    ex, data, _ = one_slice
+    ex._force_path = "serial"
+    programs = (topn_ops.tanimoto_masked_counts_at,
+                bitops._count_and_rows_at_impl)
+    q = 'TopN(Bitmap(frame="f", rowID={p}), frame="f", n=5{t})'
+    ex.execute("i", q.format(p=PROBE, t=", tanimotoThreshold=50"))
+    ex.execute("i", q.format(p=PROBE, t=""))
+    sizes = [fn._cache_size() for fn in programs]
+    assert all(sizes)
+    for p, t in ((7, 70), (33, 90), (64, 1), (200, 50)):
+        want = brute_topn(data["f"], src=data["f"][p], n=5, tanimoto=t)
+        assert ex.execute(
+            "i", q.format(p=p, t=f", tanimotoThreshold={t}"))[0] == want
+        assert ex.execute("i", q.format(p=p, t=""))[0] \
+            == brute_topn(data["f"], src=data["f"][p], n=5)
+    assert [fn._cache_size() for fn in programs] == sizes
+
+
+def test_a_profile_says_where_the_probe_came_from(server):
+    """``top.src`` is tagged ``probe``, the profile's ``resources`` and
+    ``/debug/vars`` count the scans by it."""
+    server.executor._force_path = "serial"
+    seen = {}
+    for kind, child in (("mirror", 'Bitmap(frame="f", rowID=0)'),
+                        ("host", 'Union(Bitmap(frame="f", rowID=0))')):
+        doc = _post(server, "/index/i/query?profile=true",
+                    f'TopN({child}, frame="f", n=50, tanimotoThreshold=70)')
+        seen[kind] = doc["results"][0]
+        tags = [sp["tags"] for sp in doc["profile"]["spans"]
+                if sp["name"] == "top.src"]
+        assert tags == [{"rows": 40, "probe": kind}]
+        res = doc["profile"]["resources"]
+        assert (res["topnProbeFromMirror"], res["topnProbeFromHost"]) \
+            == ((1, 0) if kind == "mirror" else (0, 1))
+    assert seen["mirror"] == seen["host"] and len(seen["host"]) > 1
+    with urllib.request.urlopen(f"http://{server.host}/debug/vars",
+                                timeout=30) as resp:
+        totals = json.loads(resp.read())
+    assert (totals["topnProbeFromMirror"], totals["topnProbeFromHost"]) \
+        == (1, 1)
