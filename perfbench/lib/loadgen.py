@@ -18,7 +18,13 @@ replacement, warm-up takes the first of them and the window the rest,
 dealt round robin to the clients. ``unordered`` forms count (a, b) and
 (b, a) as one query. The forms come in the order of a deck that holds
 each form ``weight`` times and is shuffled anew, from the seed, every
-time it runs out: every seed sends the same mix in another order."""
+time it runs out: every seed sends the same mix in another order.
+
+A query knows its form: what ``ladder``, ``mixed_warm`` and ``window``
+yield is a ``Query``, the text itself (a ``str``: sent, logged, parsed
+and compared as before) with the index of its form in ``forms`` on it.
+Nothing has to read a form back out of the text, so a mix may vary an
+operand of any kind: a row, a column, a bound, a window, a time."""
 import itertools
 import json
 import threading
@@ -61,6 +67,17 @@ def _tuples(form, pools, rng, need):
     return out[:need]
 
 
+class Query(str):
+    """A query's text, with ``form``: the index in the mix's ``forms``
+    of the form it was rendered from."""
+    __slots__ = ("form",)
+
+    def __new__(cls, text, form):
+        self = super().__new__(cls, text)
+        self.form = form
+        return self
+
+
 class Traffic:
     """The queries of one run, from the mix, the pools and the seed."""
 
@@ -84,7 +101,7 @@ class Traffic:
             return None
         vals = {n: self._pools[form["operands"][n]][k]
                 for n, k in zip(form["operands"], tuples[j])}
-        return form["pql"].format(**vals)
+        return Query(form["pql"].format(**vals), i)
 
     def _warm(self, i):
         """The next reserved warm-up query of form i; None when the

@@ -214,15 +214,16 @@ def compare(reference, log, out_dir, sample, seed, control=False):
 
 def dump_requests(log, t_open, out_dir):
     """The window's requests, one line each, for whoever reads a run
-    afterwards: when, how long, and in a traced run what the server
-    said of it."""
+    afterwards: when, how long, which form of the mix it was, and in a
+    traced run what the server said of it."""
     with open(os.path.join(out_dir, "requests.jsonl"), "w") as f:
         for r in log:
             prof = r.get("profile") or {}
             f.write(json.dumps({
                 "client": r["client"], "at_s": r["t0"] - t_open,
                 "ms": (r["t1"] - r["t0"]) * 1000.0, "status": r["status"],
-                "pql": r["pql"], "resources": prof.get("resources"),
+                "form": r["pql"].form, "pql": r["pql"],
+                "resources": prof.get("resources"),
                 "spans": prof.get("spans")}) + "\n")
 
 

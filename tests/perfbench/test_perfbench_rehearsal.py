@@ -4,6 +4,7 @@ contract's keys and says ``cpu`` wherever it names a device; and the
 harness refuses to measure where the server's device is not a TPU."""
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -45,6 +46,18 @@ def test_rehearsal_of_every_cell(tmp_path, cell, trace):
     if trace:
         assert "compiles_in_window" in last["rehearsal_values"]
         assert last["breakdown"]["idle_gaps"]
+    # Every request of the window is written down with the form of the
+    # mix it was, for whoever splits a run's latency by form.
+    out = tmp_path / "out" / f"{cell}-2147483949-t{trace}" / "requests.jsonl"
+    sent = [json.loads(line) for line in out.read_text().splitlines()]
+    traffic = next(w["traffic"] for w in CELLS if w["name"] == cell)
+    with open(os.path.join(ROOT, "perfbench", "traffic",
+                           traffic + ".json")) as f:
+        forms = json.load(f)["forms"]
+    assert len(sent) == last["attempted"]
+    for r in sent:
+        shell = re.split(r"\{\w+\}", forms[r["form"]]["pql"])
+        assert r["pql"].startswith(shell[0]) and r["pql"].endswith(shell[-1])
     assert not os.listdir(tmp_path) or os.listdir(tmp_path) == ["out"]
 
 
