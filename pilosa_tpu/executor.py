@@ -104,6 +104,25 @@ def _run_count_split(fn, stacks):
         return np.asarray(out)
 
 
+def _run_outputs(fn, stacks):
+    """A batched program with several outputs (Sum's plane and filter
+    counts), as the served path calls it: enqueue, device wait and the
+    copies to the host in one expression."""
+    return [np.asarray(o) for o in fn(*stacks)]
+
+
+def _run_outputs_split(fn, stacks):
+    """The same call under a trace, cut as ``_run_count_split`` cuts a
+    Count."""
+    with tracing.span("kernel.dispatch"):
+        outs = fn(*stacks)
+    with tracing.span("kernel.wait"):
+        for o in outs:
+            o.block_until_ready()
+    with tracing.span("kernel.fetch"):
+        return [np.asarray(o) for o in outs]
+
+
 def _popcounts(x):
     """int32 popcount over the last (word) axis."""
     import jax.numpy as jnp
@@ -318,6 +337,10 @@ class Executor:
         # from (_execute_topn_slice), process lifetime, under
         # _cache_mu: /debug/vars, beside the per-query keys.
         self.topn_probe = {"topnProbeFromMirror": 0, "topnProbeFromHost": 0}
+        # Lookups of a BSI aggregate's prelude memo by outcome
+        # (_prelude_record), process lifetime, under _cache_mu:
+        # /debug/vars, beside the per-query keys.
+        self.bsi_prelude = {"bsiPreludeHits": 0, "bsiPreludeMisses": 0}
         # Hinted handoff: writes skipped because a replica was DOWN,
         # keyed by host, replayed on rejoin (anti-entropy remains the
         # backstop for hints lost to a coordinator restart).
@@ -4058,7 +4081,7 @@ class Executor:
         hit = self.plans.get(pkey, _frag.mutation_epoch(pkey[1]),
                              record=False)
         if hit is None:
-            self.plans.record(pkey[1], False)
+            self._prelude_record(pkey, False)
             return None
         head, specs, tail = hit
         with self._cache_mu:
@@ -4071,15 +4094,29 @@ class Executor:
                 if ent is None:
                     # Evicted under budget → full path (which re-puts
                     # the same key with fresh stacks).
-                    self.plans.record(pkey[1], False)
-                    return None
+                    stacks = None
+                    break
                 self._stack_cache[v] = self._stack_cache.pop(v)
                 stacks.append(ent[1])
-        self.plans.record(pkey[1], True)
+        self._prelude_record(pkey, stacks is not None)
+        if stacks is None:
+            return None
         qs = querystats.active()
         if qs is not None:
             qs.add("planCacheHit", 1)
         return head, stacks, tail
+
+    def _prelude_record(self, pkey, hit):
+        """The outcome of one prelude-memo lookup, told to the plan
+        cache. A ``bsi`` prelude's (Sum/Min/Max) is the same count
+        shown twice more: ``bsiPreludeHits`` / ``bsiPreludeMisses``
+        of the query's ``?profile=true`` block and of /debug/vars."""
+        self.plans.record(pkey[1], hit)
+        if pkey[0] == "bsi":
+            stat = "bsiPreludeHits" if hit else "bsiPreludeMisses"
+            querystats.add(stat)
+            with self._cache_mu:
+                self.bsi_prelude[stat] += 1
 
     def _prelude_memo_put(self, pkey, head, specs, tail, epoch):
         self.plans.put(pkey, epoch, (head, specs, tail))
@@ -4660,26 +4697,64 @@ class Executor:
         """Sum over the local slice list as one sharded XLA program:
         planes stack ``uint32[S, depth+1, W]`` + optional filter tree,
         fused popcounts per (slice, plane) — the cross-slice analog of
-        Fragment.field_sum. Returns None when ineligible."""
-        pre = self._bsi_batch_prelude(index, call, slices)
+        Fragment.field_sum. Returns None when ineligible. Under a
+        trace the call is cut as a batched Count is: ``sum.plan`` (the
+        prelude), ``kernel:sum_batched`` (> ``kernel.fn`` /
+        ``.dispatch`` / ``.wait`` / ``.fetch``), ``sum.reduce``."""
+        with tracing.span("sum.plan", slices=len(slices)) as psp:
+            pre = self._bsi_batch_prelude(index, call, slices, psp)
         if pre is None or pre is BATCH_OVER_BUDGET:
             return pre
         field, depth, plan, planes_stack, leaf_stacks, padded_n, win = pre
 
-        fn = self._batched_sum_fn(str(plan), plan, depth, padded_n,
-                                  win[1])
-        plane_counts, filt_counts = fn(planes_stack, *leaf_stacks)
-        plane_counts = np.asarray(plane_counts)[: len(slices)]
-        count = int(np.asarray(filt_counts)[: len(slices)].sum())
-        total = sum((1 << i) * int(plane_counts[:, i].sum())
-                    for i in range(depth))
-        return SumCount(total + count * field.min, count)
+        tree_key = str(plan)
+        stacks = [planes_stack, *leaf_stacks]
+        obs = kerneltime_mod.ACTIVE
+        with tracing.span("kernel:sum_batched", slices=len(slices),
+                          width32=win[1]) as ksp:
+            traced = ksp is not tracing.NOP_SPAN
+            hit = True
+            if traced or obs.enabled:
+                # As _batched_count: a racy, lock-free membership read.
+                hit = (("sum", tree_key, depth, padded_n, win[1])
+                       in self._batched_cache)
+            with tracing.span("kernel.fn") as fsp:
+                fn = self._batched_sum_fn(tree_key, plan, depth, padded_n,
+                                          win[1])
+                if traced:
+                    fsp.tag(compile=not hit)
+            run = _run_outputs_split if traced else _run_outputs
+            t0 = time.perf_counter()
+            plane_counts, filt_counts = run(fn, stacks)
+            if obs.enabled:
+                # One cost row per (slice-count, width) shape class, as
+                # a batched Count's: a compile always records (it is
+                # what ``compileCalls`` of /debug/kernels counts), a
+                # steady dispatch 1-in-OBS_STRIDE with scaled weight.
+                self._obs_tick = w = self._obs_tick + 1
+                w = 0 if w % self.OBS_STRIDE else self.OBS_STRIDE
+                if not hit or w:
+                    obs.note(
+                        "sum_batched", "dense*dense",
+                        kerneltime_mod.shape_bucket(padded_n * win[1] * 4),
+                        time.perf_counter() - t0, compiled=not hit,
+                        device=True, n=(1 if not hit else w))
+        with tracing.span("sum.reduce"):
+            count = int(filt_counts[: len(slices)].sum())
+            total = sum((1 << i) * int(plane_counts[: len(slices), i].sum())
+                        for i in range(depth))
+            return SumCount(total + count * field.min, count)
 
-    def _bsi_batch_prelude(self, index, call, slices):
+    def _bsi_batch_prelude(self, index, call, slices,
+                           span=tracing.NOP_SPAN):
         """Shared eligibility + stack build for batched BSI aggregates
         (Sum/Min/Max): (field, depth, plan, planes_stack, leaf_stacks,
         padded_n), or None when ineligible (missing frame/field,
-        unbatchable filter tree, over device budget)."""
+        unbatchable filter tree, over device budget). ``span`` (the
+        caller's, around this call) is tagged ``memo`` = hit or miss,
+        ``leaves`` and ``rows`` (row-equivalents a slice the program
+        reads); a miss runs under ``build.frags`` / ``build.window`` /
+        ``build.args`` as a Count's does."""
         import jax
 
         from pilosa_tpu.storage import fragment as _frag
@@ -4692,9 +4767,13 @@ class Executor:
         if resolved is None:
             return None
         frame_name, field_name, field, depth, plan, leaves = resolved
+        rows = depth + 1 + sum(self._spec_rows(sp) for sp in leaves)
         pkey = ("bsi", index, slice_key(slices), frame_name, field_name,
                 depth, str(plan), tuple(leaves))
         memo = self._prelude_memo_get(pkey)
+        if span is not tracing.NOP_SPAN:
+            span.tag(memo="hit" if memo is not None else "miss",
+                     leaves=len(leaves), rows=rows)
         if memo is not None:
             if qs is not None:
                 qs.add("planMs", (time.perf_counter() - t0) * 1000)
@@ -4705,30 +4784,38 @@ class Executor:
 
         n_dev = len(jax.devices())
         pad = (-len(slices)) % n_dev
-        # The planes spec may not be among the filter's leaves; include
-        # it explicitly so the window covers the BSI fragments too.
-        win_leaves = leaves + [("planes", frame_name, field_name, depth)]
-        frag_map = self._leaf_frags(index, win_leaves, slices)
-        win = self._union_window(frag_map)
-        rows = depth + 1 + sum(self._spec_rows(sp) for sp in leaves)
-        if not self._fits_device_budget(rows, len(slices) + pad,
-                                        width32=win[1]):
-            return BATCH_OVER_BUDGET
-        planes_stack = self._planes_stack(
-            index, frame_name, field_name, depth, slices, pad, n_dev,
-            win=win,
-            frags=frag_map.get((frame_name, view_field_name(field_name))))
-        leaf_stacks = [self._spec_arg(index, sp, slices, pad, n_dev, win,
-                                      frag_map)
-                       for sp in leaves]
-        planes_spec = [("key", ("planes", index, frame_name, field_name,
-                                depth, slice_key(slices), n_dev,
-                                win[0], win[1]))]
-        leaf_specs = self._prelude_specs(index, leaves, leaf_stacks,
-                                         slices, n_dev, win)
-        self._prelude_memo_put(pkey, (field, depth, plan),
-                               planes_spec + leaf_specs,
-                               (len(slices) + pad, win), epoch)
+        with tracing.span("build.frags") as fsp:
+            # The planes spec may not be among the filter's leaves;
+            # include it explicitly so the window covers the BSI
+            # fragments too.
+            win_leaves = leaves + [("planes", frame_name, field_name, depth)]
+            walked = []
+            frag_map = self._leaf_frags(index, win_leaves, slices,
+                                        walked=walked)
+            if fsp is not tracing.NOP_SPAN:
+                fsp.tag(walked=len(walked))
+        with tracing.span("build.window"):
+            win = self._union_window(frag_map)
+            if not self._fits_device_budget(rows, len(slices) + pad,
+                                            width32=win[1]):
+                return BATCH_OVER_BUDGET
+        with tracing.span("build.args"):
+            planes_stack = self._planes_stack(
+                index, frame_name, field_name, depth, slices, pad, n_dev,
+                win=win,
+                frags=frag_map.get((frame_name,
+                                    view_field_name(field_name))))
+            leaf_stacks = [self._spec_arg(index, sp, slices, pad, n_dev,
+                                          win, frag_map)
+                           for sp in leaves]
+            planes_spec = [("key", ("planes", index, frame_name,
+                                    field_name, depth, slice_key(slices),
+                                    n_dev, win[0], win[1]))]
+            leaf_specs = self._prelude_specs(index, leaves, leaf_stacks,
+                                             slices, n_dev, win)
+            self._prelude_memo_put(pkey, (field, depth, plan),
+                                   planes_spec + leaf_specs,
+                                   (len(slices) + pad, win), epoch)
         if qs is not None:
             qs.add("planMs", (time.perf_counter() - t0) * 1000)
         return (field, depth, plan, planes_stack, leaf_stacks,

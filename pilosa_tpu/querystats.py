@@ -94,6 +94,11 @@ MAX_HEDGE_LEGS = 64
 # scan's program from the fragment's HBM mirror (the child is a plain
 # Bitmap of a row of the fragment the TopN scans), or executed to host
 # words and uploaded (any other child): executor._execute_topn_slice.
+# bsiPreludeHits / bsiPreludeMisses count the lookups of a BSI
+# aggregate's prelude memo (Sum/Min/Max) by outcome: the memo is keyed
+# by the plan AND its leaves, which hold a condition's predicate bits
+# and a Bitmap's row, so a never-seen query reads a miss
+# (executor._prelude_record).
 KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "cacheMisses", "deviceTransfers", "deviceTransferBytes",
         "fanoutCalls", "fanoutRetries", "planMs", "planCacheHit",
@@ -101,7 +106,8 @@ KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "containerBlocksRun", "stackBuilds", "oomFallbacks",
         "leafMemoHits", "leafMemoMisses", "topnRowsScanned",
         "topnCandidates", "topnKept", "topnRecountsSkipped",
-        "topnProbeFromMirror", "topnProbeFromHost")
+        "topnProbeFromMirror", "topnProbeFromHost",
+        "bsiPreludeHits", "bsiPreludeMisses")
 
 
 class QueryStats:

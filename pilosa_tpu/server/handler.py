@@ -1828,6 +1828,7 @@ class Handler:
         data["oomFallbacks"] = self.executor.oom_fallbacks
         data.update(self.executor.leaf_memo)
         data.update(self.executor.topn_probe)
+        data.update(self.executor.bsi_prelude)
         if self.tracer.enabled:
             data["tracing"] = self.tracer.summary()
         # One consistent snapshot: the qos/faults/memory groups answer
