@@ -2152,7 +2152,8 @@ class Executor:
         the serial path's out-of-range/not-null shortcuts folded in at
         plan time (they depend only on field/op/value, never the slice
         — executeFieldRangeSlice executor.go:682-819). Predicate bits
-        ride as array args so distinct values share one executable."""
+        ride as array args so distinct values share one executable:
+        host int32 arrays, uploaded by the call that reads them."""
         idx = self.holder.index(index)
         frame_name = call.args.get("frame") or DEFAULT_FRAME
         frame = idx.frame(frame_name)
@@ -3375,9 +3376,10 @@ class Executor:
 
     def _co_stack_args(self, per_query, leaves0, k_pad, n_dev):
         """Give each leaf slot a query axis: stack the K per-query
-        device args to [K, ...], zero-padding to the k_pad bucket. The
-        slice axis is re-sharded for row/plane stacks only — "bits"
-        predicate args are [K, depth] with no slice axis. The ONE
+        args to [K, ...], zero-padding to the k_pad bucket. The slice
+        axis is re-sharded for row/plane stacks only — "bits"
+        predicate args have no slice axis and are stacked on the host
+        ([K, depth] NumPy, uploaded by the fused call). The ONE
         stacking loop shared by every fused shape (count, sum)."""
         import jax
         import jax.numpy as jnp
@@ -3385,11 +3387,14 @@ class Executor:
         args = []
         for j in range(len(per_query[0])):
             cols = [pq[j] for pq in per_query]
+            if leaves0[j][0] == "bits":
+                cols += [np.zeros_like(cols[0])] * (k_pad - len(cols))
+                args.append(np.stack(cols))
+                continue
             while len(cols) < k_pad:
                 cols.append(jnp.zeros_like(cols[0]))
             stacked = jnp.stack(cols)
-            if (n_dev > 1 and stacked.ndim >= 2
-                    and leaves0[j][0] != "bits"):
+            if n_dev > 1 and stacked.ndim >= 2:
                 from jax.sharding import NamedSharding, PartitionSpec
 
                 spec = PartitionSpec(None, "slice",
@@ -3837,9 +3842,10 @@ class Executor:
 
     def _spec_arg(self, index, spec, slices, pad, n_dev, win=None,
                   frag_map=None):
-        """Build the device arg for one typed leaf spec."""
-        import jax.numpy as jnp
-
+        """Build the program operand for one typed leaf spec: a device
+        stack for a row or a plane matrix, a host int32[depth] array
+        for predicate bits (it travels with the launch that reads it;
+        an eager jnp.asarray would be a program of its own)."""
         if spec[0] == "row":
             _, fname, rid, view = spec
             frags = frag_map.get((fname, view)) if frag_map else None
@@ -3853,7 +3859,7 @@ class Executor:
                                       slices, pad, n_dev, win=win,
                                       frags=frags)
         _, bits, depth = spec
-        return jnp.asarray(bits, dtype=jnp.int32)
+        return np.asarray(bits, dtype=np.int32)
 
     # Minimum device-stack window width (uint32 words): 2 × the
     # fragment minimum (_MIN_W64=64 u64 words), and a multiple of the
@@ -4125,7 +4131,8 @@ class Executor:
         """Memo descriptors per leaf: the stack-cache KEY for row/plane
         stacks (must match _leaf_stack/_planes_stack key layout), the
         raw array only for tiny host-derived args (BSI predicate
-        bits)."""
+        bits: a host int32[depth] array, a few dozen bytes, pinned by
+        the memo)."""
         specs = []
         skey = slice_key(slices)
         for sp, st in zip(leaves, stacks):

@@ -173,6 +173,90 @@ def test_concurrent_filtered_sums_fuse(env):
     assert e._co_stats["fused_queries"] >= 2
 
 
+def test_concurrent_sums_under_bsi_ranges_fuse_with_host_bits(env):
+    """Sums of one shape with different bounds fuse (PR 32): each
+    "bits" slot reaches the fused program as ONE host array
+    [k_pad, depth] (stacked and padded with NumPy, uploaded by the
+    call), row slots stay device stacks, and every answer equals the
+    single batched path's and the serial path's."""
+    import jax
+
+    holder, idx, e = env
+    from pilosa_tpu.storage.frame import Field
+    from pilosa_tpu.storage.index import FrameOptions
+
+    frame = idx.frame("general")
+    _fill(frame, n_slices=3)
+    idx.create_frame("sums", FrameOptions(
+        range_enabled=True,
+        fields=[Field(name="v", type="int", min=0, max=300)]))
+    bsi = idx.frame("sums")
+    for s in range(3):
+        base = s * SLICE_WIDTH
+        for i in range(400):
+            bsi.set_field_value(base + i, "v", (i * 7) % 300)
+    depth = bsi.field("v").bit_depth()
+
+    single = Executor(holder)
+    single._force_path = "batched"
+    single._co_enabled_memo = False
+    serial = Executor(holder)
+    serial._force_path = "serial"
+    queries = [
+        (f'Sum(Intersect(Bitmap(frame="general", rowID=1), '
+         f'Range(frame="sums", v >< [{lo}, {lo + 90}])), '
+         f'frame="sums", field="v")')
+        for lo in (5, 40, 75, 110, 145, 180)
+    ]
+    want = {q: single.execute("i", q)[0] for q in queries}
+    assert want == {q: serial.execute("i", q)[0] for q in queries}
+    assert len(set(map(tuple, want.values()))) > 1
+
+    stacked = []
+    real = e._co_stack_args
+
+    def spy(per_query, leaves0, k_pad, n_dev):
+        args = real(per_query, leaves0, k_pad, n_dev)
+        stacked.append((len(per_query), k_pad, leaves0, args))
+        return args
+
+    e._co_stack_args = spy
+    results = {}
+    errors = []
+    barrier = threading.Barrier(len(queries))
+
+    def run(q):
+        try:
+            barrier.wait(timeout=30)
+            results[q] = e.execute("i", q)[0]
+        except Exception as exc:  # noqa: BLE001
+            errors.append(repr(exc))
+
+    threads = [threading.Thread(target=run, args=(q,)) for q in queries]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not errors, errors[:3]
+    assert results == want
+    assert e._co_stats["fused_queries"] >= 2, e._co_stats
+    assert max(k for k, _, _, _ in stacked) >= 2
+    for k, k_pad, leaves0, args in stacked:
+        kinds = [sp[0] for sp in leaves0]
+        assert sorted(kinds) == ["bits", "bits", "planes", "row"]
+        for kind, arg in zip(kinds, args):
+            if kind == "bits":
+                assert type(arg) is np.ndarray and arg.dtype == np.int32
+                assert arg.shape == (k_pad, depth)
+                assert not arg[k:].any()        # the bucket's filler
+            else:
+                assert isinstance(arg, jax.Array)
+                assert arg.shape[0] == k_pad
+        # Each member's own bounds, row by row.
+        lows = [a for sp, a in zip(leaves0, args) if sp[0] == "bits"][0]
+        assert len({tuple(r) for r in lows[:k].tolist()}) == k
+
+
 def test_concurrent_filtered_minmax_fuse(env):
     """Min/Max coalescing: shared plane stack, per-query filters, the
     global bit-descent vmapped over the query axis — results equal the

@@ -8,10 +8,13 @@ windows at the fields' ends and the plan-time shortcuts included; and a
 import json
 import os
 
+import jax
+import numpy as np
 import pytest
 
 from perfbench.datagen import ssb
-from perfbench.lib.serverproc import Client
+from perfbench.lib import pql
+from perfbench.lib.serverproc import Client, compile_calls
 from perfbench.reference import ssb_flight1
 from pilosa_tpu.server.server import Server
 
@@ -211,3 +214,131 @@ def test_an_untraced_sum_emits_no_span(served, monkeypatch):
     assert _ask(client, query)["results"] == [reference.answer(query)]
     assert made == []
     assert ex.bsi_prelude["bsiPreludeMisses"] == before + 1
+
+
+# ------------------------- predicate bits: host arrays with the launch
+
+def _spied_operands(server, client, monkeypatch, query):
+    """The operands the batched Sum's program was called with, and the
+    leaf specs they were built from (the planes of the summed field go
+    first and are no leaf)."""
+    from pilosa_tpu import executor as executor_mod
+    from pilosa_tpu.pql.parser import parse
+
+    ex = server.executor
+    ex._force_path = "batched"
+    seen = []
+    real = executor_mod._run_outputs
+
+    def spy(fn, stacks):
+        seen.append(list(stacks))
+        return real(fn, stacks)
+
+    with monkeypatch.context() as patch:
+        patch.setattr(executor_mod, "_run_outputs", spy)
+        got = _ask(client, query)["results"]
+    (stacks,) = seen
+    leaves = ex._co_bsi_resolve("ssb", parse(query).calls[0])[5]
+    assert len(leaves) == len(stacks) - 1
+    return got, leaves, stacks
+
+
+@pytest.mark.parametrize("dates,d,q,depths", [
+    pytest.param(_bitmap("d_year", 1995), ">< [2, 4]", "< 24", [4, 4, 6],
+                 id="Q1.1"),
+    pytest.param(_bitmap("d_weeknuminyear", 9) + ", "
+                 + _bitmap("d_year", 1995), ">< [3, 5]", ">< [27, 36]",
+                 [4, 4, 6, 6], id="Q1.3"),
+])
+def test_predicate_bits_reach_the_program_as_host_arrays(
+        served, monkeypatch, dates, d, q, depths):
+    """A ``bits`` leaf's operand is a NumPy int32[depth] that the jitted
+    call uploads: an eager ``jnp.asarray`` would be a launched program
+    of its own before the scan (PR 32)."""
+    server, client, reference = served
+    query = SUM.format(dates=dates, d=d, q=q)
+    got, leaves, stacks = _spied_operands(server, client, monkeypatch, query)
+    assert got == [reference.answer(query)]
+    bits = [(sp, st) for sp, st in zip(leaves, stacks[1:])
+            if sp[0] == "bits"]
+    assert [sp[2] for sp, _ in bits] == depths
+    for sp, st in bits:
+        assert type(st) is np.ndarray
+        assert st.dtype == np.int32 and st.shape == (sp[2],)
+        assert st.tolist() == list(sp[1])
+    for st in stacks:
+        if isinstance(st, jax.Array):
+            assert st.ndim >= 2
+        else:
+            assert type(st) is np.ndarray and st.ndim == 1
+    # Rows and planes stay on the device.
+    assert sum(isinstance(st, jax.Array) for st in stacks) \
+        == len(stacks) - len(bits)
+
+
+def test_a_second_query_of_a_served_shape_compiles_nothing(served,
+                                                           monkeypatch):
+    """The bits travel as operands of the same aval, so distinct bounds
+    share the executable: ``compileCalls`` of /debug/kernels stands
+    still, and the memoised prelude hands the same host arrays back."""
+    server, client, reference = served
+    server.executor._force_path = "batched"
+    month = _bitmap("d_yearmonthnum", 199503)
+    first = SUM.format(dates=month, d=">< [2, 4]", q=">< [11, 20]")
+    second = SUM.format(dates=month, d=">< [6, 8]", q=">< [31, 40]")
+    assert _ask(client, first)["results"] == [reference.answer(first)]
+    before = compile_calls(client)[:2]
+    assert _ask(client, second)["results"] == [reference.answer(second)]
+    assert compile_calls(client)[:2] == before
+    # A repeat is a prelude hit: the memo pinned host arrays.
+    got, leaves, stacks = _spied_operands(server, client, monkeypatch,
+                                          second)
+    assert got == [reference.answer(second)]
+    assert compile_calls(client)[:2] == before
+    assert [type(st) for sp, st in zip(leaves, stacks[1:])
+            if sp[0] == "bits"] == [np.ndarray] * 4
+
+
+# -------------------------- every comparison, every aggregate under it
+
+OPS = {"==": "== 17", "!=": "!= 17", "<": "< 17", "<=": "<= 17",
+       ">": "> 33", ">=": ">= 33", "><": ">< [12, 29]"}
+RANGE = 'Range(frame="lo", lo_quantity {cond})'
+AGGREGATES = {
+    "Count": "Count(" + RANGE + ")",
+    "Sum": "Sum(" + RANGE + ', frame="lo", field="lo_revrate")',
+    "Min": "Min(" + RANGE + ', frame="lo", field="lo_quantity")',
+    "Max": "Max(" + RANGE + ', frame="lo", field="lo_quantity")',
+}
+
+
+def _from_the_cube(reference, aggregate, cond):
+    _, quantities = reference.axes["lo_quantity"]
+    ((_, _, parsed),) = pql.conditions(
+        pql.parse(RANGE.format(cond=cond)))
+    picked = ssb_flight1.select(parsed, quantities)
+    counts = reference.cube.counts[:, :, picked].sum(axis=(0, 1))
+    if aggregate == "Count":
+        return int(counts.sum())
+    if aggregate == "Sum":
+        return {"sum": int(reference.cube.sums[:, :, picked].sum()),
+                "count": int(counts.sum())}
+    held = counts.nonzero()[0]
+    at = held[0] if aggregate == "Min" else held[-1]
+    return {"sum": int(quantities[picked][at]), "count": int(counts[at])}
+
+
+@pytest.mark.parametrize("aggregate", list(AGGREGATES))
+@pytest.mark.parametrize("op", list(OPS))
+def test_every_comparison_under_every_aggregate(served, op, aggregate):
+    """Batched against serial against the reference's cube: the bits of
+    a comparison are host arrays on the batched path, whatever reads
+    them (count, sum and descent programs)."""
+    server, client, reference = served
+    query = AGGREGATES[aggregate].format(cond=OPS[op])
+    want = _from_the_cube(reference, aggregate, OPS[op])
+    for path in ("batched", "serial"):
+        server.executor._force_path = path
+        doc = _ask(client, query, profile=True)
+        assert doc["results"] == [want], path
+        assert doc["profile"]["resources"]["servedBy"] == {path: 1}
