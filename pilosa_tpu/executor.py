@@ -341,6 +341,11 @@ class Executor:
         # (_prelude_record), process lifetime, under _cache_mu:
         # /debug/vars, beside the per-query keys.
         self.bsi_prelude = {"bsiPreludeHits": 0, "bsiPreludeMisses": 0}
+        # Views the covers of planned time Ranges asked for, and the
+        # operands they were bucketed to (_batched_plan), process
+        # lifetime, under _cache_mu: /debug/vars, beside the per-query
+        # keys.
+        self.range_cover = {"rangeCoverViews": 0, "rangeCoverOperands": 0}
         # Hinted handoff: writes skipped because a replica was DOWN,
         # keyed by host, replayed on rejoin (anti-entropy remains the
         # backstop for hints lost to a coordinator restart).
@@ -1047,6 +1052,16 @@ class Executor:
         """Candidate counts bucket to a power of two: the jitted TopN
         evaluator re-traces O(log R) times, not per candidate set."""
         return 1 << max(n_ids - 1, 0).bit_length()
+
+    @staticmethod
+    def _cover_bucket(n_views):
+        """Operands a time Range's cover of ``n_views`` views is
+        brought to: a power of two up to 16, then also the halfway
+        steps (2, 4, 8, 16, 24, 32, 48, 64, 96, ...). The Union's width
+        is part of the plan and so of the program's key: covers of 1
+        to 63 views reach eight programs, not one a size."""
+        p = 1 << max(n_views - 1, 1).bit_length()
+        return p * 3 // 4 if p > 16 and n_views <= p * 3 // 4 else p
 
     def _serial_exec(self, node_slices, map_fn, reduce_fn, deadline=None):
         """Per-slice loop. With ``deadline`` (a perf_counter instant,
@@ -2085,8 +2100,9 @@ class Executor:
         arg combinations surface their errors from the serial path).
         Bitmap leaves carry their own orientation: columnID leaves read
         the inverse view, exactly like executeBitmapSlice. Time Ranges
-        expand to a Union over the time-view cover's leaves; BSI
-        conditions plan via _plan_bsi_range."""
+        expand to a Union over the time-view cover's leaves, at a
+        bucketed width (_cover_bucket); BSI conditions plan via
+        _plan_bsi_range."""
         if call.name == "Bitmap":
             idx = self.holder.index(index)
             frame_name = call.args.get("frame") or DEFAULT_FRAME
@@ -2128,13 +2144,25 @@ class Executor:
                 end_t = datetime.strptime(end, TIME_FORMAT)
             except ValueError:
                 return None
-            views = tq.views_by_time_range(VIEW_STANDARD, start_t, end_t,
-                                           frame.time_quantum)
+            with tracing.span("range.cover", frame=frame_name) as csp:
+                views = tq.views_by_time_range(VIEW_STANDARD, start_t,
+                                               end_t, frame.time_quantum)
+                # Union is idempotent: the slots past the cover hold
+                # its views again, so the plan's text (and the program
+                # it keys) depends on the bucket alone.
+                width = self._cover_bucket(len(views)) if views else 0
+                csp.tag(views=len(views), operands=width)
             if not views:
                 return None
+            querystats.add("rangeCoverViews", len(views))
+            querystats.add("rangeCoverOperands", width)
+            with self._cache_mu:
+                self.range_cover["rangeCoverViews"] += len(views)
+                self.range_cover["rangeCoverOperands"] += width
             kids = []
-            for v in views:
-                leaves.append(("row", frame_name, row_id, v))
+            for k in range(width):
+                leaves.append(("row", frame_name, row_id,
+                               views[k % len(views)]))
                 kids.append(("leaf", len(leaves) - 1))
             return ("Union", kids)
         if call.name in self._BATCH_OPS and call.children:

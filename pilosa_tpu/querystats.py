@@ -99,6 +99,10 @@ MAX_HEDGE_LEGS = 64
 # by the plan AND its leaves, which hold a condition's predicate bits
 # and a Bitmap's row, so a never-seen query reads a miss
 # (executor._prelude_record).
+# rangeCoverViews / rangeCoverOperands count the views that the covers
+# of a query's time Ranges asked for, and the operands the batched plan
+# gave them once each cover was bucketed (executor._cover_bucket): the
+# difference is operands read twice.
 KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "cacheMisses", "deviceTransfers", "deviceTransferBytes",
         "fanoutCalls", "fanoutRetries", "planMs", "planCacheHit",
@@ -107,7 +111,8 @@ KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "leafMemoHits", "leafMemoMisses", "topnRowsScanned",
         "topnCandidates", "topnKept", "topnRecountsSkipped",
         "topnProbeFromMirror", "topnProbeFromHost",
-        "bsiPreludeHits", "bsiPreludeMisses")
+        "bsiPreludeHits", "bsiPreludeMisses",
+        "rangeCoverViews", "rangeCoverOperands")
 
 
 class QueryStats:

@@ -1829,6 +1829,7 @@ class Handler:
         data.update(self.executor.leaf_memo)
         data.update(self.executor.topn_probe)
         data.update(self.executor.bsi_prelude)
+        data.update(self.executor.range_cover)
         if self.tracer.enabled:
             data["tracing"] = self.tracer.summary()
         # One consistent snapshot: the qos/faults/memory groups answer
