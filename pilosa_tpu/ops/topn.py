@@ -100,7 +100,8 @@ def fetch_counts(fn, matrix, *args, op=None):
     """The per-fragment TopN call as ``Fragment.top`` makes it: enqueue,
     device wait and the copy of the counts to the host in one
     expression; under a trace cut where the time can hide, into
-    ``top.kernel`` (the jitted call until it returns), ``top.wait``
+    ``top.kernel`` (the jitted call until it returns, tagged
+    ``scanned`` = the rows of the operand it was given), ``top.wait``
     (``block_until_ready``) and ``top.fetch`` (``np.asarray``). With
     ``op``, a dispatch that grew ``fn``'s executable cache is noted as
     that op's compile in the kernel observatory (``/debug/kernels``)."""
@@ -108,7 +109,7 @@ def fetch_counts(fn, matrix, *args, op=None):
     if tracing.active_span() is None:
         counts = np.asarray(fn(matrix, *args))
     else:
-        with tracing.span("top.kernel"):
+        with tracing.span("top.kernel", scanned=matrix.shape[0]):
             out = fn(matrix, *args)
         with tracing.span("top.wait"):
             out.block_until_ready()

@@ -85,7 +85,10 @@ MAX_HEDGE_LEGS = 64
 # never-seen query over a quiet index reads misses 0.
 # topnRowsScanned counts the rows whose popcounts a TopN program took
 # on the device (a fragment's rows a scan of it, candidates x slices
-# in the batched program); topnCandidates the ids TopN's phase 1 gave
+# in the batched program): the rows that hold data, not the operand's.
+# A scan's operand is the fragment's whole mirror, a power of two of
+# rows with zeros past the last (the ``scanned`` tag of ``top.kernel``
+# says how many). topnCandidates the ids TopN's phase 1 gave
 # its exact re-query; topnKept the pairs a TopN call returned;
 # topnRecountsSkipped is 1 for a TopN over one slice, answered from
 # phase 1 alone (its pairs are the totals: executor._execute_topn).

@@ -1887,7 +1887,11 @@ class Fragment:
     def device_matrix(self):
         """uint32[cap, 2·width] HBM copy, refreshed lazily — NARROW
         when the fragment is (width ≤ 32768 device words); callers must
-        trim full-slice operands to match, as top() does."""
+        trim full-slice COLUMN operands to match, as top() does its
+        host src. Its rows are the mirror's ``_cap``, a power of two,
+        zero past ``len(_phys_rows)``: scan the array as it is and cut
+        the counts on the host, as top() does (a device-side ``[:n]``
+        is a program of its own that copies the matrix)."""
         with self.mu:
             if self._cap == 0:
                 return jnp.zeros((0, WORDS_PER_SLICE), dtype=jnp.uint32)
@@ -1925,16 +1929,18 @@ class Fragment:
             self._dev_version = self._version
             return self._dev
 
-    def _row_counts_device(self, n_phys):
-        """Device copy of the per-row cardinalities, memoized against
-        the mutation version — the Tanimoto denominator reads it every
-        query and would otherwise be uploaded per query. The
-        version check subsumes every invalidation site (any mutation
-        bumps ``_version``); callers hold ``self.mu``."""
+    def _row_counts_device(self):
+        """int32[cap] device copy of the per-row cardinalities (one a
+        row of ``device_matrix()``, zero past the last physical row),
+        memoized against the mutation version — the Tanimoto
+        denominator reads it every query and would otherwise be
+        uploaded per query. The version check subsumes every
+        invalidation site (any mutation bumps ``_version``); callers
+        hold ``self.mu``."""
         rc = self._rc_dev
         if (rc is None or rc[0] != self._version
-                or rc[1].shape[0] != n_phys):
-            arr = jnp.asarray(self._row_counts[:n_phys].astype(np.int32))
+                or rc[1].shape[0] != self._cap):
+            arr = jnp.asarray(self._row_counts.astype(np.int32))
             self._rc_dev = rc = (self._version, arr)
         return rc[1]
 
@@ -2992,6 +2998,26 @@ class Fragment:
         index (a traced scalar) and takes ``|src|`` from the row
         counts: nothing of the probe crosses to the host. The ``top.src``
         span is tagged ``probe`` = ``mirror`` | ``host`` accordingly.
+
+        The scan's operands have the mirror's shape, not the row
+        count's: ``device_matrix()`` as the fragment holds it (``_cap``
+        rows, a power of two) and ``_cap`` row counts, with nothing
+        between the mirror and the program. A device-side ``[:n_phys]``
+        is a program of its own that reads and writes the whole matrix
+        before every scan (2.3x the scan's device time at 500,000
+        rows, PR 33's trace) and a new shape to compile for every
+        appended row; this way a scan compiles when the mirror
+        doubles, as it is uploaded anew then anyway. The counts come
+        back ``_cap`` long and are cut to the physical rows on the host
+        (a view) before selection. The padded rows cannot change an
+        answer: a row past ``n_phys`` is all zero in ``_matrix``
+        (``_grow_rows_locked`` allocates zeros, rows are only ever
+        appended) and has row count 0, so its intersection is 0;
+        ``tanimoto_keep(0, 0, src_n, T)`` is ``0 > T*src_n``, false;
+        ungated, a count of 0 fails ``_top_select``'s ``counts > 0``;
+        and the host cut drops them before selection anyway. A probe's
+        ``phys`` is below ``n_phys``, so ``row_at`` never reads a
+        padded row as the probe.
         """
         from pilosa_tpu.ops import topn as topn_ops
         from pilosa_tpu.storage.cache import NopCache
@@ -3014,9 +3040,9 @@ class Fragment:
                 return []
             if has_src:
                 # Only the src-intersection path reads the device
-                # matrix; building (and slicing) it for the src-less
-                # cache walk cost a device upload + dispatch per
-                # fragment per query for data the counts never touch.
+                # matrix; refreshing it for the src-less cache walk
+                # cost a device upload per fragment per query for data
+                # the counts never touch.
                 with tracing.span("top.src", rows=n_phys,
                                   probe="mirror" if from_mirror else "host"):
                     if from_mirror:
@@ -3041,7 +3067,7 @@ class Fragment:
                         probe = jnp.asarray(np.ascontiguousarray(
                             src_words[base : base + self._w64]
                         ).view(np.uint32))
-                    matrix = self.device_matrix()[:n_phys]
+                    matrix = self.device_matrix()
                 querystats.add("topnRowsScanned", n_phys)
                 if not opt.tanimoto_threshold:
                     counts = topn_ops.fetch_counts(
@@ -3050,15 +3076,16 @@ class Fragment:
                 elif from_mirror:
                     counts = topn_ops.fetch_counts(
                         topn_ops.tanimoto_masked_counts_at, matrix, probe,
-                        self._row_counts_device(n_phys),
+                        self._row_counts_device(),
                         opt.tanimoto_threshold,
                         op="topn_tanimoto_frag_probe")
                 else:
                     counts = topn_ops.fetch_counts(
                         topn_ops.tanimoto_masked_counts, matrix, probe,
-                        self._row_counts_device(n_phys),
+                        self._row_counts_device(),
                         int(np.bitwise_count(src_words).sum()),
                         opt.tanimoto_threshold, op="topn_tanimoto_frag")
+                counts = counts[:n_phys]
             else:
                 counts = self._row_counts[:n_phys].copy()
 

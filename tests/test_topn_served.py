@@ -721,3 +721,186 @@ def test_a_profile_says_where_the_probe_came_from(server):
         totals = json.loads(resp.read())
     assert (totals["topnProbeFromMirror"], totals["topnProbeFromHost"]) \
         == (1, 1)
+
+
+# ------- the scan's operands have the mirror's shape, not the rows' (PR 34)
+
+# Row ``100 + w`` holds columns 0..w-1; row 7 is a copy of the probe
+# (row 110, ten columns). Against the probe a row reads inter min(w, 10)
+# over denom max(w, 10): widths 5, 7 and 9 lie exactly on the gates of
+# 50, 70 and 90 (100*w == T*10, not kept), and the five rows of ten
+# columns or more tie at a count of 10, so a cut at 2 falls inside a tie
+# and is decided by id. Nine rows leave 7 of the mirror's 16 padded with
+# zeros; sixteen fill it.
+MIRROR_PROBE = 110
+MIRROR_WIDTHS = {9: (3, 5, 7, 9, 10, 12, 14, 20),
+                 16: (1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 14, 15, 20)}
+
+
+def _mirror_rows(n_rows):
+    rows = {100 + w: set(range(w)) for w in MIRROR_WIDTHS[n_rows]}
+    rows[7] = set(range(10))
+    assert len(rows) == n_rows
+    return rows
+
+
+def _open_fragment(path, rows):
+    import os
+
+    from pilosa_tpu.storage.fragment import Fragment
+
+    f = Fragment(os.path.join(str(path), "frag"), "i", "f", "standard",
+                 0).open()
+    f.import_bits([r for r, cs in rows.items() for _ in cs],
+                  [c for cs in rows.values() for c in cs])
+    return f
+
+
+@pytest.fixture(scope="module", params=[9, 16], ids=["9of16", "16of16"])
+def mirror_fragment(request, tmp_path_factory):
+    rows = _mirror_rows(request.param)
+    f = _open_fragment(tmp_path_factory.mktemp("mirror_fragment"), rows)
+    assert (len(f._phys_rows), f._cap) == (request.param, 16)
+    yield f, rows
+    f.close()
+
+
+@pytest.fixture
+def scan_operands(monkeypatch):
+    """What ``Fragment.top`` hands ``fetch_counts`` and gets back, a
+    call: (matrix, the other operands, the counts)."""
+    from pilosa_tpu.ops import topn as topn_ops
+
+    calls, real = [], topn_ops.fetch_counts
+
+    def spy(fn, matrix, *args, **kw):
+        counts = real(fn, matrix, *args, **kw)
+        calls.append((matrix, args, counts))
+        return counts
+
+    monkeypatch.setattr(topn_ops, "fetch_counts", spy)
+    return calls
+
+
+# (id, TopOptions' arguments, brute_topn's): ``n`` below and above the
+# survivors; explicit row ids are never cut at ``n`` (a padded row can
+# be asked for by no id); ``min_threshold`` over the counts.
+MIRROR_ASKS = [
+    ("n2", dict(n=2), dict(n=2)),
+    ("n50", dict(n=50), dict(n=50)),
+    ("row_ids", dict(n=2, row_ids=[7, 105, 107, 109, 112, 120, 999]),
+     dict(allowed={7, 105, 107, 109, 112, 120, 999})),
+    ("min_threshold", dict(n=50, min_threshold=9), dict(n=50, threshold=9)),
+]
+
+
+@pytest.mark.parametrize("name, ask, ref", MIRROR_ASKS,
+                         ids=[a[0] for a in MIRROR_ASKS])
+@pytest.mark.parametrize("gate", [0, 50, 70, 90])
+@pytest.mark.parametrize("probe", ["src_row", "src"])
+def test_a_scan_of_the_whole_mirror_equals_brute_force(
+        mirror_fragment, scan_operands, probe, gate, name, ask, ref):
+    """Every form ``Fragment.top`` takes with a src, on a mirror with
+    zero rows past the last physical one and on a full one: the list
+    equals brute force, the program was given the mirror itself and
+    ``_cap`` row counts, and ``topnRowsScanned`` counts the physical
+    rows."""
+    from pilosa_tpu.storage.fragment import TopOptions
+
+    f, rows = mirror_fragment
+    src = ({"src_row": MIRROR_PROBE} if probe == "src_row"
+           else {"src": np.array(f.row_words(MIRROR_PROBE))})
+    with querystats.scope(querystats.QueryStats()) as qs:
+        got = f.top(TopOptions(tanimoto_threshold=gate, **src, **ask))
+        stats = qs.to_dict()
+    want = brute_topn(rows, src=rows[MIRROR_PROBE], tanimoto=gate, **ref)
+    assert got == want and want
+    on_gate = 100 + gate // 10
+    assert on_gate not in dict(got) and (not gate or on_gate in rows)
+    if name == "n2":
+        assert [r for r, _ in got] == [7, MIRROR_PROBE]
+
+    (matrix, args, counts), = scan_operands
+    assert matrix is f._dev
+    assert matrix.shape == (f._cap, 2 * f._w64) == (16, 2 * f._w64)
+    assert counts.shape == (16,) and not counts[len(rows):].any()
+    if gate:
+        assert args[1].shape == (16,) and args[1] is f._rc_dev[1]
+    assert stats["topnRowsScanned"] == len(rows)
+
+
+def test_an_appended_row_is_scanned_by_the_program_already_compiled(
+        tmp_path, scan_operands):
+    """The scan's jit signature is the mirror's capacity: a tenth row
+    in a mirror of 16 is seen by the next ``top`` and compiles nothing;
+    the row that doubles the mirror to 32 is answered exactly too."""
+    from pilosa_tpu.ops import bitops
+    from pilosa_tpu.ops import topn as topn_ops
+    from pilosa_tpu.storage.fragment import TopOptions
+
+    rows = _mirror_rows(9)
+    f = _open_fragment(tmp_path, rows)
+    programs = (topn_ops.tanimoto_masked_counts_at,
+                topn_ops.tanimoto_masked_counts,
+                bitops._count_and_rows_at_impl, bitops._count_and_rows_impl)
+
+    def check(cap):
+        for gate in (0, 70):
+            want = brute_topn(rows, src=rows[MIRROR_PROBE], n=4,
+                              tanimoto=gate)
+            for src in ({"src_row": MIRROR_PROBE},
+                        {"src": np.array(f.row_words(MIRROR_PROBE))}):
+                assert f.top(TopOptions(n=4, tanimoto_threshold=gate,
+                                        **src)) == want
+                matrix, _, counts = scan_operands[-1]
+                assert matrix is f._dev and matrix.shape[0] == cap
+                assert counts.shape == (cap,)
+
+    def append(row, n_cols):
+        for col in range(n_cols):
+            f.set_bit(row, col)
+        rows[row] = set(range(n_cols))
+
+    try:
+        check(16)
+        sizes = [fn._cache_size() for fn in programs]
+        assert all(sizes)
+        append(3, 10)                 # a third copy of the probe, lowest id
+        assert (len(f._phys_rows), f._cap) == (10, 16)
+        check(16)
+        assert f.top(TopOptions(n=1, src_row=MIRROR_PROBE)) == [(3, 10)]
+        assert [fn._cache_size() for fn in programs] == sizes
+        for row in range(300, 307):   # seven more: the 17th doubles it
+            append(row, row - 295)
+        assert (len(f._phys_rows), f._cap) == (17, 32)
+        check(32)
+    finally:
+        f.close()
+
+
+def test_a_profile_says_how_many_rows_the_scan_was_given(server,
+                                                         monkeypatch):
+    """Forty rows in a mirror of 64: ``top.kernel`` is tagged
+    ``scanned`` 64, ``top.src`` and the counter still say 40, whether
+    the probe came from the mirror or from host words; an unprofiled
+    request builds no span for any of it."""
+    server.executor._force_path = "serial"
+    frag = server.holder.fragment("i", "f", "standard", 0)
+    for kind, child in (("mirror", 'Bitmap(frame="f", rowID=0)'),
+                        ("host", 'Union(Bitmap(frame="f", rowID=0))')):
+        doc = _post(server, "/index/i/query?profile=true",
+                    f'TopN({child}, frame="f", n=50, tanimotoThreshold=70)')
+        tags = {sp["name"]: sp["tags"] for sp in doc["profile"]["spans"]
+                if sp["name"].startswith("top.")}
+        assert (len(frag._phys_rows), frag._cap) == (40, 64)
+        assert tags["top.kernel"] == {"scanned": 64}
+        assert tags["top.src"] == {"rows": 40, "probe": kind}
+        assert tags["top.select"] == {"rows": 40}
+        assert doc["profile"]["resources"]["topnRowsScanned"] == 40
+    built = []
+    real_init = tracing.Span.__init__
+    monkeypatch.setattr(
+        tracing.Span, "__init__",
+        lambda self, *a, **k: (built.append(a), real_init(self, *a, **k))[1])
+    out = _post(server, "/index/i/query", TOPN.format(p=0, t=70))
+    assert out["results"][0][0]["id"] == 0 and not built
