@@ -94,9 +94,10 @@ def _run_count(fn, stacks):
 
 def _run_count_split(fn, stacks):
     """The same call under a trace, cut where the time can hide: the
-    jitted call until it returns (enqueue), the wait for the device,
-    and the copy to the host."""
-    with tracing.span("kernel.dispatch"):
+    jitted call until it returns (enqueue, tagged ``program`` = the
+    name the launch carries in a device trace after ``jit_``), the wait
+    for the device, and the copy to the host."""
+    with tracing.span("kernel.dispatch", program=fn.__name__):
         out = fn(*stacks)
     with tracing.span("kernel.wait"):
         out.block_until_ready()
@@ -114,7 +115,7 @@ def _run_outputs(fn, stacks):
 def _run_outputs_split(fn, stacks):
     """The same call under a trace, cut as ``_run_count_split`` cuts a
     Count."""
-    with tracing.span("kernel.dispatch"):
+    with tracing.span("kernel.dispatch", program=fn.__name__):
         outs = fn(*stacks)
     with tracing.span("kernel.wait"):
         for o in outs:
@@ -1063,12 +1064,18 @@ class Executor:
         p = 1 << max(n_views - 1, 1).bit_length()
         return p * 3 // 4 if p > 16 and n_views <= p * 3 // 4 else p
 
-    def _serial_exec(self, node_slices, map_fn, reduce_fn, deadline=None):
+    def _serial_exec(self, node_slices, map_fn, reduce_fn, deadline=None,
+                     probe=None):
         """Per-slice loop. With ``deadline`` (a perf_counter instant,
-        set only for cost-model serial PROBES that have a batched
+        set only for serial picks of the cost model that have a batched
         alternative), returns SERIAL_ABORT as soon as the loop runs
         past it — partial results are safely discarded because every
         read path is side-effect free.
+
+        ``probe`` is the ``path.probe`` span of a look at the loser
+        (_run_path): the loop then opens no ``slice`` span of its own
+        (a probe is one span, not one a slice) and tags the probe with
+        the ``slices`` it finished.
 
         Independently, the REQUEST deadline (qos.deadline_scope,
         stamped by the handler from X-Pilosa-Deadline / ?timeout=) is
@@ -1080,7 +1087,7 @@ class Executor:
         # must not pay a span call (kwargs dict) per slice. The active
         # span can't change across iterations — spans opened inside
         # map_fn restore on exit.
-        traced = tracing.active_span() is not None
+        traced = probe is None and tracing.active_span() is not None
         req_deadline = qos.current_deadline()
         # Hoisted like the trace check: with faults disabled the loop
         # pays nothing (the chaos suite's knob for making a query
@@ -1089,6 +1096,8 @@ class Executor:
         for i, s in enumerate(node_slices):
             if (deadline is not None and i
                     and time.perf_counter() > deadline):
+                if probe is not None:
+                    probe.tag(slices=i)
                 return SERIAL_ABORT
             if (req_deadline is not None and i
                     and time.monotonic() > req_deadline):
@@ -1101,6 +1110,8 @@ class Executor:
             else:
                 v = map_fn(s)
             result = reduce_fn(result, v)
+        if probe is not None:
+            probe.tag(slices=len(node_slices))
         return result
 
     def _local_exec(self, call, node_slices, map_fn, reduce_fn, batch_fn):
@@ -1148,16 +1159,21 @@ class Executor:
                 querystats.note_tier("batched")
             return out
         with tracing.span("exec.route") as rsp:
-            choice, st, n, b = self._path_choice(call, node_slices)
+            choice, probe, st, n, b = self._path_choice(call, node_slices)
             if rsp is not tracing.NOP_SPAN:
                 rsp.tag(choice=choice)
-        return self._run_path(choice, st, n, b, node_slices, map_fn,
+        return self._run_path(choice, probe, st, n, b, node_slices, map_fn,
                               reduce_fn, batch_fn)
 
     def _path_choice(self, call, node_slices):
         """The path model's pick for one (call structure, slice-count
-        bucket): (choice, the bucket's stat entry, its query count
-        before this one, its batched minimum)."""
+        bucket): (choice, whether the pick is a PROBE, the bucket's
+        stat entry, its query count before this one, its batched
+        minimum). A probe is a measurement, not the model's steady
+        choice: a turn of the exploration, the first serial sample, or
+        the 64th query's look at the losing path. The ``batched`` those
+        branches fall back to where the slice list is too long to probe
+        serially is the steady choice and is not one."""
         key = (self._call_shape(call), max(len(node_slices), 1).bit_length())
         with self._path_mu:
             st = self._path_stats.get(key)
@@ -1171,6 +1187,7 @@ class Executor:
             probe_ok = len(node_slices) <= self.SERIAL_PROBE_MAX_SLICES
 
             b, s = st.get("b"), st.get("s")
+            probe = False
             if st.get("inel", 0) >= 2 and n % 64 != 63:
                 # Batch planning declined twice in a row (structural
                 # ineligibility) — skip the doomed re-plan; the rare
@@ -1184,51 +1201,83 @@ class Executor:
                 # choice — one noisy sample must not park the model on
                 # the wrong path.
                 choice = "serial" if n % 2 else "batched"
+                probe = True
             elif s is None:
                 choice = "serial" if probe_ok else "batched"
+                probe = probe_ok
             elif n % 64 == 63:
                 # Re-measure the currently losing path.
                 choice = ("batched" if s <= b
                           else ("serial" if probe_ok else "batched"))
+                probe = s <= b or probe_ok
             else:
                 # Slight hysteresis so exact ties don't flap between
                 # paths (flapping between near-equal paths costs
                 # nothing anyway — the minima keep both honest).
                 choice = ("serial" if (s < 0.98 * b and probe_ok)
                           else "batched")
-        return choice, st, n, b
+        return choice, probe, st, n, b
 
-    def _run_path(self, choice, st, n, b, node_slices, map_fn, reduce_fn,
-                  batch_fn):
-        """Serve by the chosen path and record what it took."""
+    def _run_path(self, choice, probe, st, n, b, node_slices, map_fn,
+                  reduce_fn, batch_fn):
+        """Serve by the chosen path and record what it took. A PROBE
+        (_path_choice) runs its attempt under span ``path.probe`` (tags
+        ``path``, ``outcome`` = ``finished`` or ``aborted``; a serial
+        one also ``deadline_ms`` and ``slices``) and is counted, traced
+        or not: ``pathProbes`` / ``pathProbeAborts`` of the request
+        (querystats) and ``probes`` / ``probeAborts`` / ``probeMs`` of
+        the call shape (path_model_snapshot). An attempt is aborted
+        when the serial loop ran past its deadline or the batched
+        program declined; the request is then served by the other
+        path, outside the span."""
         t0 = time.perf_counter()
         if choice.startswith("serial"):
             deadline = None
             if choice == "serial" and b is not None:
-                # A PROBE with a batched alternative: once the loop has
-                # provably lost (5x the batched minimum, floored so a
-                # microsecond batched time can't abort a probe that
-                # deserves a fair sample), abandon it and serve the
-                # query batched below. The pessimistic elapsed still
-                # records as a serial sample, so the model converges
-                # away from serial without ever paying its full cost.
+                # A serial pick with a batched alternative: once the
+                # loop has provably lost (5x the batched minimum,
+                # floored so a microsecond batched time can't abort a
+                # run that deserves a fair sample), abandon it and
+                # serve the query batched below. The pessimistic
+                # elapsed still records as a serial sample, so the
+                # model converges away from serial without ever paying
+                # its full cost.
                 deadline = t0 + max(5.0 * b, 0.05)
-            out = self._serial_exec(node_slices, map_fn, reduce_fn,
-                                    deadline)
+            if probe:
+                with tracing.span(
+                        "path.probe", path="serial",
+                        deadline_ms=round((deadline - t0) * 1000, 3)) as psp:
+                    out = self._serial_exec(node_slices, map_fn, reduce_fn,
+                                            deadline, psp)
+                    probe = self._probe_outcome(psp, out is SERIAL_ABORT)
+            else:
+                out = self._serial_exec(node_slices, map_fn, reduce_fn,
+                                        deadline)
             if out is not SERIAL_ABORT:
                 if choice == "serial":  # skip ineligibility-forced runs
-                    self._record_path(st, "s", time.perf_counter() - t0)
+                    self._record_path(st, "s", time.perf_counter() - t0,
+                                      probe)
                 querystats.note_tier("serial")
                 return out
-            # Aborted probe: the elapsed (already >= 5x the batched
-            # minimum) is serial's sample, and the query falls through
-            # to the batched path. Restart the clock so the batched
-            # minimum isn't polluted by the aborted probe's time.
-            self._record_path(st, "s", time.perf_counter() - t0)
+            # Aborted: the elapsed (already >= 5x the batched minimum)
+            # is serial's sample, and the query falls through to the
+            # batched path. Restart the clock so the batched minimum
+            # isn't polluted by the aborted run's time.
+            self._record_path(st, "s", time.perf_counter() - t0, probe)
             t0 = time.perf_counter()
-        out = self._try_batch(batch_fn, node_slices)
+            probe = False
+        if probe:
+            with tracing.span("path.probe", path="batched") as psp:
+                out = self._try_batch(batch_fn, node_slices)
+                probe = self._probe_outcome(
+                    psp, out is None or out is BATCH_TRANSIENT)
+        else:
+            out = self._try_batch(batch_fn, node_slices)
         if out is None or out is BATCH_TRANSIENT:
-            t0 = time.perf_counter()
+            now = time.perf_counter()
+            if probe:  # a declined program is no sample of a path
+                self._record_path(st, None, now - t0, probe)
+            t0 = now
             querystats.note_tier("serial")
             res = self._serial_exec(node_slices, map_fn, reduce_fn)
             if out is None:
@@ -1243,14 +1292,37 @@ class Executor:
         with self._path_mu:
             st["inel"] = 0
         if n > 0:  # skip the compile-laden first sample
-            self._record_path(st, "b", time.perf_counter() - t0)
+            self._record_path(st, "b", time.perf_counter() - t0, probe)
         querystats.note_tier("batched")
         return out
 
-    def _record_path(self, st, path, elapsed):
+    def _record_path(self, st, path, elapsed, probe=False):
+        """One sample of ``path``'s wall time into the rolling minimum;
+        ``probe`` (``finished`` | ``aborted``) where the attempt was a
+        look at the loser, counted for the call shape under the same
+        lock. The counts are not persisted: a restarted server starts
+        from 0 (save_path_model)."""
         with self._path_mu:
-            prev = st.get(path)
-            st[path] = elapsed if prev is None else min(prev, elapsed)
+            if path is not None:
+                prev = st.get(path)
+                st[path] = elapsed if prev is None else min(prev, elapsed)
+            if probe:
+                st["probes"] = st.get("probes", 0) + 1
+                st["probe_s"] = st.get("probe_s", 0.0) + elapsed
+                if probe == "aborted":
+                    st["probe_aborts"] = st.get("probe_aborts", 0) + 1
+
+    @staticmethod
+    def _probe_outcome(psp, aborted):
+        """Close the account of one probe attempt: the span's
+        ``outcome`` and the request's counters. Returns the outcome,
+        which ``_record_path`` then counts for the call shape."""
+        outcome = "aborted" if aborted else "finished"
+        psp.tag(outcome=outcome)
+        querystats.add("pathProbes")
+        if aborted:
+            querystats.add("pathProbeAborts")
+        return outcome
 
     @staticmethod
     def _shape_sig(shape):
@@ -1346,6 +1418,12 @@ class Executor:
                                   if "b" in st else None),
                     "serialMs": (round(st["s"] * 1000, 3)
                                  if "s" in st else None),
+                    # The looks at the loser (_run_path): attempts,
+                    # those that did not finish, and their cumulative
+                    # wall time.
+                    "probes": st.get("probes", 0),
+                    "probeAborts": st.get("probe_aborts", 0),
+                    "probeMs": round(st.get("probe_s", 0.0) * 1000, 3),
                 }
         return out
 
@@ -2308,8 +2386,9 @@ class Executor:
                 # dispatches (fn-cache miss, known up front) always
                 # record exactly; steady-state dispatches record
                 # 1-in-OBS_STRIDE with scaled weight — the hit check
-                # already ran, and full per-query bookkeeping here
-                # would eat the 2% observatory budget (obscheck).
+                # already ran, and the stride keeps two clock readings
+                # and a locked note off fifteen warm queries in
+                # sixteen (counts and sums scale, means stay true).
                 self._obs_tick = w = self._obs_tick + 1
                 w = 0 if w % self.OBS_STRIDE else self.OBS_STRIDE
                 if not hit or w:

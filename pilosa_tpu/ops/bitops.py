@@ -191,8 +191,8 @@ def _traced_dispatch(name, fn, *args):
         # post-call cache-size probe (the note_jit_cache delta is the
         # compile detector — exact, per kernel); STEADY notes are
         # stride-sampled with scaled weight so the per-slice serial
-        # dense loop stays inside the 2% observatory budget, while
-        # compile and device-sampled dispatches always record.
+        # dense loop pays a locked note one dispatch in OBS_STRIDE,
+        # while compile and device-sampled dispatches always record.
         sampled = obs.should_sample()
         t0 = time.perf_counter()
         out = fn(*args)

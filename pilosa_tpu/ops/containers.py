@@ -370,8 +370,8 @@ def _jitted(name, builder):
 
 # Serial-cell observation stride: the per-slice compressed count path
 # dispatches one cell PER SLICE, so exact per-call bookkeeping there
-# would eat the 2% observatory budget (make obscheck). 1-in-N calls
-# record with weight N (the statsd |@rate idiom — counts/sums scale,
+# would cost two clock readings and a locked note a slice. 1-in-N
+# calls record with weight N (the statsd |@rate idiom — counts/sums scale,
 # means stay unbiased); the deterministic tick guarantees a sample
 # every N dispatches. Fused LANE cells stay exactly instrumented —
 # they launch once per tick, not per slice.
@@ -982,9 +982,9 @@ def _count_cell(op):
             # coerce to a host int (the int() in _and_count blocks),
             # so every sample is device time. Compile attribution is
             # the first-sample-of-cell rule (note's compiled=None) —
-            # exact jit-cache introspection here would dominate the
-            # 2% observatory budget; the exact probes live on the
-            # bitops and fused-lane paths.
+            # exact jit-cache introspection here, once a slice, would
+            # cost more than the cell it watches; the exact probes
+            # live on the bitops and fused-lane paths.
             t0 = time.perf_counter()
             inter = _and_count(a, b)
             dt = time.perf_counter() - t0

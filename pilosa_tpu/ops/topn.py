@@ -101,7 +101,9 @@ def fetch_counts(fn, matrix, *args, op=None):
     device wait and the copy of the counts to the host in one
     expression; under a trace cut where the time can hide, into
     ``top.kernel`` (the jitted call until it returns, tagged
-    ``scanned`` = the rows of the operand it was given), ``top.wait``
+    ``scanned`` = the rows of the operand it was given and ``program``
+    = ``fn``'s name, which the launch carries in a device trace after
+    ``jit_``), ``top.wait``
     (``block_until_ready``) and ``top.fetch`` (``np.asarray``). With
     ``op``, a dispatch that grew ``fn``'s executable cache is noted as
     that op's compile in the kernel observatory (``/debug/kernels``)."""
@@ -109,7 +111,8 @@ def fetch_counts(fn, matrix, *args, op=None):
     if tracing.active_span() is None:
         counts = np.asarray(fn(matrix, *args))
     else:
-        with tracing.span("top.kernel", scanned=matrix.shape[0]):
+        with tracing.span("top.kernel", scanned=matrix.shape[0],
+                          program=fn.__name__):
             out = fn(matrix, *args)
         with tracing.span("top.wait"):
             out.block_until_ready()

@@ -106,6 +106,10 @@ MAX_HEDGE_LEGS = 64
 # of a query's time Ranges asked for, and the operands the batched plan
 # gave them once each cover was bucketed (executor._cover_bucket): the
 # difference is operands read twice.
+# pathProbes / pathProbeAborts count the attempts a query ran as the
+# path model's look at the loser (span ``path.probe``) and those of
+# them that did not finish: a serial loop past its deadline, a batched
+# program that declined (executor._run_path). 0 on a steady pick.
 KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "cacheMisses", "deviceTransfers", "deviceTransferBytes",
         "fanoutCalls", "fanoutRetries", "planMs", "planCacheHit",
@@ -115,7 +119,8 @@ KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "topnCandidates", "topnKept", "topnRecountsSkipped",
         "topnProbeFromMirror", "topnProbeFromHost",
         "bsiPreludeHits", "bsiPreludeMisses",
-        "rangeCoverViews", "rangeCoverOperands")
+        "rangeCoverViews", "rangeCoverOperands",
+        "pathProbes", "pathProbeAborts")
 
 
 class QueryStats:

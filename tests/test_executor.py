@@ -988,9 +988,9 @@ def test_path_model_persists_across_restart(tmp_path):
         recorded = []
         orig_record = server.executor._record_path
 
-        def spy(st_, arm, elapsed):
+        def spy(st_, arm, elapsed, probe=False):
             recorded.append((id(st_), arm))
-            return orig_record(st_, arm, elapsed)
+            return orig_record(st_, arm, elapsed, probe)
 
         server.executor._record_path = spy
         try:

@@ -42,7 +42,7 @@ def test_a_count_shapes_key_is_unchanged():
     e = _bare_executor()
     call = _call('Count(Intersect(Bitmap(frame="f", rowID=1), '
                  'Bitmap(frame="f", rowID=2)))')
-    _, st, _, _ = e._path_choice(call, list(range(954)))
+    _, _, st, _, _ = e._path_choice(call, list(range(954)))
     assert e._path_stats == {(e._call_shape(call), 10): st}
     st["b"] = 0.001
     assert set(e.save_path_model()["entries"]) == {
@@ -884,16 +884,21 @@ def test_a_profile_says_how_many_rows_the_scan_was_given(server,
     ``scanned`` 64, ``top.src`` and the counter still say 40, whether
     the probe came from the mirror or from host words; an unprofiled
     request builds no span for any of it."""
+    from pilosa_tpu.ops import topn as topn_ops
+
     server.executor._force_path = "serial"
     frag = server.holder.fragment("i", "f", "standard", 0)
-    for kind, child in (("mirror", 'Bitmap(frame="f", rowID=0)'),
-                        ("host", 'Union(Bitmap(frame="f", rowID=0))')):
+    for kind, child, program in (
+            ("mirror", 'Bitmap(frame="f", rowID=0)',
+             topn_ops.TANIMOTO_FRAGMENT_PROBE_PROGRAM),
+            ("host", 'Union(Bitmap(frame="f", rowID=0))',
+             topn_ops.TANIMOTO_FRAGMENT_PROGRAM)):
         doc = _post(server, "/index/i/query?profile=true",
                     f'TopN({child}, frame="f", n=50, tanimotoThreshold=70)')
         tags = {sp["name"]: sp["tags"] for sp in doc["profile"]["spans"]
                 if sp["name"].startswith("top.")}
         assert (len(frag._phys_rows), frag._cap) == (40, 64)
-        assert tags["top.kernel"] == {"scanned": 64}
+        assert tags["top.kernel"] == {"scanned": 64, "program": program}
         assert tags["top.src"] == {"rows": 40, "probe": kind}
         assert tags["top.select"] == {"rows": 40}
         assert doc["profile"]["resources"]["topnRowsScanned"] == 40
