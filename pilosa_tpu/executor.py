@@ -338,6 +338,11 @@ class Executor:
         # from (_execute_topn_slice), process lifetime, under
         # _cache_mu: /debug/vars, beside the per-query keys.
         self.topn_probe = {"topnProbeFromMirror": 0, "topnProbeFromHost": 0}
+        # The same scans by where their selection ran (the fragment
+        # says: ``TopOptions.selected``), process lifetime, under
+        # _cache_mu: /debug/vars, beside the per-query keys.
+        self.topn_select = {"topnSelectDevice": 0, "topnSelectHost": 0,
+                            "topnSelectOverflow": 0}
         # Lookups of a BSI aggregate's prelude memo by outcome
         # (_prelude_record), process lifetime, under _cache_mu:
         # /debug/vars, beside the per-query keys.
@@ -5765,7 +5770,7 @@ class Executor:
             querystats.add(stat)
             with self._cache_mu:
                 self.topn_probe[stat] += 1
-        return frag.top(TopOptions(
+        opt = TopOptions(
             n=int(n),
             src=src,
             src_row=src_row,
@@ -5773,7 +5778,14 @@ class Executor:
             filter_row_ids=filter_row_ids,
             min_threshold=max(int(min_threshold), MIN_THRESHOLD),
             tanimoto_threshold=int(tanimoto),
-        ))
+        )
+        pairs = frag.top(opt)
+        if opt.selected:
+            stat = "topnSelect" + opt.selected.capitalize()
+            querystats.add(stat)
+            with self._cache_mu:
+                self.topn_select[stat] += 1
+        return pairs
 
     # ------------------------------------------------------------ writes
 

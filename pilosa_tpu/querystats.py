@@ -97,6 +97,12 @@ MAX_HEDGE_LEGS = 64
 # scan's program from the fragment's HBM mirror (the child is a plain
 # Bitmap of a row of the fragment the TopN scans), or executed to host
 # words and uploaded (any other child): executor._execute_topn_slice.
+# topnSelectDevice / topnSelectHost / topnSelectOverflow count the same
+# scans by where their selection ran (``TopOptions.selected``): inside
+# the scan's program, a kilobyte back (n set, no explicit ids, no
+# attribute filter); on the host over a count a row (the rest); or on
+# the host after the device's selection came back with more rows tied
+# at the cut than its bucket holds, a second launch.
 # bsiPreludeHits / bsiPreludeMisses count the lookups of a BSI
 # aggregate's prelude memo (Sum/Min/Max) by outcome: the memo is keyed
 # by the plan AND its leaves, which hold a condition's predicate bits
@@ -118,6 +124,7 @@ KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "leafMemoHits", "leafMemoMisses", "topnRowsScanned",
         "topnCandidates", "topnKept", "topnRecountsSkipped",
         "topnProbeFromMirror", "topnProbeFromHost",
+        "topnSelectDevice", "topnSelectHost", "topnSelectOverflow",
         "bsiPreludeHits", "bsiPreludeMisses",
         "rangeCoverViews", "rangeCoverOperands",
         "pathProbes", "pathProbeAborts")

@@ -1828,6 +1828,7 @@ class Handler:
         data["oomFallbacks"] = self.executor.oom_fallbacks
         data.update(self.executor.leaf_memo)
         data.update(self.executor.topn_probe)
+        data.update(self.executor.topn_select)
         data.update(self.executor.bsi_prelude)
         data.update(self.executor.range_cover)
         if self.tracer.enabled:

@@ -331,10 +331,13 @@ class TopOptions:
     comes as ``src`` (host words, whatever built them) or, where it is
     a row of the very fragment ``top`` scans, as that row's id in
     ``src_row``: the scan's program then reads the probe from the HBM
-    mirror by itself. At most one of the two is given."""
+    mirror by itself. At most one of the two is given. ``top`` writes
+    ``selected`` back: where the selection of a scan with a src ran,
+    ``device`` | ``host`` | ``overflow`` (None: nothing was scanned)."""
 
     def __init__(self, n=0, src=None, row_ids=None, filter_row_ids=None,
                  min_threshold=0, tanimoto_threshold=0, src_row=None):
+        self.selected = None
         self.n = n
         self.src = src                      # np.uint64[WORDS64] filter bitmap
         self.src_row = src_row              # or: the id of this fragment's row
@@ -435,6 +438,7 @@ class Fragment:
         self._planes_cache = {}   # (start_row, depth) -> (version, jnp planes)
         self._row_dev = {}        # phys -> (version, jnp row) dirty-row memo
         self._rc_dev = None       # (version, jnp int32 row counts) memo
+        self._elig_dev = None     # (host cache mask, jnp bool[cap]) memo
         # Container-granular read path for EVICTED fragments: an mmap-
         # backed codec.LazyReader + per-row host memo, so a query
         # touching one row of an unloaded fragment decodes O(that
@@ -754,9 +758,9 @@ class Fragment:
         d = self._dev
         if d is not None:
             dev += int(getattr(d, "nbytes", 0))
-        rc = self._rc_dev
-        if rc is not None:
-            dev += int(getattr(rc[1], "nbytes", 0))
+        for memo in (self._rc_dev, self._elig_dev):
+            if memo is not None:
+                dev += int(getattr(memo[1], "nbytes", 0))
         for memo in list(self._row_dev.values()):
             dev += int(getattr(memo[1], "nbytes", 0))
         for memo in list(self._planes_cache.values()):
@@ -836,6 +840,7 @@ class Fragment:
                 self._planes_cache = {}
                 self._row_dev = {}
                 self._rc_dev = None
+                self._elig_dev = None
                 self._cont_dev = {}
                 self._cont_fmt = {}
                 self._resident = False
@@ -1218,6 +1223,7 @@ class Fragment:
             self._planes_cache = {}
             self._row_dev = {}
             self._rc_dev = None
+            self._elig_dev = None
             self._cont_dev = {}
             self._cont_fmt = {}
         finally:
@@ -2999,25 +3005,39 @@ class Fragment:
         counts: nothing of the probe crosses to the host. The ``top.src``
         span is tagged ``probe`` = ``mirror`` | ``host`` accordingly.
 
+        Where the selection runs is decided by what the request is:
+        with a src, an ``n``, no explicit ``row_ids`` (the phase-2
+        re-query is never truncated a slice), no ``filter_row_ids``
+        (attribute filters stay on the host) and a bucket of at most
+        ``SELECT_MAX_K``, the scan's program selects as well
+        (``_top_device``): eligibility, the ``K`` largest masked counts
+        and the tie count come back in a kilobyte, and the host orders
+        at most ``K`` pairs by ``(-count, id)``. Everything else — no
+        src (the cache walk over host row counts), explicit ids,
+        filters, more rows tied at the cut than ``K`` holds — takes a
+        count a row and ``_top_select``. ``opt.selected`` says which;
+        the ``top.select`` span carries it as ``where``.
+
         The scan's operands have the mirror's shape, not the row
         count's: ``device_matrix()`` as the fragment holds it (``_cap``
-        rows, a power of two) and ``_cap`` row counts, with nothing
-        between the mirror and the program. A device-side ``[:n_phys]``
-        is a program of its own that reads and writes the whole matrix
-        before every scan (2.3x the scan's device time at 500,000
-        rows, PR 33's trace) and a new shape to compile for every
-        appended row; this way a scan compiles when the mirror
-        doubles, as it is uploaded anew then anyway. The counts come
-        back ``_cap`` long and are cut to the physical rows on the host
-        (a view) before selection. The padded rows cannot change an
-        answer: a row past ``n_phys`` is all zero in ``_matrix``
-        (``_grow_rows_locked`` allocates zeros, rows are only ever
-        appended) and has row count 0, so its intersection is 0;
+        rows, a power of two), ``_cap`` row counts and ``_cap``
+        eligibility bits, with nothing between the mirror and the
+        program. A device-side ``[:n_phys]`` is a program of its own
+        that reads and writes the whole matrix before every scan (2.3x
+        the scan's device time at 500,000 rows, PR 33's trace) and a
+        new shape to compile for every appended row; this way a scan
+        compiles when the mirror doubles, as it is uploaded anew then
+        anyway. The padded rows cannot change an answer: a row past
+        ``n_phys`` is all zero in ``_matrix`` (``_grow_rows_locked``
+        allocates zeros, rows are only ever appended) and has row
+        count 0, so its intersection is 0;
         ``tanimoto_keep(0, 0, src_n, T)`` is ``0 > T*src_n``, false;
-        ungated, a count of 0 fails ``_top_select``'s ``counts > 0``;
-        and the host cut drops them before selection anyway. A probe's
-        ``phys`` is below ``n_phys``, so ``row_at`` never reads a
-        padded row as the probe.
+        the device's selection never names a row with a count of 0 or
+        one that is not eligible; and where the counts come back
+        ``_cap`` long they are cut to the physical rows on the host (a
+        view) before ``_top_select``, whose ``counts > 0`` a 0 fails
+        anyway. A probe's ``phys`` is below ``n_phys``, so ``row_at``
+        never reads a padded row as the probe.
         """
         from pilosa_tpu.ops import topn as topn_ops
         from pilosa_tpu.storage.cache import NopCache
@@ -3069,6 +3089,19 @@ class Fragment:
                         ).view(np.uint32))
                     matrix = self.device_matrix()
                 querystats.add("topnRowsScanned", n_phys)
+                src_n = (None if from_mirror
+                         else int(np.bitwise_count(src_words).sum()))
+                if (opt.n and opt.row_ids is None
+                        and opt.filter_row_ids is None
+                        and topn_ops.select_k(opt.n)
+                        <= topn_ops.SELECT_MAX_K):
+                    pairs = self._top_device(opt, matrix, probe, src_n)
+                    if pairs is not None:
+                        opt.selected = "device"
+                        return pairs
+                    opt.selected = "overflow"
+                else:
+                    opt.selected = "host"
                 if not opt.tanimoto_threshold:
                     counts = topn_ops.fetch_counts(
                         bitops.count_and_rows_at if from_mirror
@@ -3082,15 +3115,68 @@ class Fragment:
                 else:
                     counts = topn_ops.fetch_counts(
                         topn_ops.tanimoto_masked_counts, matrix, probe,
-                        self._row_counts_device(),
-                        int(np.bitwise_count(src_words).sum()),
+                        self._row_counts_device(), src_n,
                         opt.tanimoto_threshold, op="topn_tanimoto_frag")
                 counts = counts[:n_phys]
             else:
                 counts = self._row_counts[:n_phys].copy()
 
-            with tracing.span("top.select", rows=n_phys):
+            with tracing.span("top.select", rows=n_phys,
+                              where=opt.selected or "host"):
                 return self._top_select(opt, counts)
+
+    def _top_device(self, opt, matrix, probe, src_n):
+        """A scan whose program selects as well (``ops/topn.py``
+        ``_select_top``): the ``k`` largest eligible masked counts come
+        back with their physical rows, a kilobyte where ``_top_select``
+        is handed a count a row of the mirror, and the host orders at
+        most ``k`` pairs by ``(-count, id)`` and cuts at ``n``. None
+        where more rows tie at or above the n-th count than ``k``
+        holds: the caller then selects over all the counts. Caller
+        holds ``mu``; ``src_n`` is None where ``probe`` is a physical
+        row of the mirror."""
+        from pilosa_tpu.ops import topn as topn_ops
+
+        k = topn_ops.select_k(opt.n)
+        scalars = np.array(
+            [probe if src_n is None else src_n, opt.tanimoto_threshold,
+             min(opt.min_threshold, SLICE_WIDTH + 1), opt.n],
+            dtype=np.int32)
+        tail = (scalars, self._row_counts_device(), self._elig_device())
+        if src_n is None:
+            out = topn_ops.fetch_counts(
+                topn_ops.tanimoto_select_at, matrix, *tail, k=k,
+                op="topn_tanimoto_frag_probe_select")
+        else:
+            out = topn_ops.fetch_counts(
+                topn_ops.tanimoto_select, matrix, probe, *tail, k=k,
+                op="topn_tanimoto_frag_select")
+        k = len(out) // 2         # a mirror shorter than the bucket: all of it
+        if out[-1] > k:
+            return None
+        counts = out[:k]
+        kept = int(np.count_nonzero(counts))   # descending: zeros last
+        with tracing.span("top.select", rows=kept, where="device"):
+            counts = counts[:kept]
+            ids = self._phys_row_ids()[out[k:k + kept]]
+            order = np.lexsort((ids, -counts))[:opt.n]
+            return list(zip(ids[order].tolist(), counts[order].tolist()))
+
+    def _elig_device(self):
+        """bool[cap] device copy of ``_cached_rows_mask()`` (false past
+        the last physical row): the rows the device's selection may
+        return. Uploaded once and kept while the host mask is the same
+        object and the mirror as long: a row appended or a change of
+        the cache's membership builds a new mask. Caller holds
+        ``mu``."""
+        mask = self._cached_rows_mask()
+        memo = self._elig_dev
+        if (memo is None or memo[0] is not mask
+                or memo[1].shape[0] != self._cap):
+            padded = np.zeros(self._cap, dtype=bool)
+            padded[:len(mask)] = mask
+            memo = self._elig_dev = (mask, jnp.asarray(padded))
+        return memo[1]
 
     def _phys_row_ids(self):
         """uint64 array of the physical rows' ids (read only), kept
@@ -3313,6 +3399,7 @@ class Fragment:
         self._planes_cache = {}
         self._row_dev = {}
         self._rc_dev = None
+        self._elig_dev = None
         self._cont_dev = {}
         self._cont_fmt = {}
         self._version += 1

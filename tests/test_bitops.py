@@ -8,6 +8,7 @@ varying density (dense≈bitmap containers, sparse≈array, runs≈runs).
 import numpy as np
 import jax
 import jax.numpy as jnp
+import pytest
 
 from pilosa_tpu.ops import bitops
 from pilosa_tpu.ops import bsi as bsi_ops
@@ -158,21 +159,98 @@ def test_bsi_extrema(rng):
 
 # --------------------------- TopN -----------------------------------------
 
-def test_top_k(rng):
-    m = np.stack([mk(rng, d) for d in (0.1, 0.9, 0.5, 0.3, 0.7)])
-    counts, idx = topn_ops.top_k_rows(jnp.asarray(m), 3)
-    want_counts = sorted((np_count(m[i]) for i in range(5)), reverse=True)[:3]
-    assert list(np.asarray(counts)) == want_counts
-    assert list(np.asarray(idx))[:2] == [1, 4]
+def _select_reference(masked, elig, min_threshold, n, k):
+    """What ``_select_top`` must return, but for the order inside a
+    count tie: (counts descending, the k'-th value, n_ge)."""
+    cand = np.where(elig & (masked >= max(min_threshold, 1)), masked, 0)
+    top = np.sort(cand)[::-1][:k]
+    nth = max(int(top[min(n, len(top)) - 1]), 1)
+    return cand, top, int((cand >= nth).sum())
 
 
-def test_top_k_src_and_tanimoto(rng):
+# (rows, k): a mirror no longer than the bucket (all of it comes back),
+# the flat sort, and more than k chunks of 128 rows (chunk maxima
+# first), at two bucket sizes.
+SELECT_SHAPES = [(16, 64), (1024, 64), (8192, 64), (16384, 64),
+                 (32768, 128)]
+
+
+@pytest.mark.parametrize("rows, k", SELECT_SHAPES,
+                         ids=[f"{r}x{k}" for r, k in SELECT_SHAPES])
+@pytest.mark.parametrize("spread", [3, 40, 5000],
+                         ids=["ties", "some-ties", "distinct"])
+def test_select_top_is_an_exact_top_k(rows, k, spread):
+    """The K largest eligible counts with rows that hold them, and
+    ``n_ge`` counted over every row: against NumPy, with ties in
+    droves, a few, and next to none; ineligible rows and rows under
+    ``min_threshold`` read 0 and are never named with a count."""
+    rng = np.random.default_rng(rows + spread)
+    masked = rng.integers(0, spread, rows).astype(np.int32)
+    masked[rng.random(rows) < 0.5] = 0
+    elig = rng.random(rows) < 0.8
+    elig[-rows // 8:] = False               # the mirror's padded tail
+    for n, min_threshold in ((1, 0), (k // 2, 1), (k // 2, 2), (k, 1)):
+        out = np.asarray(topn_ops._select_top(
+            jnp.asarray(masked), jnp.asarray(elig), min_threshold, n, k))
+        kk = min(k, rows)
+        cand, top, n_ge = _select_reference(masked, elig, min_threshold, n,
+                                            kk)
+        assert out.shape == (2 * kk + 1,) and out.dtype == np.int32
+        counts, at = out[:kk], out[kk:2 * kk]
+        assert list(counts) == list(top)
+        assert (cand[at] == counts).all()
+        kept = at[counts > 0]
+        assert len(set(kept.tolist())) == len(kept) and elig[kept].all()
+        assert out[-1] == n_ge
+        if n_ge <= kk:
+            # the check's promise: every row at or above the n-th count
+            # is among the pairs, so the host's cut by id is exact
+            nth = max(int(top[min(n, kk) - 1]), 1)
+            assert set(np.nonzero(cand >= nth)[0].tolist()) \
+                <= set(kept.tolist())
+
+
+def test_select_k_is_the_power_of_two_at_or_above_2n():
+    assert [topn_ops.select_k(n) for n in (1, 32, 33, 50, 64, 65, 512, 513)] \
+        == [64, 64, 128, 128, 128, 256, 1024, 2048]
+    assert topn_ops.select_k(512) == topn_ops.SELECT_MAX_K
+
+
+def test_the_select_programs_equal_the_masked_counts_selected(rng):
+    """``tanimoto_select`` / ``tanimoto_select_at`` are the programs
+    that return a count a row, with the tail: one int32[4] of scalars,
+    a threshold of 0 no gate at all."""
+    m = np.stack([mk(rng, d) for d in rng.random(40)])
+    m[30:] = 0
+    dm = jnp.asarray(m)
+    row_n = np.array([np_count(r) for r in m], dtype=np.int32)
+    elig = np.arange(40) < 30
+    for phys, t, min_threshold, n in ((3, 0, 1, 5), (7, 30, 1, 40),
+                                      (11, 50, 200, 10)):
+        src_n = int(row_n[phys])
+        masked = np.asarray(topn_ops.tanimoto_masked_counts(
+            dm, dm[phys], jnp.asarray(row_n), src_n, t))
+        if not t:
+            assert (masked == np.asarray(
+                bitops.count_and_rows(dm, dm[phys]))).all()
+        want = np.asarray(topn_ops._select_top(
+            jnp.asarray(masked), jnp.asarray(elig), min_threshold, n, 64))
+        at = topn_ops.tanimoto_select_at(
+            dm, np.array([phys, t, min_threshold, n], dtype=np.int32),
+            jnp.asarray(row_n), jnp.asarray(elig), k=64)
+        host = topn_ops.tanimoto_select(
+            dm, dm[phys], np.array([src_n, t, min_threshold, n],
+                                   dtype=np.int32),
+            jnp.asarray(row_n), jnp.asarray(elig), k=64)
+        assert list(np.asarray(at)) == list(np.asarray(host)) == list(want)
+        assert want[0] == src_n >= 1 or min_threshold > src_n
+    for fn in (topn_ops.tanimoto_select, topn_ops.tanimoto_select_at):
+        assert fn.__name__.startswith("pilosa_topn_tanimoto_frag")
+
+
+def test_count_and_rows_and_the_tanimoto_gate(rng):
     m = np.stack([mk(rng, d) for d in (0.2, 0.8, 0.5)])
     src = mk(rng, 0.5)
-    counts, idx = topn_ops.top_k_rows_src(jnp.asarray(m), jnp.asarray(src), 3)
-    want = sorted(((np_count(m[i] & src), i) for i in range(3)), reverse=True)
-    assert list(np.asarray(counts)) == [w[0] for w in want]
-
     inter = bitops.count_and_rows(jnp.asarray(m), jnp.asarray(src))
     row_n = jnp.sum(
         jax.lax.population_count(jnp.asarray(m)).astype(jnp.int32), axis=-1)

@@ -201,7 +201,17 @@ def test_a_profiled_topn_carries_phases_spans_and_counters(server, path):
         assert PHASE_SPANS[path] <= names, (phase["name"], names)
     if path == "serial":
         assert all(sp["tags"]["rows"] == 40 for sp in spans
-                   if sp["name"] in ("top.src", "top.select"))
+                   if sp["name"] == "top.src")
+        # Phase 1 (n set, no ids) selects inside the scan's program and
+        # the host orders the pairs that came back; the explicit-ids
+        # re-query selects over a count a row, on the host.
+        for name, where, rows in (("topn.phase1", "device", n),
+                                  ("topn.phase2", "host", 40)):
+            tags = [sp["tags"] for sp in spans if sp["name"] == "top.select"
+                    and under(sp, phases[name])]
+            assert tags == [{"rows": rows, "where": where}] * 2, name
+        assert (res["topnSelectDevice"], res["topnSelectHost"],
+                res["topnSelectOverflow"]) == (2, 2, 0)
     else:
         stacks = [sp for sp in spans if sp["name"] == "topn.stacks"]
         assert [sp["tags"]["candidates"] for sp in stacks] == [40, n]
@@ -675,28 +685,39 @@ def test_the_mirror_probe_follows_the_fragments_state(tmp_path, change,
 
 
 def test_one_program_for_every_probe_and_threshold(one_slice):
-    """The probe's physical index and the threshold are traced: after
-    the first gated and the first ungated scan of a fragment no probe
-    and no threshold compiles again."""
+    """The probe's physical index, the threshold, ``min_threshold`` and
+    ``n`` are traced, the selection's size is ``n``'s bucket: after the
+    first scan of a fragment no probe, no threshold (0, no gate,
+    among them) and no ``n`` up to 32 compiles again; nor, with
+    explicit ids (the host's selection), after the first gated and the
+    first ungated one."""
     from pilosa_tpu.ops import bitops
     from pilosa_tpu.ops import topn as topn_ops
 
     ex, data, _ = one_slice
     ex._force_path = "serial"
-    programs = (topn_ops.tanimoto_masked_counts_at,
+    programs = (topn_ops.tanimoto_select_at,
+                topn_ops.tanimoto_masked_counts_at,
                 bitops._count_and_rows_at_impl)
-    q = 'TopN(Bitmap(frame="f", rowID={p}), frame="f", n=5{t})'
-    ex.execute("i", q.format(p=PROBE, t=", tanimotoThreshold=50"))
-    ex.execute("i", q.format(p=PROBE, t=""))
+    q = 'TopN(Bitmap(frame="f", rowID={p}), frame="f", n={n}{t})'
+    every = ", ids=[%s]" % ", ".join(str(r) for r in sorted(data["f"]))
+    ex.execute("i", q.format(p=PROBE, n=5, t=", tanimotoThreshold=50"))
+    ex.execute("i", q.format(p=PROBE, n=5,
+                             t=", tanimotoThreshold=50" + every))
+    ex.execute("i", q.format(p=PROBE, n=5, t=every))
     sizes = [fn._cache_size() for fn in programs]
     assert all(sizes)
-    for p, t in ((7, 70), (33, 90), (64, 1), (200, 50)):
-        want = brute_topn(data["f"], src=data["f"][p], n=5, tanimoto=t)
-        assert ex.execute(
-            "i", q.format(p=p, t=f", tanimotoThreshold={t}"))[0] == want
-        assert ex.execute("i", q.format(p=p, t=""))[0] \
-            == brute_topn(data["f"], src=data["f"][p], n=5)
+    for p, t, n in ((7, 70, 5), (33, 90, 1), (64, 1, 32), (200, 50, 17)):
+        want = brute_topn(data["f"], src=data["f"][p], n=n, tanimoto=t)
+        gated = f", tanimotoThreshold={t}"
+        assert ex.execute("i", q.format(p=p, n=n, t=gated))[0] == want
+        assert ex.execute("i", q.format(p=p, n=n, t=gated + every))[0][:n] \
+            == want
+        want = brute_topn(data["f"], src=data["f"][p], n=n)
+        assert ex.execute("i", q.format(p=p, n=n, t=""))[0] == want
+        assert ex.execute("i", q.format(p=p, n=n, t=every))[0][:n] == want
     assert [fn._cache_size() for fn in programs] == sizes
+    assert topn_ops.select_k(32) == 64 < topn_ops.select_k(33) == 128
 
 
 def test_a_profile_says_where_the_probe_came_from(server):
@@ -820,12 +841,28 @@ def test_a_scan_of_the_whole_mirror_equals_brute_force(
     if name == "n2":
         assert [r for r, _ in got] == [7, MIRROR_PROBE]
 
-    (matrix, args, counts), = scan_operands
+    (matrix, args, out), = scan_operands
     assert matrix is f._dev
     assert matrix.shape == (f._cap, 2 * f._w64) == (16, 2 * f._w64)
-    assert counts.shape == (16,) and not counts[len(rows):].any()
-    if gate:
-        assert args[1].shape == (16,) and args[1] is f._rc_dev[1]
+    if name == "row_ids":
+        # Explicit ids are selected on the host, over a count a row.
+        assert out.shape == (16,) and not out[len(rows):].any()
+        if gate:
+            assert args[1].shape == (16,) and args[1] is f._rc_dev[1]
+        assert stats["topnRowsScanned"] == len(rows)
+        return
+    # The program selected: 16 counts (a mirror of 16 has no more), 16
+    # physical rows, n_ge; the request's scalars in ONE host operand;
+    # no row past the last physical one is eligible or returned.
+    scalars, row_n, elig = args[-3:]
+    assert out.shape == (33,) and out[32] <= 16
+    assert isinstance(scalars, np.ndarray) and scalars.dtype == np.int32
+    assert list(scalars[1:]) == [gate, ask.get("min_threshold", 0), ask["n"]]
+    assert row_n is f._rc_dev[1] and row_n.shape == (16,)
+    assert elig is f._elig_dev[1] and elig.shape == (16,)
+    assert not np.asarray(elig)[len(rows):].any()
+    kept = out[:16] > 0
+    assert kept.sum() >= len(got) and (out[16:32][kept] < len(rows)).all()
     assert stats["topnRowsScanned"] == len(rows)
 
 
@@ -840,7 +877,8 @@ def test_an_appended_row_is_scanned_by_the_program_already_compiled(
 
     rows = _mirror_rows(9)
     f = _open_fragment(tmp_path, rows)
-    programs = (topn_ops.tanimoto_masked_counts_at,
+    programs = (topn_ops.tanimoto_select_at, topn_ops.tanimoto_select,
+                topn_ops.tanimoto_masked_counts_at,
                 topn_ops.tanimoto_masked_counts,
                 bitops._count_and_rows_at_impl, bitops._count_and_rows_impl)
 
@@ -850,11 +888,15 @@ def test_an_appended_row_is_scanned_by_the_program_already_compiled(
                               tanimoto=gate)
             for src in ({"src_row": MIRROR_PROBE},
                         {"src": np.array(f.row_words(MIRROR_PROBE))}):
-                assert f.top(TopOptions(n=4, tanimoto_threshold=gate,
-                                        **src)) == want
-                matrix, _, counts = scan_operands[-1]
-                assert matrix is f._dev and matrix.shape[0] == cap
-                assert counts.shape == (cap,)
+                # the device's selection, then the host's over all the
+                # counts (an id list that names every row)
+                for ids, size in ((None, 2 * cap + 1), (list(rows), cap)):
+                    got = f.top(TopOptions(n=4, tanimoto_threshold=gate,
+                                           row_ids=ids, **src))
+                    assert got[:4] == want
+                    matrix, _, out = scan_operands[-1]
+                    assert matrix is f._dev and matrix.shape[0] == cap
+                    assert out.shape == (size,)
 
     def append(row, n_cols):
         for col in range(n_cols):
@@ -890,9 +932,9 @@ def test_a_profile_says_how_many_rows_the_scan_was_given(server,
     frag = server.holder.fragment("i", "f", "standard", 0)
     for kind, child, program in (
             ("mirror", 'Bitmap(frame="f", rowID=0)',
-             topn_ops.TANIMOTO_FRAGMENT_PROBE_PROGRAM),
+             topn_ops.tanimoto_select_at.__name__),
             ("host", 'Union(Bitmap(frame="f", rowID=0))',
-             topn_ops.TANIMOTO_FRAGMENT_PROGRAM)):
+             topn_ops.tanimoto_select.__name__)):
         doc = _post(server, "/index/i/query?profile=true",
                     f'TopN({child}, frame="f", n=50, tanimotoThreshold=70)')
         tags = {sp["name"]: sp["tags"] for sp in doc["profile"]["spans"]
@@ -900,7 +942,9 @@ def test_a_profile_says_how_many_rows_the_scan_was_given(server,
         assert (len(frag._phys_rows), frag._cap) == (40, 64)
         assert tags["top.kernel"] == {"scanned": 64, "program": program}
         assert tags["top.src"] == {"rows": 40, "probe": kind}
-        assert tags["top.select"] == {"rows": 40}
+        assert program.startswith("pilosa_topn_tanimoto_frag")
+        assert tags["top.select"] == {"rows": len(doc["results"][0]),
+                                      "where": "device"}
         assert doc["profile"]["resources"]["topnRowsScanned"] == 40
     built = []
     real_init = tracing.Span.__init__
@@ -909,3 +953,315 @@ def test_a_profile_says_how_many_rows_the_scan_was_given(server,
         lambda self, *a, **k: (built.append(a), real_init(self, *a, **k))[1])
     out = _post(server, "/index/i/query", TOPN.format(p=0, t=70))
     assert out["results"][0][0]["id"] == 0 and not built
+
+
+# ---------- the selection inside the scan's program (PR 36): a kilobyte
+# back where a count a row came, and the same LIST as the host's cut
+
+def _scattered_fragment(path, seed, n_rows, bits=24, width=96, **kw):
+    """(fragment, {row id: columns}): random rows of a few columns each
+    out of ``width`` (so counts tie in droves), with families of copies
+    of row 0's columns, written in an order that is not id order."""
+    import os
+
+    from pilosa_tpu.storage.fragment import Fragment
+
+    rng = np.random.default_rng(seed)
+    ids = rng.permutation(np.arange(1, 4 * n_rows, 3))[:n_rows]
+    rows = {}
+    for k, rid in enumerate(ids.tolist()):
+        cols = rng.choice(width, rng.integers(1, bits), replace=False)
+        if k % 5 == 0 and k:                 # near copies of the first row
+            first = sorted(rows[int(ids[0])])
+            cols = first[:rng.integers(1, len(first) + 1)]
+        rows[rid] = set(int(c) for c in cols)
+    f = Fragment(os.path.join(str(path), "frag"), "i", "f", "standard", 0,
+                 **kw).open()
+    for part in np.array_split(ids, 3):       # an import sorts its rows
+        f.import_bits([r for r in part.tolist() for _ in rows[r]],
+                      [c for r in part.tolist() for c in rows[r]])
+    return f, rows
+
+
+def _on_the_host(monkeypatch, f, **ask):
+    """The same request with the device's selection switched off
+    underneath: ``_top_select`` over a count a row."""
+    from pilosa_tpu.ops import topn as topn_ops
+    from pilosa_tpu.storage.fragment import TopOptions
+
+    with monkeypatch.context() as m:
+        m.setattr(topn_ops, "SELECT_MAX_K", 0)
+        opt = TopOptions(**ask)
+        pairs = f.top(opt)
+    assert opt.selected == "host"
+    return pairs
+
+
+@pytest.fixture(scope="module", params=[401, 2_147_484_001],
+                ids=["seed401", "seed2147484001"])
+def scattered(request, tmp_path_factory):
+    f, rows = _scattered_fragment(tmp_path_factory.mktemp("scattered"),
+                                  request.param, 700)
+    assert f._phys_rows != sorted(f._phys_rows) and f._cap == 1024
+    yield f, rows
+    f.close()
+
+
+# (id, TopOptions' arguments, brute_topn's): the cut inside count ties,
+# the three gates, ``min_threshold`` above 1, more asked for than
+# survive.
+SELECT_ASKS = [
+    ("n1", dict(n=1), dict(n=1)),
+    ("n7", dict(n=7), dict(n=7)),
+    ("n32-t50", dict(n=32, tanimoto_threshold=50), dict(n=32, tanimoto=50)),
+    ("n50-t70", dict(n=50, tanimoto_threshold=70), dict(n=50, tanimoto=70)),
+    ("n50-t90", dict(n=50, tanimoto_threshold=90), dict(n=50, tanimoto=90)),
+    ("n20-min5", dict(n=20, min_threshold=5), dict(n=20, threshold=5)),
+    ("n9-min3-t50", dict(n=9, min_threshold=3, tanimoto_threshold=50),
+     dict(n=9, threshold=3, tanimoto=50)),
+    ("n512", dict(n=512), dict(n=512)),
+]
+
+
+@pytest.mark.parametrize("name, ask, ref", SELECT_ASKS,
+                         ids=[a[0] for a in SELECT_ASKS])
+@pytest.mark.parametrize("probe", ["src_row", "src"])
+def test_the_devices_selection_is_the_hosts_list(scattered, monkeypatch,
+                                                 probe, name, ask, ref):
+    """Physical order is not id order, counts tie across every cut:
+    the pairs the device selected, ordered and cut by the host, equal
+    ``_top_select``'s list over all the counts and brute force, for a
+    probe from the mirror and from host words alike."""
+    from pilosa_tpu.storage.fragment import TopOptions
+
+    f, rows = scattered
+    for p in [r for r in rows if len(rows[r]) >= 6][:6]:
+        src = ({"src_row": p} if probe == "src_row"
+               else {"src": np.array(f.row_words(p))})
+        opt = TopOptions(**src, **ask)
+        got = f.top(opt)
+        assert opt.selected in ("device", "overflow")
+        assert got == _on_the_host(monkeypatch, f, **src, **ask) \
+            == brute_topn(rows, src=rows[p], **ref)
+        assert got and got[0][0] in rows
+    if name == "n7":
+        # a cut that falls inside a tie is decided by id
+        counts = [c for _, c in brute_topn(rows, src=rows[p])]
+        assert counts[6] == counts[7]
+
+
+def test_ties_beyond_the_bucket_take_the_hosts_path(tmp_path, monkeypatch):
+    """n = 5 selects into 64: with a hundred copies of the probe, 101
+    rows tie at the cut, ``n_ge`` says so and the request is answered
+    over all the counts, the same list; with sixty the device's 64 hold
+    every row of the tie."""
+    from pilosa_tpu.ops import topn as topn_ops
+    from pilosa_tpu.storage.fragment import TopOptions
+
+    rows = {1000 - r: set(range(12)) for r in range(100)}
+    rows.update({r: set(range(r % 11)) | {50 + r} for r in range(1, 300)})
+    f = _open_fragment(tmp_path, rows)
+    try:
+        assert topn_ops.select_k(5) == 64
+        for t in (0, 70):
+            opt = TopOptions(n=5, src_row=1000, tanimoto_threshold=t)
+            assert f.top(opt) == [(r, 12) for r in range(901, 906)] \
+                == brute_topn(rows, src=rows[1000], n=5, tanimoto=t)
+            assert opt.selected == "overflow"
+        for r in range(901, 941):               # forty leave the tie
+            f.clear_bit(r, 0)
+            rows[r].discard(0)
+        opt = TopOptions(n=5, src_row=1000, tanimoto_threshold=70)
+        assert f.top(opt) == [(r, 12) for r in range(941, 946)] \
+            == brute_topn(rows, src=rows[1000], n=5, tanimoto=70)
+        assert opt.selected == "device"
+    finally:
+        f.close()
+
+
+@pytest.mark.parametrize("ask", [
+    dict(n=0), dict(n=3, row_ids=[7, 105, 110, 120]),
+    dict(n=3, filter_row_ids=[7, 105, 110, 120]), dict(n=513),
+], ids=["n0", "row_ids", "filter_row_ids", "n513"])
+def test_what_the_device_may_not_cut_stays_on_the_host(mirror_fragment,
+                                                       scan_operands, ask):
+    """No ``n``, explicit ids (never truncated a slice), an attribute
+    filter, an ``n`` past the largest bucket: a count a row comes back
+    and ``_top_select`` selects."""
+    from pilosa_tpu.storage.fragment import TopOptions
+
+    f, rows = mirror_fragment
+    opt = TopOptions(src_row=MIRROR_PROBE, tanimoto_threshold=50, **ask)
+    got = f.top(opt)
+    allowed = ask.get("row_ids") or ask.get("filter_row_ids")
+    want = brute_topn(rows, src=rows[MIRROR_PROBE], tanimoto=50,
+                      allowed=allowed and set(allowed),
+                      n=0 if "row_ids" in ask else ask["n"])
+    assert got == want and opt.selected == "host"
+    (_, _, counts), = scan_operands
+    assert counts.shape == (16,)
+
+
+def test_a_cache_that_holds_some_rows_names_the_eligible(tmp_path,
+                                                         monkeypatch):
+    """Only rows in the ranked cache may be returned: the ``elig``
+    operand is the host's mask, padded with False to the mirror."""
+    from pilosa_tpu.storage.fragment import TopOptions
+
+    f, rows = _scattered_fragment(tmp_path, 77, 300)
+    try:
+        dropped = sorted(rows)[::3]
+        for r in dropped:
+            f.cache.bulk_add(r, 0)
+        cached = set(rows) - set(dropped)
+        for p in (dropped[0], sorted(cached)[0]):
+            for t in (0, 50):
+                ask = dict(n=40, src_row=p, tanimoto_threshold=t)
+                opt = TopOptions(**ask)
+                got = f.top(opt)
+                assert opt.selected == "device"
+                assert got == _on_the_host(monkeypatch, f, **ask) \
+                    == brute_topn(rows, src=rows[p], n=40, tanimoto=t,
+                                  allowed=cached)
+                assert (got or t) and not set(dict(got)) & set(dropped)
+        elig = np.asarray(f._elig_dev[1])
+        assert elig.shape == (f._cap,) and elig.sum() == len(cached)
+        assert not elig[len(rows):].any()
+    finally:
+        f.close()
+
+
+def test_elig_follows_the_rows_the_mirror_and_the_cache(tmp_path):
+    """The device's copy of the eligibility mask is kept between scans
+    and built anew when a row is appended (same mirror), when the
+    mirror doubles, and when the cache's membership changes; a bit set
+    a moment ago is in the next answer."""
+    from pilosa_tpu.storage.fragment import TopOptions
+
+    rows = _mirror_rows(9)
+    f = _open_fragment(tmp_path, rows)
+
+    def top(n=4):
+        opt = TopOptions(n=n, src_row=MIRROR_PROBE, tanimoto_threshold=70)
+        pairs = f.top(opt)
+        assert opt.selected == "device"
+        assert pairs == brute_topn(rows, src=rows[MIRROR_PROBE], n=n,
+                                   tanimoto=70)
+        return pairs
+
+    def append(row, n_cols):
+        for col in range(n_cols):
+            f.set_bit(row, col)
+        rows[row] = set(range(n_cols))
+
+    try:
+        top()
+        elig = f._elig_dev[1]
+        top()
+        assert f._elig_dev[1] is elig and elig.shape == (16,)
+        assert np.asarray(elig).sum() == 9
+        append(3, 10)                   # a copy of the probe, lowest id
+        assert top(1) == [(3, 10)]
+        assert f._elig_dev[1] is not elig and f._elig_dev[1].shape == (16,)
+        assert np.asarray(f._elig_dev[1]).sum() == 10
+        f.set_bit(114, 50)              # an acknowledged bit, no new row
+        rows[114].add(50)
+        elig = f._elig_dev[1]
+        top()
+        assert f._elig_dev[1] is elig
+        for row in range(300, 307):     # the 17th row doubles the mirror
+            append(row, row - 295)
+        assert (len(f._phys_rows), f._cap) == (17, 32)
+        top(50)
+        assert f._elig_dev[1].shape == (32,)
+        assert np.asarray(f._elig_dev[1]).sum() == 17
+        f.cache.bulk_add(3, 0)          # row 3 leaves the cache
+        elig, gone = f._elig_dev[1], rows.pop(3)
+        assert top(1) == [(7, 10)]
+        assert f._elig_dev[1] is not elig
+        rows[3] = gone
+    finally:
+        f.close()
+
+
+def test_the_chunked_selection_on_a_mirror_of_32768(tmp_path, monkeypatch):
+    """More than ``k`` chunks of 128 rows: the program takes the
+    chunks' maxima first and sorts ``k`` chunks only. 20,000 rows in id
+    order reversed, families across chunk boundaries, the cut inside
+    ties: the host's list."""
+    from pilosa_tpu.ops import topn as topn_ops
+    from pilosa_tpu.storage.fragment import TopOptions
+
+    rng = np.random.default_rng(5)
+    rows = {}
+    for k in range(20_000):
+        rid = 40_000 - k
+        width = int(rng.integers(1, 9))
+        start = int(rng.integers(0, 40))
+        rows[rid] = set(range(start, start + width))
+        if k % 97 == 0:
+            rows[rid] = set(range(8, 20))          # copies of the probe
+    rows[5] = set(range(8, 20))
+    f = _open_fragment(tmp_path, rows)
+    try:
+        assert f._cap == 32_768 > topn_ops.SELECT_CHUNK * 128
+        for ask, ref in ((dict(n=50), dict(n=50)),
+                         (dict(n=50, tanimoto_threshold=50),
+                          dict(n=50, tanimoto=50)),
+                         (dict(n=3, tanimoto_threshold=90),
+                          dict(n=3, tanimoto=90)),
+                         (dict(n=64, min_threshold=8),
+                          dict(n=64, threshold=8))):
+            opt = TopOptions(src_row=5, **ask)
+            got = f.top(opt)
+            assert got == _on_the_host(monkeypatch, f, src_row=5, **ask) \
+                == brute_topn(rows, src=rows[5], **ref)
+            # 208 rows tie with the probe, more than any of the buckets
+            # (64, 128) holds
+            assert opt.selected == "overflow"
+        for rid in [r for r in rows if r != 5 and rows[r] == rows[5]][60:]:
+            f.clear_bit(rid, 8)
+            rows[rid].discard(8)
+        opt = TopOptions(src_row=5, n=50, tanimoto_threshold=50)
+        assert f.top(opt) == brute_topn(rows, src=rows[5], n=50, tanimoto=50)
+        assert opt.selected == "device"
+    finally:
+        f.close()
+
+
+def test_a_profile_says_where_the_selection_ran(server):
+    """``top.select`` is tagged ``where`` and ``rows`` = what the host
+    sorted; ``resources`` and ``/debug/vars`` count the scans by it; a
+    bit set a moment ago is in the device's answer."""
+    server.executor._force_path = "serial"
+    probe = 'Bitmap(frame="f", rowID=0)'
+    asks = (("device", f'TopN({probe}, frame="f", n=50, '
+                       'tanimotoThreshold=70)'),
+            ("host", f'TopN({probe}, frame="f", n=50, ids=[0, 1, 2, 30], '
+                     'tanimotoThreshold=70)'),
+            ("host", f'TopN({probe}, frame="f", tanimotoThreshold=70)'),
+            ("device", f'TopN({probe}, frame="f", n=1)'))
+    seen = {"device": 0, "host": 0, "overflow": 0}
+    for where, pql in asks:
+        doc = _post(server, "/index/i/query?profile=true", pql)
+        pairs = doc["results"][0]
+        tags = [sp["tags"] for sp in doc["profile"]["spans"]
+                if sp["name"] == "top.select"]
+        (tag,) = tags
+        assert tag["where"] == where, pql
+        assert (len(pairs) <= tag["rows"] <= 40 if where == "device"
+                else tag["rows"] == 40), pql
+        res = doc["profile"]["resources"]
+        assert {k: res["topnSelect" + k.capitalize()] for k in seen} \
+            == {**dict.fromkeys(seen, 0), where: 1}
+        seen[where] += 1
+    _post(server, "/index/i/query",
+          "".join(f'SetBit(frame="f", rowID=77, columnID={c})'
+                  for c in range(60)))
+    doc = _post(server, "/index/i/query?profile=true", asks[0][1])
+    assert 77 in [p["id"] for p in doc["results"][0]]
+    seen["device"] += 1
+    with urllib.request.urlopen(f"http://{server.host}/debug/vars",
+                                timeout=30) as resp:
+        totals = json.loads(resp.read())
+    assert {k: totals["topnSelect" + k.capitalize()] for k in seen} == seen
