@@ -264,6 +264,9 @@ class Executor:
     # JIT compile; bound the table so shape-churning clients can't grow
     # it without limit.
     BATCHED_FN_CACHE_MAX = 128
+    # Resident predicate-bit vectors (_bits_arg): at most 256 B each, so
+    # the bound only stops a client that sends ever new bounds.
+    BSI_BITS_MAX = 4096
 
     def __init__(self, holder, cluster=None, host=None, client=None,
                  max_writes_per_request=5000):
@@ -366,6 +369,11 @@ class Executor:
         # (_prelude_record), process lifetime, under _cache_mu:
         # /debug/vars, beside the per-query keys.
         self.bsi_prelude = {"bsiPreludeHits": 0, "bsiPreludeMisses": 0}
+        # Resident predicate-bit vectors (_bits_arg) and their lookups
+        # by outcome, process lifetime, under _cache_mu: /debug/vars,
+        # beside the per-query keys.
+        self._bsi_bits = {}
+        self.bsi_bits = {"bsiBitsHits": 0, "bsiBitsMisses": 0}
         # Views the covers of planned time Ranges asked for, and the
         # operands they were bucketed to (_batched_plan), process
         # lifetime, under _cache_mu: /debug/vars, beside the per-query
@@ -2283,7 +2291,7 @@ class Executor:
         plan time (they depend only on field/op/value, never the slice
         — executeFieldRangeSlice executor.go:682-819). Predicate bits
         ride as array args so distinct values share one executable:
-        host int32 arrays, uploaded by the call that reads them."""
+        resident device arrays, one a distinct vector (_bits_arg)."""
         idx = self.holder.index(index)
         frame_name = call.args.get("frame") or DEFAULT_FRAME
         frame = idx.frame(frame_name)
@@ -3206,10 +3214,8 @@ class Executor:
             # charge that member (parked coalescees included), not the
             # leader running the loop.
             with querystats.exclusive_scope(req.get("qs")):
-                per_query.append(
-                    [self._spec_arg(index, sp, slices, pad, n_dev, win,
-                                    fm)
-                     for sp in req["leaves"]])
+                per_query.append(self._co_member_args(
+                    index, req["leaves"], slices, pad, n_dev, win, fm))
         args = self._co_stack_args(per_query, leaves0, k_pad, n_dev)
         obs = kerneltime_mod.ACTIVE
         tree_key = str(plan)
@@ -3505,13 +3511,25 @@ class Executor:
         self._co_stats["lane_launches"] += 1
         return True
 
+    def _co_member_args(self, index, leaves, slices, pad, n_dev, win,
+                        frag_map):
+        """One member's operands for _co_stack_args: the device stack
+        of each row or plane leaf, and the bits tuple of each predicate
+        as the spec holds it (the fused call stacks those on the host;
+        reading a resident copy back would be a device-to-host copy)."""
+        return [sp[1] if sp[0] == "bits"
+                else self._spec_arg(index, sp, slices, pad, n_dev, win,
+                                    frag_map)
+                for sp in leaves]
+
     def _co_stack_args(self, per_query, leaves0, k_pad, n_dev):
         """Give each leaf slot a query axis: stack the K per-query
         args to [K, ...], zero-padding to the k_pad bucket. The slice
         axis is re-sharded for row/plane stacks only — "bits"
         predicate args have no slice axis and are stacked on the host
-        ([K, depth] NumPy, uploaded by the fused call). The ONE
-        stacking loop shared by every fused shape (count, sum)."""
+        from the members' bits tuples ([K, depth] NumPy, uploaded by
+        the fused call). The ONE stacking loop shared by every fused
+        shape (count, sum)."""
         import jax
         import jax.numpy as jnp
 
@@ -3519,8 +3537,8 @@ class Executor:
         for j in range(len(per_query[0])):
             cols = [pq[j] for pq in per_query]
             if leaves0[j][0] == "bits":
-                cols += [np.zeros_like(cols[0])] * (k_pad - len(cols))
-                args.append(np.stack(cols))
+                cols += [(0,) * len(cols[0])] * (k_pad - len(cols))
+                args.append(np.asarray(cols, dtype=np.int32))
                 continue
             while len(cols) < k_pad:
                 cols.append(jnp.zeros_like(cols[0]))
@@ -3732,10 +3750,8 @@ class Executor:
             # Per-member staging charges the member, not the leader
             # (the _co_fuse_dense attribution rule).
             with querystats.exclusive_scope(req.get("qs")):
-                per_query.append(
-                    [self._spec_arg(index, sp, slices, pad, n_dev, win,
-                                    fm)
-                     for sp in req["leaves"]])
+                per_query.append(self._co_member_args(
+                    index, req["leaves"], slices, pad, n_dev, win, fm))
         args = self._co_stack_args(per_query, leaves0, k_pad, n_dev)
         return planes_stack, args, win, pad, k, k_pad
 
@@ -3959,14 +3975,14 @@ class Executor:
             return 1
         if spec[0] == "planes":
             return spec[3] + 1
-        return 0  # bits: a few dozen host bytes
+        return 0  # bits: a few dozen bytes, held by _bits_arg
 
     def _spec_arg(self, index, spec, slices, pad, n_dev, win=None,
                   frag_map=None):
         """Build the program operand for one typed leaf spec: a device
-        stack for a row or a plane matrix, a host int32[depth] array
-        for predicate bits (it travels with the launch that reads it;
-        an eager jnp.asarray would be a program of its own)."""
+        stack for a row or a plane matrix, the resident int32[depth]
+        device copy of predicate bits (_bits_arg: the launch uploads
+        nothing)."""
         if spec[0] == "row":
             _, fname, rid, view = spec
             frags = frag_map.get((fname, view)) if frag_map else None
@@ -3979,8 +3995,7 @@ class Executor:
             return self._planes_stack(index, fname, field_name, depth,
                                       slices, pad, n_dev, win=win,
                                       frags=frags)
-        _, bits, depth = spec
-        return np.asarray(bits, dtype=np.int32)
+        return self._bits_arg(spec)
 
     # Minimum device-stack window width (uint32 words): 2 × the
     # fragment minimum (_MIN_W64=64 u64 words), and a multiple of the
@@ -4251,9 +4266,9 @@ class Executor:
     def _prelude_specs(self, index, leaves, stacks, slices, n_dev, win):
         """Memo descriptors per leaf: the stack-cache KEY for row/plane
         stacks (must match _leaf_stack/_planes_stack key layout), the
-        raw array only for tiny host-derived args (BSI predicate
-        bits: a host int32[depth] array, a few dozen bytes, pinned by
-        the memo)."""
+        array itself only for tiny query-derived args (BSI predicate
+        bits: _bits_arg's resident int32[depth] device array, a few
+        dozen bytes, pinned by the memo)."""
         specs = []
         skey = slice_key(slices)
         for sp, st in zip(leaves, stacks):
@@ -5379,6 +5394,37 @@ class Executor:
             arr = self._zero_rows[width32, device] = jnp.zeros(
                 width32, jnp.uint32, device=device)
         return arr
+
+    def _bits_arg(self, spec):
+        """The committed ``int32[depth]`` device copy of a ``("bits",
+        bits, depth)`` spec, replicated over the local mesh so that a
+        launch over stacks sharded on every device reads it where it
+        lies. A miss is one ``jax.device_put`` (a transfer, no program);
+        every later launch passes the same array and uploads nothing.
+        The bits depend on the query's constants and the field's depth
+        alone, never on the data, so a copy cannot go stale. Keyed by
+        the spec and the mesh's device set; the oldest goes first past
+        BSI_BITS_MAX. Lookups count as ``bsiBitsHits`` /
+        ``bsiBitsMisses`` (profile and /debug/vars)."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        mesh = self._local_mesh()
+        key = (spec, self._mesh_fp)
+        with self._cache_mu:
+            arr = self._bsi_bits.get(key)
+        stat = "bsiBitsMisses" if arr is None else "bsiBitsHits"
+        if arr is None:
+            arr = jax.device_put(np.asarray(spec[1], dtype=np.int32),
+                                 NamedSharding(mesh, PartitionSpec()))
+        querystats.add(stat)
+        with self._cache_mu:
+            self.bsi_bits[stat] += 1
+            if key not in self._bsi_bits:
+                while len(self._bsi_bits) >= self.BSI_BITS_MAX:
+                    self._bsi_bits.pop(next(iter(self._bsi_bits)))
+                self._bsi_bits[key] = arr
+            return self._bsi_bits[key]
 
     def _local_mesh(self):
         """Local device mesh for sharded batched stacks, memoized

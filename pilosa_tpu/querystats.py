@@ -108,6 +108,10 @@ MAX_HEDGE_LEGS = 64
 # by the plan AND its leaves, which hold a condition's predicate bits
 # and a Bitmap's row, so a never-seen query reads a miss
 # (executor._prelude_record).
+# bsiBitsHits / bsiBitsMisses count the lookups of a BSI condition's
+# predicate bits among the executor's resident device copies: a miss
+# uploads the vector once (executor._bits_arg), a hit uploads nothing.
+# A prelude-memo hit makes no lookup.
 # rangeCoverViews / rangeCoverOperands count the views that the covers
 # of a query's time Ranges asked for, and the operands the batched plan
 # gave them once each cover was bucketed (executor._cover_bucket): the
@@ -130,6 +134,7 @@ KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "topnProbeFromMirror", "topnProbeFromHost",
         "topnSelectDevice", "topnSelectHost", "topnSelectOverflow",
         "bsiPreludeHits", "bsiPreludeMisses",
+        "bsiBitsHits", "bsiBitsMisses",
         "rangeCoverViews", "rangeCoverOperands",
         "pathProbes", "pathProbeAborts", "crossDeviceRows")
 

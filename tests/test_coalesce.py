@@ -173,13 +173,17 @@ def test_concurrent_filtered_sums_fuse(env):
     assert e._co_stats["fused_queries"] >= 2
 
 
-def test_concurrent_sums_under_bsi_ranges_fuse_with_host_bits(env):
+def test_concurrent_sums_under_bsi_ranges_fuse_with_host_bits(
+        env, monkeypatch):
     """Sums of one shape with different bounds fuse (PR 32): each
     "bits" slot reaches the fused program as ONE host array
     [k_pad, depth] (stacked and padded with NumPy, uploaded by the
     call), row slots stay device stacks, and every answer equals the
-    single batched path's and the serial path's."""
+    single batched path's and the serial path's. The slot is built
+    from the members' bits tuples: the resident device copies that the
+    single path made of the same vectors are never read back."""
     import jax
+    from jax._src import array as array_mod
 
     holder, idx, e = env
     from pilosa_tpu.storage.frame import Field
@@ -211,6 +215,22 @@ def test_concurrent_sums_under_bsi_ranges_fuse_with_host_bits(env):
     want = {q: single.execute("i", q)[0] for q in queries}
     assert want == {q: serial.execute("i", q)[0] for q in queries}
     assert len(set(map(tuple, want.values()))) > 1
+    # The same executor serves each once unfused: two resident vectors
+    # a query, which the fused rounds below must not read back.
+    e._co_enabled_memo = False
+    assert {q: e.execute("i", q)[0] for q in queries} == want
+    e._co_enabled_memo = True
+    resident = list(e._bsi_bits.values())
+    assert len(resident) == 2 * len(queries)
+    read_back = []
+    value = array_mod.ArrayImpl._value
+
+    def spy_value(arr):
+        if any(arr is r for r in resident):
+            read_back.append(arr)
+        return value.fget(arr)
+
+    monkeypatch.setattr(array_mod.ArrayImpl, "_value", property(spy_value))
 
     stacked = []
     real = e._co_stack_args
@@ -255,6 +275,7 @@ def test_concurrent_sums_under_bsi_ranges_fuse_with_host_bits(env):
         # Each member's own bounds, row by row.
         lows = [a for sp, a in zip(leaves0, args) if sp[0] == "bits"][0]
         assert len({tuple(r) for r in lows[:k].tolist()}) == k
+    assert read_back == []
 
 
 def test_concurrent_filtered_minmax_fuse(env):

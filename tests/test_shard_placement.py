@@ -324,6 +324,36 @@ def test_a_bsi_sum_under_a_range_is_exact(small):
                                               padded)
 
 
+def test_a_sum_under_two_ranges_reads_bits_replicated_over_the_mesh(small):
+    """Over a planes stack sharded on every device, the predicate bits
+    of two ``><`` ranges are resident arrays replicated over the same
+    devices, one upload a distinct vector: the answer equals the serial
+    path's and a brute force, and the same bounds in another order
+    upload nothing."""
+    e, values, _, _ = small
+    q = ('Sum(Intersect(Range(frame="b", v >< [{}, {}]), '
+         'Range(frame="b", v >< [{}, {}])), frame="b", field="v")')
+    one, other = q.format(150, 650, 350, 950), q.format(350, 950, 150, 650)
+    kept = [v for v in values.values() if 350 <= v <= 650]
+    serial = Executor(e.holder)
+    serial._force_path = "serial"
+    assert serial.execute("i", one)[0] == (sum(kept), len(kept))
+    had, misses = set(e._bsi_bits), e.bsi_bits["bsiBitsMisses"]
+    assert e.execute("i", one)[0] == (sum(kept), len(kept))
+    new = [e._bsi_bits[k] for k in e._bsi_bits if k not in had]
+    assert len(new) == 4 == e.bsi_bits["bsiBitsMisses"] - misses
+    (planes,) = [v[1] for k, v in e._stack_cache.items()
+                 if k[0] == "planes"]
+    for arr in new:
+        assert arr.sharding.device_set == planes.sharding.device_set \
+            == set(jax.devices())
+        assert arr.sharding.is_fully_replicated
+    hits = e.bsi_bits["bsiBitsHits"]
+    assert e.execute("i", other)[0] == (sum(kept), len(kept))
+    assert e.bsi_bits["bsiBitsMisses"] - misses == 4
+    assert e.bsi_bits["bsiBitsHits"] - hits == 4
+
+
 def test_a_time_range_count_is_exact(small):
     e, _, days, first = small
     for a, b in ((0, 7), (10, 41), (3, 88)):

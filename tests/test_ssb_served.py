@@ -216,31 +216,39 @@ def test_an_untraced_sum_emits_no_span(served, monkeypatch):
     assert ex.bsi_prelude["bsiPreludeMisses"] == before + 1
 
 
-# ------------------------- predicate bits: host arrays with the launch
+# ------------------- predicate bits: resident on the launch's devices
 
-def _spied_operands(server, client, monkeypatch, query):
-    """The operands the batched Sum's program was called with, and the
-    leaf specs they were built from (the planes of the summed field go
-    first and are no leaf)."""
+def _spied_operands(server, client, monkeypatch, query, profile=False):
+    """The operands the batched Sum's program was called with, the leaf
+    specs they were built from (the planes of the summed field go first
+    and are no leaf), and the request's ``resources`` where profiled."""
     from pilosa_tpu import executor as executor_mod
     from pilosa_tpu.pql.parser import parse
 
     ex = server.executor
     ex._force_path = "batched"
     seen = []
-    real = executor_mod._run_outputs
 
-    def spy(fn, stacks):
-        seen.append(list(stacks))
-        return real(fn, stacks)
+    def spied(real):
+        def spy(fn, stacks):
+            seen.append(list(stacks))
+            return real(fn, stacks)
+        return spy
 
     with monkeypatch.context() as patch:
-        patch.setattr(executor_mod, "_run_outputs", spy)
-        got = _ask(client, query)["results"]
+        for name in ("_run_outputs", "_run_outputs_split"):
+            patch.setattr(executor_mod, name,
+                          spied(getattr(executor_mod, name)))
+        doc = _ask(client, query, profile=profile)
     (stacks,) = seen
     leaves = ex._co_bsi_resolve("ssb", parse(query).calls[0])[5]
     assert len(leaves) == len(stacks) - 1
-    return got, leaves, stacks
+    return (doc["results"], leaves, stacks,
+            doc["profile"]["resources"] if profile else None)
+
+
+def _bits_operands(leaves, stacks):
+    return [st for sp, st in zip(leaves, stacks[1:]) if sp[0] == "bits"]
 
 
 @pytest.mark.parametrize("dates,d,q,depths", [
@@ -252,51 +260,106 @@ def _spied_operands(server, client, monkeypatch, query):
 ])
 def test_predicate_bits_reach_the_program_as_host_arrays(
         served, monkeypatch, dates, d, q, depths):
-    """A ``bits`` leaf's operand is a NumPy int32[depth] that the jitted
-    call uploads: an eager ``jnp.asarray`` would be a launched program
-    of its own before the scan (PR 32)."""
+    """A ``bits`` leaf's operand is the executor's resident device copy,
+    a ``jax.Array`` int32[depth] that holds the leaf's bits and lies on
+    the devices the launch spans, replicated: the jitted call has no
+    host array left to upload before the program can start."""
     server, client, reference = served
     query = SUM.format(dates=dates, d=d, q=q)
-    got, leaves, stacks = _spied_operands(server, client, monkeypatch, query)
+    got, leaves, stacks, _ = _spied_operands(server, client, monkeypatch,
+                                             query)
     assert got == [reference.answer(query)]
     bits = [(sp, st) for sp, st in zip(leaves, stacks[1:])
             if sp[0] == "bits"]
     assert [sp[2] for sp, _ in bits] == depths
+    launch = stacks[0].sharding.device_set
+    assert launch == set(jax.devices())
     for sp, st in bits:
-        assert type(st) is np.ndarray
+        assert isinstance(st, jax.Array)
         assert st.dtype == np.int32 and st.shape == (sp[2],)
-        assert st.tolist() == list(sp[1])
+        assert np.asarray(st).tolist() == list(sp[1])
+        assert st.sharding.device_set == launch
+        assert st.sharding.is_fully_replicated
+    # Rows and planes are still device stacks, sharded by slice.
     for st in stacks:
-        if isinstance(st, jax.Array):
-            assert st.ndim >= 2
-        else:
-            assert type(st) is np.ndarray and st.ndim == 1
-    # Rows and planes stay on the device.
-    assert sum(isinstance(st, jax.Array) for st in stacks) \
-        == len(stacks) - len(bits)
+        assert isinstance(st, jax.Array)
+    assert all(st.ndim >= 2 for sp, st in zip(leaves, stacks[1:])
+               if sp[0] != "bits")
+    assert stacks[0].ndim == 3
 
 
 def test_a_second_query_of_a_served_shape_compiles_nothing(served,
                                                            monkeypatch):
     """The bits travel as operands of the same aval, so distinct bounds
     share the executable: ``compileCalls`` of /debug/kernels stands
-    still, and the memoised prelude hands the same host arrays back."""
+    still. A distinct query whose bounds were all seen before gets the
+    very arrays the earlier queries got, and uploads nothing:
+    ``bsiBitsMisses`` stands still in its profile and in /debug/vars.
+    The memoised prelude of a repeat hands the same arrays back."""
     server, client, reference = served
-    server.executor._force_path = "batched"
+    ex = server.executor
+    ex._force_path = "batched"
     month = _bitmap("d_yearmonthnum", 199503)
     first = SUM.format(dates=month, d=">< [2, 4]", q=">< [11, 20]")
     second = SUM.format(dates=month, d=">< [6, 8]", q=">< [31, 40]")
-    assert _ask(client, first)["results"] == [reference.answer(first)]
+    # The discount bounds of the first, the quantity bounds of the second.
+    third = SUM.format(dates=month, d=">< [2, 4]", q=">< [31, 40]")
+    got, leaves, stacks, _ = _spied_operands(server, client, monkeypatch,
+                                             first)
+    assert got == [reference.answer(first)]
+    held = _bits_operands(leaves, stacks)
     before = compile_calls(client)[:2]
-    assert _ask(client, second)["results"] == [reference.answer(second)]
-    assert compile_calls(client)[:2] == before
-    # A repeat is a prelude hit: the memo pinned host arrays.
-    got, leaves, stacks = _spied_operands(server, client, monkeypatch,
-                                          second)
+    got, leaves, stacks, _ = _spied_operands(server, client, monkeypatch,
+                                             second)
     assert got == [reference.answer(second)]
+    held += _bits_operands(leaves, stacks)
     assert compile_calls(client)[:2] == before
-    assert [type(st) for sp, st in zip(leaves, stacks[1:])
-            if sp[0] == "bits"] == [np.ndarray] * 4
+    seen = client.json("GET", "/debug/vars")
+    got, leaves, stacks, res = _spied_operands(server, client, monkeypatch,
+                                               third, profile=True)
+    assert got == [reference.answer(third)]
+    shared = _bits_operands(leaves, stacks)
+    assert [a is b for a, b in zip(shared, held[:2] + held[6:])] \
+        == [True] * 4
+    assert (res["bsiBitsMisses"], res["bsiBitsHits"]) == (0, 4)
+    assert res["bsiPreludeMisses"] == 1
+    after = client.json("GET", "/debug/vars")
+    assert after["bsiBitsMisses"] == seen["bsiBitsMisses"] \
+        == ex.bsi_bits["bsiBitsMisses"]
+    assert after["bsiBitsHits"] == seen["bsiBitsHits"] + 4
+    assert compile_calls(client)[:2] == before
+    # A repeat is a prelude hit: the memo pinned the same arrays, and
+    # no lookup is made.
+    got, leaves, stacks, res = _spied_operands(server, client, monkeypatch,
+                                               third, profile=True)
+    assert got == [reference.answer(third)]
+    assert all(a is b for a, b in zip(_bits_operands(leaves, stacks),
+                                      shared))
+    assert res["bsiPreludeHits"] == 1
+    assert (res["bsiBitsMisses"], res["bsiBitsHits"]) == (0, 0)
+    assert compile_calls(client)[:2] == before
+
+
+def test_a_cold_executor_uploads_each_distinct_vector_once(served):
+    """The first query of a fresh executor looks up every predicate's
+    bits and finds none: one miss a distinct vector, no hit, and the
+    memo holds that many arrays. The same bounds under another date row
+    then hit every one."""
+    from pilosa_tpu.executor import Executor
+
+    server, _, reference = served
+    ex = Executor(server.holder)
+    ex._force_path = "batched"
+    query = SUM.format(dates=WEEK, d=">< [5, 7]", q=">< [26, 35]")
+    (got,) = ex.execute("ssb", query)
+    assert {"sum": got.sum, "count": got.count} == reference.answer(query)
+    assert ex.bsi_bits == {"bsiBitsHits": 0, "bsiBitsMisses": 4}
+    assert len(ex._bsi_bits) == 4
+    other = SUM.format(dates=YEAR, d=">< [5, 7]", q=">< [26, 35]")
+    (got,) = ex.execute("ssb", other)
+    assert {"sum": got.sum, "count": got.count} == reference.answer(other)
+    assert ex.bsi_bits == {"bsiBitsHits": 4, "bsiBitsMisses": 4}
+    assert len(ex._bsi_bits) == 4
 
 
 # -------------------------- every comparison, every aggregate under it
