@@ -365,6 +365,11 @@ class Executor:
         # _cache_mu: /debug/vars, beside the per-query keys.
         self.topn_select = {"topnSelectDevice": 0, "topnSelectHost": 0,
                             "topnSelectOverflow": 0}
+        # Slices the batched TopN phase 1 scanned, and those of them
+        # with a candidate whose count was not 0 (_batched_topn_phase1),
+        # process lifetime, under _cache_mu: /debug/vars, beside the
+        # per-query keys.
+        self.topn_slices = {"topnSlicesScanned": 0, "topnSlicesLive": 0}
         # Lookups of a BSI aggregate's prelude memo by outcome
         # (_prelude_record), process lifetime, under _cache_mu:
         # /debug/vars, beside the per-query keys.
@@ -4761,23 +4766,37 @@ class Executor:
             leaves, candidates_shrink=True)
         if counts is None or counts is BATCH_OVER_BUDGET:
             return counts
+        # A slice whose column of the count matrix is all zero holds no
+        # bit of the filter among its candidates: it was scanned and
+        # could give nothing.
+        live = int(np.count_nonzero(counts.any(axis=0)))
+        querystats.add("topnSlicesScanned", len(slices))
+        querystats.add("topnSlicesLive", live)
+        with self._cache_mu:
+            self.topn_slices["topnSlicesScanned"] += len(slices)
+            self.topn_slices["topnSlicesLive"] += live
 
         # Per-slice cache-membership mask + threshold, then the serial
         # path's per-slice top-n truncation before the merge.
-        mask = np.zeros(counts.shape, dtype=bool)
-        pos = {rid: i for i, rid in enumerate(union_ids)}
-        for j, es in enumerate(ent_sets):
-            for rid in es:
-                mask[pos[rid], j] = True
-        counts = np.where(mask & (counts >= min_threshold), counts, 0)
-        if n:
-            ids_arr = np.asarray(union_ids, dtype=np.uint64)
-            for j in range(counts.shape[1]):
-                col = counts[:, j]
-                nz = np.nonzero(col)[0]
-                if len(nz) > n:
-                    order = nz[np.lexsort((ids_arr[nz], -col[nz]))]
-                    col[order[n:]] = 0
+        with tracing.span("topn.mask", slices=len(slices),
+                          candidates=len(union_ids)) as msp:
+            mask = np.zeros(counts.shape, dtype=bool)
+            pos = {rid: i for i, rid in enumerate(union_ids)}
+            for j, es in enumerate(ent_sets):
+                for rid in es:
+                    mask[pos[rid], j] = True
+            counts = np.where(mask & (counts >= min_threshold), counts, 0)
+            cut = 0
+            if n:
+                ids_arr = np.asarray(union_ids, dtype=np.uint64)
+                for j in range(counts.shape[1]):
+                    col = counts[:, j]
+                    nz = np.nonzero(col)[0]
+                    if len(nz) > n:
+                        order = nz[np.lexsort((ids_arr[nz], -col[nz]))]
+                        col[order[n:]] = 0
+                        cut += 1
+            msp.tag(cut=cut)
         return self._topn_pairs(union_ids, counts)
 
     def _batched_src_fn(self, tree_key, plan, padded_n, width32):

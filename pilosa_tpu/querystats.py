@@ -120,6 +120,11 @@ MAX_HEDGE_LEGS = 64
 # path model's look at the loser (span ``path.probe``) and those of
 # them that did not finish: a serial loop past its deadline, a batched
 # program that declined (executor._run_path). 0 on a steady pick.
+# topnSlicesScanned / topnSlicesLive count, for each pass of the batched
+# TopN phase 1, the slices it scanned and those whose column of the
+# count matrix it fetched is not all zero: a slice where no candidate
+# meets the filter was read and could give nothing
+# (executor._batched_topn_phase1).
 # crossDeviceRows counts the rows copied from one device to another
 # while a sharded stack was built: a row's only clean device copy lay
 # on another chip than its shard's (the boundaries moved as the index
@@ -136,7 +141,8 @@ KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "bsiPreludeHits", "bsiPreludeMisses",
         "bsiBitsHits", "bsiBitsMisses",
         "rangeCoverViews", "rangeCoverOperands",
-        "pathProbes", "pathProbeAborts", "crossDeviceRows")
+        "pathProbes", "pathProbeAborts", "crossDeviceRows",
+        "topnSlicesScanned", "topnSlicesLive")
 
 
 class QueryStats:

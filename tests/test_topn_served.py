@@ -170,7 +170,7 @@ def _profiled(server, path):
     spans = doc["profile"]["spans"]
     by_id = {sp["spanId"]: sp for sp in spans}
     phases = {sp["name"]: sp for sp in spans if sp["name"].startswith("topn.")
-              and sp["name"] != "topn.stacks"}
+              and sp["name"] not in ("topn.stacks", "topn.mask")}
     assert set(phases) == {"topn.phase1", "topn.phase2"}
     call = next(sp for sp in spans if sp["name"] == "call:TopN")
     for sp in phases.values():
@@ -215,6 +215,13 @@ def test_a_profiled_topn_carries_phases_spans_and_counters(server, path):
     else:
         stacks = [sp for sp in spans if sp["name"] == "topn.stacks"]
         assert [sp["tags"]["candidates"] for sp in stacks] == [40, n]
+        # Phase 1's host mask over both slices' 40 cached rows; n = 50
+        # cuts no slice.
+        masks = [sp for sp in spans if sp["name"] == "topn.mask"]
+        assert [sp["tags"] for sp in masks] == [
+            {"slices": 2, "candidates": 40, "cut": 0}]
+        assert under(masks[0], phases["topn.phase1"])
+        assert (res["topnSlicesScanned"], res["topnSlicesLive"]) == (2, 2)
     assert res["topnCandidates"] == n and res["topnKept"] == n
     assert res["topnRecountsSkipped"] == 0
     # A scan reads every row of a fragment; the batched program the
