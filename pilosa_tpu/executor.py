@@ -370,6 +370,12 @@ class Executor:
         # process lifetime, under _cache_mu: /debug/vars, beside the
         # per-query keys.
         self.topn_slices = {"topnSlicesScanned": 0, "topnSlicesLive": 0}
+        # Phase-1 passes that found the grouped frame's ranked-cache
+        # membership memoised on its FragList, or built it
+        # (_topn_membership), process lifetime, under _cache_mu:
+        # /debug/vars, beside the per-query keys.
+        self.topn_membership = {"topnMembershipHits": 0,
+                                "topnMembershipMisses": 0}
         # Lookups of a BSI aggregate's prelude memo by outcome
         # (_prelude_record), process lifetime, under _cache_mu:
         # /debug/vars, beside the per-query keys.
@@ -4078,6 +4084,7 @@ class Executor:
             frags.tokens = self._frag_tokens(frags)
             frags.extent = self._list_extent(frags)
             frags.dense = set()
+            frags.members = None
             self.plans.put(key, epoch, frags)
             if walked is not None:
                 walked.append(key)
@@ -4716,6 +4723,33 @@ class Executor:
         counts = np.where(counts >= min_threshold, counts, 0)
         return self._topn_pairs(row_ids, counts)
 
+    def _topn_membership(self, frags):
+        """The ranked-cache membership of a ``FragList``'s fragments as
+        arrays, ``(ids, cand, col, widest)``: ``ids`` the sorted uint64
+        union of their cached row ids; one ``(cand, col)`` pair a
+        cached entry, its position in ``ids`` and its fragment's in
+        the list; ``widest`` the most ids one fragment caches. Built
+        once a list and kept on it (``frags.members``): a write that
+        reaches a ranked cache moves the index's mutation epoch, and
+        the epoch replaces the list. A hit touches no fragment; a build
+        reads evicted ones from their sidecar (``cache_entry_ids``).
+        Counted as ``topnMembershipHits`` / ``topnMembershipMisses``."""
+        got = frags.members
+        stat = "topnMembershipHits"
+        if got is None:
+            stat = "topnMembershipMisses"
+            per = [np.fromiter(frag.cache_entry_ids() if frag is not None
+                               else (), dtype=np.uint64) for frag in frags]
+            lens = np.array([len(a) for a in per])
+            ids, cand = np.unique(np.concatenate(per), return_inverse=True)
+            col = np.repeat(np.arange(len(per)), lens)
+            got = frags.members = (ids, cand, col,
+                                   int(lens.max(initial=0)))
+        querystats.add(stat)
+        with self._cache_mu:
+            self.topn_membership[stat] += 1
+        return got
+
     def _batched_topn_phase1(self, index, call, slices):
         """Approximate TopN phase 1 (candidate discovery) as one fused
         program, eligible when a src tree is present (without one the
@@ -4738,29 +4772,30 @@ class Executor:
 
         frags = self._frag_list(index, frame_name, view, slices)
         allowed = self._topn_attr_allowed(index, call, frame_name)
-        if allowed is None and any(
+        if allowed is None and frags.members is None and any(
                 frag is not None
                 and frag.cache_entry_count() > self.TOPN_CANDIDATES_MAX
                 for frag in frags):
             # One slice's own candidates already pass what the
             # candidate program takes, so no window of the slices
-            # fits: decline before the union below is built. (At
-            # 500,000 cached rows in one fragment that union cost the
+            # fits: decline before the membership below is built. (At
+            # 500,000 cached rows in one fragment that build cost the
             # path model's every-64th retry of this shape ~100 ms
             # only to be declined at the bucket check.)
             return None
-        # cache_entry_ids serves evicted fragments from the sidecar
-        # through the lazy path — phase 1 over a cold slice list no
-        # longer faults every fragment in just to read candidate ids.
-        ent_sets = [
-            frag.cache_entry_ids() if frag is not None else frozenset()
-            for frag in frags]
+        ids, cand, col, widest = self._topn_membership(frags)
+        if allowed is None and widest > self.TOPN_CANDIDATES_MAX:
+            return None
         if allowed is not None:
-            ent_sets = [es & allowed for es in ent_sets]
-
-        union_ids = sorted(set().union(*ent_sets))
-        if not union_ids:
+            keep = np.isin(ids, np.fromiter(allowed, dtype=np.uint64,
+                                            count=len(allowed)))
+            entry = keep[cand]
+            cand = (np.cumsum(keep) - 1)[cand[entry]]
+            col = col[entry]
+            ids = ids[keep]
+        if not len(ids):
             return []
+        union_ids = ids.tolist()
         counts = self._topn_candidate_counts(
             index, frame_name, view, union_ids, slices, tanimoto, plan,
             leaves, candidates_shrink=True)
@@ -4777,25 +4812,27 @@ class Executor:
             self.topn_slices["topnSlicesLive"] += live
 
         # Per-slice cache-membership mask + threshold, then the serial
-        # path's per-slice top-n truncation before the merge.
+        # path's per-slice top-n truncation before the merge: in each
+        # column over n pairs, the first n in (-count, id) order stay.
+        # The rows are in id order, so -count * rows + row is that
+        # order as one key, unique in a column: the n-th smallest key
+        # of a column is its cut.
         with tracing.span("topn.mask", slices=len(slices),
                           candidates=len(union_ids)) as msp:
             mask = np.zeros(counts.shape, dtype=bool)
-            pos = {rid: i for i, rid in enumerate(union_ids)}
-            for j, es in enumerate(ent_sets):
-                for rid in es:
-                    mask[pos[rid], j] = True
+            mask[cand, col] = True
             counts = np.where(mask & (counts >= min_threshold), counts, 0)
             cut = 0
             if n:
-                ids_arr = np.asarray(union_ids, dtype=np.uint64)
-                for j in range(counts.shape[1]):
-                    col = counts[:, j]
-                    nz = np.nonzero(col)[0]
-                    if len(nz) > n:
-                        order = nz[np.lexsort((ids_arr[nz], -col[nz]))]
-                        col[order[n:]] = 0
-                        cut += 1
+                over = np.count_nonzero(counts, axis=0) > n
+                cut = int(np.count_nonzero(over))
+                if cut:
+                    sub = counts[:, over]
+                    rows = len(union_ids)
+                    key = (np.arange(rows, dtype=np.int64)[:, None]
+                           - sub.astype(np.int64) * rows)
+                    sub[key > np.partition(key, n - 1, axis=0)[n - 1]] = 0
+                    counts[:, over] = sub
             msp.tag(cut=cut)
         return self._topn_pairs(union_ids, counts)
 

@@ -96,11 +96,16 @@ class FragList(list):
       outcome is kept: dense -> compressed needs an eviction or a
       mutation (both bump the epoch), compressed -> dense does not (a
       fault-in), so a compressed-everywhere row is probed every time.
+    - ``members``: the fragments' ranked-cache membership as arrays,
+      built by TopN phase 1 on its first pass over the list (None
+      until then): every write that reaches a ranked cache moves the
+      epoch, and the epoch replaces the list.
 
     Shared across concurrent queries and immutable by convention, as
-    ``SliceList``; ``dense`` only grows, and goes with its entry."""
+    ``SliceList``; ``dense`` only grows, ``members`` is set once, and
+    both go with their entry."""
 
-    __slots__ = ("tokens", "extent", "dense")
+    __slots__ = ("tokens", "extent", "dense", "members")
 
 
 def slice_key(slices):

@@ -125,6 +125,9 @@ MAX_HEDGE_LEGS = 64
 # count matrix it fetched is not all zero: a slice where no candidate
 # meets the filter was read and could give nothing
 # (executor._batched_topn_phase1).
+# topnMembershipHits / topnMembershipMisses count the phase-1 passes
+# that found the grouped frame's ranked-cache membership memoised on its
+# fragment list, or built it (executor._topn_membership).
 # crossDeviceRows counts the rows copied from one device to another
 # while a sharded stack was built: a row's only clean device copy lay
 # on another chip than its shard's (the boundaries moved as the index
@@ -142,7 +145,8 @@ KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "bsiBitsHits", "bsiBitsMisses",
         "rangeCoverViews", "rangeCoverOperands",
         "pathProbes", "pathProbeAborts", "crossDeviceRows",
-        "topnSlicesScanned", "topnSlicesLive")
+        "topnSlicesScanned", "topnSlicesLive",
+        "topnMembershipHits", "topnMembershipMisses")
 
 
 class QueryStats:

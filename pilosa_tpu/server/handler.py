@@ -1831,6 +1831,7 @@ class Handler:
         data.update(self.executor.topn_probe)
         data.update(self.executor.topn_select)
         data.update(self.executor.topn_slices)
+        data.update(self.executor.topn_membership)
         data.update(self.executor.bsi_prelude)
         data.update(self.executor.bsi_bits)
         data.update(self.executor.range_cover)

@@ -1562,6 +1562,9 @@ class Fragment:
                 if n:
                     self.cache.bulk_add(row_id, n)
             self.cache.invalidate()
+            # The ranked cache's membership may have changed: memos of
+            # it (TopN phase 1's, on the plan cache's FragList) go.
+            _bump_epoch(self.index)
 
     # ------------------------------------------------------- row plumbing
 
@@ -3483,6 +3486,7 @@ class Fragment:
                     self.cache.clear()
                     self._open_cache()
                     self._cache_loaded = True
+                    _bump_epoch(self.index)  # a new ranked membership
 
     def merge_from(self, fileobj):
         """Union-install a backup tar: every set bit in the snapshot
