@@ -376,6 +376,12 @@ class Executor:
         # /debug/vars, beside the per-query keys.
         self.topn_membership = {"topnMembershipHits": 0,
                                 "topnMembershipMisses": 0}
+        # Multi-slice TopN phase 2s read from phase 1's count matrices,
+        # and those that ran the recount (_execute_topn), process
+        # lifetime, under _cache_mu: /debug/vars, beside the per-query
+        # keys.
+        self.topn_recounts = {"topnRecountsDerived": 0,
+                              "topnRecountsRun": 0}
         # Lookups of a BSI aggregate's prelude memo by outcome
         # (_prelude_record), process lifetime, under _cache_mu:
         # /debug/vars, beside the per-query keys.
@@ -816,8 +822,7 @@ class Executor:
             # heat comes from the fragment read layer, which only
             # individual-slice work touches).
             hm.note_query(index, len(slices))
-        if (opt.remote or self.cluster is None
-                or len(self.cluster.nodes) <= 1 or self.client is None):
+        if self._all_local(opt):
             result = self._local_exec(call, slices, map_fn, reduce_fn,
                                       batch_fn)
             return None if result is BATCH_EMPTY else result
@@ -862,6 +867,12 @@ class Executor:
             # collectively). Bounded: churn past the retries returns
             # the last answer, the pre-recheck behavior.
         return result
+
+    def _all_local(self, opt):
+        """Whether every slice a call is given is this node's to run:
+        one node, no peer client, or a remote subquery."""
+        return (opt.remote or self.cluster is None
+                or len(self.cluster.nodes) <= 1 or self.client is None)
 
     def _fanout_map_reduce(self, index, slices, call, opt, map_fn,
                            reduce_fn, batch_fn):
@@ -4678,14 +4689,37 @@ class Executor:
         return counts
 
     @staticmethod
-    def _topn_pairs(row_ids, counts):
-        """Sum the per-slice count matrix and sort pairs the way
+    def _topn_pairs(row_ids, totals):
+        """The pairs of the rows' totals that are not 0, sorted the way
         pairs_add orders a merged result: (-count, id)."""
-        totals = counts.sum(axis=1)
         pairs = [(int(rid), int(t))
                  for rid, t in zip(row_ids, totals) if t > 0]
         pairs.sort(key=lambda rc: (-rc[1], rc[0]))
         return pairs
+
+    @staticmethod
+    def _topn_derived(passes, slices, row_ids):
+        """Phase 2's pairs for ``row_ids`` read from the count matrices
+        phase 1 fetched, or None where they cannot be known from them.
+        ``passes`` holds one ``(slices, ids, totals)`` a batched phase-1
+        pass (_batched_topn_phase1): the pass's sorted candidate ids and
+        each one's exact count over its slices, thresholded a slice, as
+        the recount takes it. They are the recount's answer where the
+        passes cover ``slices`` once each and every id is a candidate of
+        every pass: a row absent from a pass's matrix may still hold
+        bits in its slices."""
+        covered = [s for part, _, _ in passes for s in part]
+        if sorted(covered) != sorted(slices):
+            return None
+        want = np.asarray(row_ids, dtype=np.uint64)
+        totals = np.zeros(len(want), dtype=np.int64)
+        for _, ids, exact in passes:
+            pos = np.searchsorted(ids, want)
+            if not np.array_equal(ids[np.minimum(pos, len(ids) - 1)],
+                                  want):
+                return None
+            totals += exact[pos]
+        return Executor._topn_pairs(row_ids, totals)
 
     def _batched_topn_ids(self, index, call, slices):
         """Exact TopN re-query (phase 2): per-candidate popcounts over
@@ -4721,7 +4755,7 @@ class Executor:
         if counts is None or counts is BATCH_OVER_BUDGET:
             return counts
         counts = np.where(counts >= min_threshold, counts, 0)
-        return self._topn_pairs(row_ids, counts)
+        return self._topn_pairs(row_ids, counts.sum(axis=1))
 
     def _topn_membership(self, frags):
         """The ranked-cache membership of a ``FragList``'s fragments as
@@ -4750,7 +4784,7 @@ class Executor:
             self.topn_membership[stat] += 1
         return got
 
-    def _batched_topn_phase1(self, index, call, slices):
+    def _batched_topn_phase1(self, index, call, slices, passes=None):
         """Approximate TopN phase 1 (candidate discovery) as one fused
         program, eligible when a src tree is present (without one the
         serial path reads host-cached row counts and never touches the
@@ -4759,7 +4793,12 @@ class Executor:
         that slice's own cache membership (ref: topBitmapPairs
         fragment.go:965), per-slice threshold + top-n truncation, then
         the cross-slice pairs_add merge — bit-identical to the serial
-        per-fragment walk. None when ineligible."""
+        per-fragment walk. None when ineligible.
+
+        A list given as ``passes`` gets ``(slices, ids, totals)`` of a
+        pass that fetched its matrix: the sorted candidate ids and each
+        one's thresholded count over the slices before any mask or cut,
+        what phase 2 would count for them (_topn_derived)."""
         if not slices:
             return None
         frame_name, view, n, min_threshold, tanimoto = (
@@ -4801,6 +4840,9 @@ class Executor:
             leaves, candidates_shrink=True)
         if counts is None or counts is BATCH_OVER_BUDGET:
             return counts
+        if passes is not None:
+            passes.append((slices, ids, np.where(
+                counts >= min_threshold, counts, 0).sum(axis=1)))
         # A slice whose column of the count matrix is all zero holds no
         # bit of the filter among its candidates: it was scanned and
         # could give nothing.
@@ -4834,7 +4876,7 @@ class Executor:
                     sub[key > np.partition(key, n - 1, axis=0)[n - 1]] = 0
                     counts[:, over] = sub
             msp.tag(cut=cut)
-        return self._topn_pairs(union_ids, counts)
+        return self._topn_pairs(union_ids, counts.sum(axis=1))
 
     def _batched_src_fn(self, tree_key, plan, padded_n, width32):
         import jax
@@ -5682,13 +5724,19 @@ class Executor:
         slice phase 1's pairs are the totals already (exact counts
         under the same gate, threshold and filter, in ``(-count, id)``
         order, cut at ``n``), so they are the answer and the re-query
-        is skipped: a stated departure from ``executeTopN``, whose
-        result it equals (PARITY.md). A call that arrives with
-        ``ids`` is phase 2 by definition and always runs."""
+        is skipped. Over many slices of this node, where the batched
+        phase 1 fetched every slice's exact counts for every id it
+        returns (before its mask and cut), the re-query's answer is
+        read from those counts (_topn_derived) and the second pass does
+        not run. Both are stated departures from ``executeTopN``, whose
+        result they equal (PARITY.md). A serial phase 1, a cluster's
+        coordinator and an ids set some pass did not count still run
+        the re-query. A call that arrives with ``ids`` is phase 2 by
+        definition and always runs."""
         ids_arg, has_ids = call.uint_slice_arg("ids")
         n, _ = call.uint_arg("n")
 
-        def phase(name, phase_call):
+        def phase(name, phase_call, passes=None):
             """One pass over the slices under its span, tagged with
             the path that served it (``serial`` / ``batched``), the
             ids it was given and their bucket."""
@@ -5697,7 +5745,7 @@ class Executor:
                 traced = sp is not tracing.NOP_SPAN and qs is not None
                 mark = qs.mark() if traced else None
                 pairs = self._execute_topn_slices(index, phase_call,
-                                                  slices, opt)
+                                                  slices, opt, passes)
                 if traced:
                     ids = phase_call.args.get("ids") or ()
                     sp.tag(path=qs.served_since(mark),
@@ -5706,15 +5754,40 @@ class Executor:
             return pairs
 
         def compute():
+            # This request's batched phase-1 passes (_topn_derived),
+            # kept where a recount follows and every slice is this
+            # node's.
+            passes = ([] if len(slices) > 1 and not has_ids
+                      and not opt.remote and self._all_local(opt)
+                      else None)
             pairs = phase("topn.phase2" if has_ids else "topn.phase1",
-                          call)
+                          call, passes)
             if pairs and not has_ids and not opt.remote:
                 if len(slices) > 1:
-                    other = call.clone()
-                    other.args["ids"] = sorted(rid for rid, _ in pairs)
-                    querystats.add("topnCandidates",
-                                   len(other.args["ids"]))
-                    pairs = phase("topn.phase2", other)
+                    ids = sorted(rid for rid, _ in pairs)
+                    querystats.add("topnCandidates", len(ids))
+                    derived = (None if passes is None else
+                               self._topn_derived(passes, slices, ids))
+                    if derived is None:
+                        stat = "topnRecountsRun"
+                        other = call.clone()
+                        other.args["ids"] = ids
+                        pairs = phase("topn.phase2", other)
+                    else:
+                        # The span stays where the second pass would
+                        # have been, as where it is skipped below; the
+                        # answer is the batched program's matrix, so
+                        # the phase counts as that tier's serve.
+                        stat = "topnRecountsDerived"
+                        with tracing.span(
+                                "topn.phase2", path="derived",
+                                candidates=len(ids),
+                                bucket=self._candidate_bucket(len(ids))):
+                            querystats.note_tier("batched")
+                            pairs = derived
+                    querystats.add(stat)
+                    with self._cache_mu:
+                        self.topn_recounts[stat] += 1
                 else:
                     # The span stays where the second pass would have
                     # been, so a trace reads what it costs a request.
@@ -5747,12 +5820,13 @@ class Executor:
     # memo's worst case at tens of MB without result-memo accounting.
     TOPN_DISCOVERY_MEMO_MAX = 4
 
-    def _execute_topn_slices(self, index, call, slices, opt):
+    def _execute_topn_slices(self, index, call, slices, opt, passes=None):
         """Both phases batch this host's slice set on the mesh:
         explicit-ids calls (phase 2, or arriving at a remote node) go
         through the exact re-query kernel; candidate discovery with a
-        src tree goes through the phase-1 kernel; cross-node results
-        merge via pairs_add.
+        src tree goes through the phase-1 kernel (``passes`` as
+        _batched_topn_phase1 takes it); cross-node results merge via
+        pairs_add.
 
         Src-less discovery has no device kernel — it reads host cache
         metadata fragment by fragment, which at 10k-slice scale is
@@ -5781,10 +5855,7 @@ class Executor:
 
         discovery = (not has_ids and not call.children
                      and self._force_path is None)
-        all_local = (self.cluster is None
-                     or len(self.cluster.nodes) <= 1 or opt.remote
-                     or self.client is None)
-        if discovery and all_local:
+        if discovery and self._all_local(opt):
             # Single-node, or serving a remote subquery: every slice
             # handed in is ours — one memo entry covers the set.
             return self._topn_discovery_memoized(index, call, slices)
@@ -5823,7 +5894,7 @@ class Executor:
                     (rem or [])
             return out
         return self._topn_map_reduce(index, call, slices, opt,
-                                     has_ids) or []
+                                     has_ids, passes) or []
 
     def _topn_discovery_memoized(self, index, call, slices):
         """Epoch-validated memo over a LOCAL slice subset's src-less
@@ -5860,11 +5931,12 @@ class Executor:
             memo[memo_key] = (epoch, tuple(out))
         return out
 
-    def _topn_map_reduce(self, index, call, slices, opt, has_ids):
+    def _topn_map_reduce(self, index, call, slices, opt, has_ids,
+                         passes=None):
         def batch_fn(ns):
             if has_ids:
                 return self._batched_topn_ids(index, call, ns)
-            return self._batched_topn_phase1(index, call, ns)
+            return self._batched_topn_phase1(index, call, ns, passes)
 
         def map_fn(s):
             return self._execute_topn_slice(index, call, s)

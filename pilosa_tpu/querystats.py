@@ -92,6 +92,10 @@ MAX_HEDGE_LEGS = 64
 # its exact re-query; topnKept the pairs a TopN call returned;
 # topnRecountsSkipped is 1 for a TopN over one slice, answered from
 # phase 1 alone (its pairs are the totals: executor._execute_topn).
+# topnRecountsDerived is 1 for a TopN over many slices whose phase 2 was
+# read from the count matrices the batched phase 1 fetched, with no
+# second pass; topnRecountsRun is 1 where that recount ran, serial or
+# batched (executor._topn_derived says when it cannot be read).
 # topnProbeFromMirror / topnProbeFromHost count the per-fragment TopN
 # scans with a src by where the probe came from: read inside the
 # scan's program from the fragment's HBM mirror (the child is a plain
@@ -146,7 +150,8 @@ KEYS = ("slices", "blocks", "bytesPopcounted", "cacheHits",
         "rangeCoverViews", "rangeCoverOperands",
         "pathProbes", "pathProbeAborts", "crossDeviceRows",
         "topnSlicesScanned", "topnSlicesLive",
-        "topnMembershipHits", "topnMembershipMisses")
+        "topnMembershipHits", "topnMembershipMisses",
+        "topnRecountsDerived", "topnRecountsRun")
 
 
 class QueryStats:

@@ -1832,6 +1832,7 @@ class Handler:
         data.update(self.executor.topn_select)
         data.update(self.executor.topn_slices)
         data.update(self.executor.topn_membership)
+        data.update(self.executor.topn_recounts)
         data.update(self.executor.bsi_prelude)
         data.update(self.executor.bsi_bits)
         data.update(self.executor.range_cover)

@@ -344,9 +344,13 @@ def test_batched_vs_serial_full_surface(tmp_path):
         # cannot pass vacuously as serial-vs-serial.
         engaged = []
         saved = {a: getattr(e, a) for a in batched_attrs}
+        # A TopN's phase 2 read from its batched phase 1's count matrix
+        # (_topn_derived) is that path's answer too.
+        saved["_topn_derived"] = e._topn_derived
         entry_points = ("_batched_count", "_batched_bitmap",
                         "_batched_sum", "_batched_min_max",
-                        "_batched_topn_ids", "_batched_topn_phase1")
+                        "_batched_topn_ids", "_batched_topn_phase1",
+                        "_topn_derived")
 
         def wrap(fn):
             def inner(*args, **kw):

@@ -200,7 +200,8 @@ def test_budget_windowed_batching(tmp_path):
     e._batched_count = probe("count", e._batched_count)
     e._batched_sum = probe("sum", e._batched_sum)
     e._batched_min_max = probe("minmax", e._batched_min_max)
-    e._batched_topn_ids = probe("topn", e._batched_topn_ids)
+    e._batched_topn_phase1 = probe("topn", e._batched_topn_phase1)
+    e._batched_topn_ids = probe("topn_ids", e._batched_topn_ids)
 
     # (query, rows its stacks need) → budget sized so the full list
     # exceeds it but ≥8-slice windows fit: rows × 20-slice windows.
@@ -211,6 +212,9 @@ def test_budget_windowed_batching(tmp_path):
         ('Sum(frame="g", field="v")', 8),      # depth 7 + exists
         ('Min(frame="g", field="v")', 8),
         ('TopN(Bitmap(frame="f", rowID=1), frame="f", n=2)', 4),
+        # phase 1's windows fetched every candidate's counts above, so
+        # only an explicit-ids call runs the re-query's windows
+        ('TopN(Bitmap(frame="f", rowID=1), frame="f", ids=[1, 2])', 4),
     ]
     for q, rows in cases:
         e.STACK_CACHE_BYTES = rows * 20 * word32 * 4
@@ -224,7 +228,8 @@ def test_budget_windowed_batching(tmp_path):
             setattr(e3, a, lambda *ar, **kw: None)
         serial = e3.execute("i", q)[0]
         assert windowed == full == serial, q
-    assert set(window_hits) == {"count", "sum", "minmax", "topn"}, \
+    assert set(window_hits) == {"count", "sum", "minmax", "topn",
+                                "topn_ids"}, \
         f"sub-window batches engaged only for {sorted(window_hits)}"
     holder.close()
 

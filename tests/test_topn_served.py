@@ -189,16 +189,25 @@ def _profiled(server, path):
 @pytest.mark.parametrize("server", [2], indirect=True)
 @pytest.mark.parametrize("path", ["serial", "batched"])
 def test_a_profiled_topn_carries_phases_spans_and_counters(server, path):
-    """Two slices: both phases run, each tagged with the path that
-    served it."""
+    """Two slices: phase 1 runs tagged with the path that served it.
+    The serial phase 2 runs the recount; after the batched phase 1 the
+    recount is read from the count matrix phase 1 fetched, and its span
+    stays where the second pass would have been, tagged ``derived``,
+    with nothing under it."""
     pairs, spans, phases, under, res = _profiled(server, path)
     n = len(pairs)
     assert phases["topn.phase2"]["tags"] == {
-        "path": path, "candidates": n,
-        "bucket": Executor._candidate_bucket(n)}
-    for phase in phases.values():
-        names = {sp["name"] for sp in spans if under(sp, phase)}
-        assert PHASE_SPANS[path] <= names, (phase["name"], names)
+        "path": "derived" if path == "batched" else path,
+        "candidates": n, "bucket": Executor._candidate_bucket(n)}
+    names = {sp["name"] for sp in spans if under(sp, phases["topn.phase1"])}
+    assert PHASE_SPANS[path] <= names, names
+    names = {sp["name"] for sp in spans if under(sp, phases["topn.phase2"])}
+    if path == "serial":
+        assert PHASE_SPANS[path] <= names, names
+        assert (res["topnRecountsRun"], res["topnRecountsDerived"]) == (1, 0)
+    else:
+        assert names == {"topn.phase2"}
+        assert (res["topnRecountsRun"], res["topnRecountsDerived"]) == (0, 1)
     if path == "serial":
         assert all(sp["tags"]["rows"] == 40 for sp in spans
                    if sp["name"] == "top.src")
@@ -214,7 +223,7 @@ def test_a_profiled_topn_carries_phases_spans_and_counters(server, path):
                 res["topnSelectOverflow"]) == (2, 2, 0)
     else:
         stacks = [sp for sp in spans if sp["name"] == "topn.stacks"]
-        assert [sp["tags"]["candidates"] for sp in stacks] == [40, n]
+        assert [sp["tags"]["candidates"] for sp in stacks] == [40]
         # Phase 1's host mask over both slices' 40 cached rows; n = 50
         # cuts no slice.
         masks = [sp for sp in spans if sp["name"] == "topn.mask"]
@@ -225,9 +234,9 @@ def test_a_profiled_topn_carries_phases_spans_and_counters(server, path):
     assert res["topnCandidates"] == n and res["topnKept"] == n
     assert res["topnRecountsSkipped"] == 0
     # A scan reads every row of a fragment; the batched program the
-    # candidates it was given, a slice each.
-    assert res["topnRowsScanned"] == 2 * (80 if path == "serial"
-                                          else 40 + n)
+    # candidates it was given, a slice each, in phase 1 alone.
+    assert res["topnRowsScanned"] == 2 * (80 if path == "serial" else 40)
+    # A derived phase 2 is the batched matrix's answer, and counts so.
     assert res["servedBy"] == {path: 2}
 
 
@@ -473,7 +482,10 @@ def test_two_slices_keep_the_exact_requery(tmp_path):
     """The condition is the slice count: over two slices a row cut from
     one slice's top ``n`` comes back with its whole total, which only
     phase 2 can know. Row 2 is fourth of slice 0 (cut at n=3) and
-    second of slice 1: 1 + 3 puts it ahead of row 0's 3 + 0."""
+    second of slice 1: 1 + 3 puts it ahead of row 0's 3 + 0. The
+    batched phase 1 fetched that total before its cut, so phase 2 is
+    read from its matrix (the default path's first query is batched);
+    after the serial phase 1 the recount runs."""
     h = Holder(str(tmp_path / "d")).open()
     try:
         h.create_index("i").create_frame("f")
@@ -494,6 +506,9 @@ def test_two_slices_keep_the_exact_requery(tmp_path):
             assert stats["topnRecountsSkipped"] == 0
             # slice 0 gave {9, 0, 1}, slice 1 {9, 2}
             assert stats["topnCandidates"] == 4
+            assert (stats["topnRecountsDerived"],
+                    stats["topnRecountsRun"]) == (
+                (0, 1) if path == "serial" else (1, 0)), path
         # Phase 1 alone has row 2 short (3 of its 4): what a skip here
         # would have answered.
         ex._force_path = "serial"
@@ -510,7 +525,12 @@ def test_two_slices_keep_the_exact_requery(tmp_path):
                 ('TopN(frame="inv", n=2)', [(3, 2), (4, 1)], 0)):
             with querystats.scope(querystats.QueryStats()) as qs:
                 assert ex.execute("i", pql)[0] == want
-                assert qs.to_dict()["topnRecountsSkipped"] == skipped
+                stats = qs.to_dict()
+            assert stats["topnRecountsSkipped"] == skipped
+            # No src: phase 1 reads the caches' counts, fetches no
+            # matrix, and the recount over two slices runs.
+            assert stats["topnRecountsRun"] == 1 - skipped
+            assert stats["topnRecountsDerived"] == 0
     finally:
         h.close()
 
@@ -1272,3 +1292,225 @@ def test_a_profile_says_where_the_selection_ran(server):
                                 timeout=30) as resp:
         totals = json.loads(resp.read())
     assert {k: totals["topnSelect" + k.capitalize()] for k in seen} == seen
+
+
+# --------- many slices: phase 2 read from phase 1's count matrix
+
+MANY = 16          # slices of the standard view; two of the inverse view
+HALF = MANY // 2
+
+
+def _many_slices(path):
+    """(holder, executor): over ``MANY`` slices frame ``f`` (the default
+    ranked cache) and frame ``c`` (a ranked cache of 4) hold the same 24
+    rows a slice, each in 1-12 random columns of the slice's first 64
+    (counts tie), rows 30 and 31 near copies of frame ``g``'s row 0
+    (one and one-to-four columns short: the Tanimoto gate keeps or drops
+    them slice by slice); every third row carries ``cat``. Frame ``h``
+    holds rows 0..23 in the first half of the slices and rows no other
+    slice holds in each of the second (a candidate set that only the
+    first half fits). Frame ``inv`` has rows on both sides of
+    ``SLICE_WIDTH``: its inverse view has two slices."""
+    h = Holder(str(path / "d")).open()
+    rng = np.random.default_rng(20261018)
+    idx = h.create_index("i")
+    for name in ("f", "g", "h"):
+        idx.create_frame(name)
+    idx.create_frame("c", FrameOptions(cache_type="ranked", cache_size=4))
+    idx.create_frame("inv", FrameOptions(inverse_enabled=True))
+    for sl in range(MANY):
+        base = sl * SLICE_WIDTH
+        src = rng.choice(64, 32, replace=False)
+        rows, cols = [0] * 32, (base + src).tolist()
+        idx.frame("g").import_bits(rows, cols)
+        rows, cols = [], []
+        for row in range(24):
+            k = int(rng.integers(1, 13))
+            rows += [row] * k
+            cols += (base + rng.choice(64, k, replace=False)).tolist()
+        short = int(rng.integers(1, 5))
+        for row, drop in ((30, 1), (31, short)):
+            rows += [row] * (32 - drop)
+            cols += (base + src[drop:]).tolist()
+        for name in ("f", "c"):
+            idx.frame(name).import_bits(rows, cols)
+        hrows = [r if sl < HALF else 100 * sl + r for r in rows]
+        idx.frame("h").import_bits(hrows, cols)
+    rows, cols = [], []
+    for row in list(range(12)) + list(range(SLICE_WIDTH, SLICE_WIDTH + 12)):
+        k = int(rng.integers(4, 24))
+        rows += [row] * k
+        cols += rng.choice(32, k, replace=False).tolist()
+    idx.frame("inv").import_bits(rows, cols)
+    ex = Executor(h)
+    for row in range(0, 32, 3):
+        ex.execute("i", f'SetRowAttrs(frame="f", rowID={row}, '
+                        f'cat="{"x" if row % 2 else "y"}")')
+    assert idx.max_slice() == MANY - 1 and idx.max_inverse_slice() == 1
+    return h, ex
+
+
+@pytest.fixture(scope="module")
+def many_slices(tmp_path_factory):
+    h, ex = _many_slices(tmp_path_factory.mktemp("many_slices"))
+    yield ex
+    h.close()
+
+
+G0 = 'Bitmap(frame="g", rowID=0)'
+# (id, PQL with {ids} where an ``ids=[...], `` argument goes, n, what
+# the case is there for)
+MANY_FORMS = [
+    ("cut_in_tie", f'TopN({G0}, frame="f", {{ids}}n=8)', 8, "tie"),
+    ("threshold", f'TopN({G0}, frame="f", {{ids}}n=6, threshold=3)', 6,
+     "threshold"),
+    ("tanimoto50", f'TopN({G0}, frame="f", {{ids}}n=3, '
+                   'tanimotoThreshold=50)', 3, "gate"),
+    ("tanimoto90", f'TopN({G0}, frame="f", {{ids}}tanimotoThreshold=90)',
+     0, "gate"),
+    ("filters", f'TopN({G0}, frame="f", {{ids}}n=3, field="cat", '
+                'filters=["x"])', 3, "filter"),
+    ("small_cache", f'TopN({G0}, frame="c", {{ids}})', 0, "mask"),
+    ("inverse", 'TopN(Bitmap(frame="inv", columnID=5), frame="inv", '
+                '{ids}n=3, inverse=true)', 3, "inverse"),
+]
+
+
+def _two_phase(ex, pql, n):
+    """Phase 1 alone (a remote subquery skips the recount), then the
+    explicit-ids re-query of its ids, then the cut at ``n``: the
+    two-phase TopN the derived phase 2 stands in for, and phase 1's
+    pairs."""
+    phase1 = ex.execute("i", pql.format(ids=""),
+                        opt=ExecOptions(remote=True))[0]
+    ids = sorted(rid for rid, _ in phase1)
+    if not ids:
+        return [], phase1
+    out = ex.execute("i", pql.format(ids=f"ids={ids}, "))[0]
+    return (out[:n] if n else out), phase1
+
+
+@pytest.mark.parametrize("path", ["batched", "serial"])
+@pytest.mark.parametrize("name, pql, n, what", MANY_FORMS,
+                         ids=[f[0] for f in MANY_FORMS])
+def test_a_derived_phase_2_equals_the_two_phase_result(
+        many_slices, name, pql, n, what, path):
+    """Over many slices the batched phase 1's count matrix holds the
+    exact count of every id it returns in every slice, before its mask
+    and cut: the phase 2 read from it equals the two-phase result, as
+    lists, for every form. A serial phase 1 fetches no matrix and runs
+    the recount."""
+    ex = many_slices
+    ex._force_path = path
+    with querystats.scope(querystats.QueryStats()) as qs:
+        got = ex.execute("i", pql.format(ids=""))[0]
+        stats = qs.to_dict()
+    want, phase1 = _two_phase(ex, pql, n)
+    assert got == want and got
+    assert (stats["topnRecountsDerived"], stats["topnRecountsRun"]) == (
+        (1, 0) if path == "batched" else (0, 1))
+    assert stats["topnCandidates"] == len(phase1)
+    # What the case is there for.
+    short = {rid: c for rid, c in phase1 if c < dict(got).get(rid, 0)}
+    counts = [c for _, c in got]
+    if what == "tie":
+        # A row cut from some slice's top n comes back whole, and the
+        # cut at n falls inside a count tie of the whole list.
+        full, _ = _two_phase(ex, pql.replace("n=8", "n=0"), 0)
+        assert short and full[n - 1][1] == full[n][1]
+    elif what == "threshold":
+        assert min(counts) >= 3
+    elif what == "gate":
+        assert {30, 31} & set(dict(got))
+    elif what == "filter":
+        assert all(rid % 3 == 0 and rid % 2 for rid, _ in got)
+    elif what == "mask":
+        # No cut: every short count is a slice's nonzero count the
+        # cache mask dropped.
+        assert short
+
+
+def _fresh(ex):
+    """An executor over the same holder with no memo yet: a memoised
+    prelude of the whole slice list would skip the checks that make
+    windows."""
+    fresh = Executor(ex.holder)
+    fresh._force_path = "batched"
+    return fresh
+
+
+def _phase1_passes(ex, monkeypatch):
+    """The slices of each batched phase-1 pass that fetched its
+    matrix."""
+    seen = []
+    real = ex._batched_topn_phase1
+
+    def spy(index, call, slices, passes=None):
+        out = real(index, call, slices, passes)
+        if isinstance(out, list):
+            seen.append(len(slices))
+        return out
+
+    monkeypatch.setattr(ex, "_batched_topn_phase1", spy)
+    return seen
+
+
+def _half_budget(ex, monkeypatch):
+    """A device budget that holds half the slices."""
+    real = ex._fits_device_budget
+    monkeypatch.setattr(
+        ex, "_fits_device_budget",
+        lambda rows, padded, width32=None:
+        padded <= HALF and real(rows, padded, width32))
+
+
+@pytest.mark.parametrize("frame, derived", [("f", True), ("c", False),
+                                            ("h", False)])
+def test_halved_windows_add_their_totals_where_every_id_is_in_both(
+        many_slices, monkeypatch, frame, derived):
+    """Phase 1 runs as two windows, each fetching its own matrix. Where
+    every candidate phase 1 returns is a row of both matrices (frame
+    f's caches hold every row with a bit), the recount is the windows'
+    totals added. Where one is not, its counts in that half are in no
+    matrix (a cache of 4 left it out; frame h's halves hold other rows)
+    and the recount runs: the totals added would be short."""
+    pql = f'TopN({G0}, frame="{frame}", {{ids}}n=4)'
+    want, _ = _two_phase(many_slices, pql, 4)
+    ex = _fresh(many_slices)
+    _half_budget(ex, monkeypatch)
+    seen = _phase1_passes(ex, monkeypatch)
+    with querystats.scope(querystats.QueryStats()) as qs:
+        got = ex.execute("i", pql.format(ids=""))[0]
+        stats = qs.to_dict()
+    assert got == want
+    assert seen == [HALF, HALF]
+    # Read from the caches alone: whether each half's candidates hold
+    # every id phase 1 gave.
+    ids = {rid for rid, _ in ex.execute(
+        "i", pql.format(ids=""), opt=ExecOptions(remote=True))[0]}
+    halves = [set().union(*(
+        ex.holder.fragment("i", frame, "standard", sl).cache_entry_ids()
+        for sl in part)) for part in (range(HALF), range(HALF, MANY))]
+    assert all(ids <= half for half in halves) == derived
+    assert (stats["topnRecountsDerived"], stats["topnRecountsRun"]) == (
+        (1, 0) if derived else (0, 1))
+
+
+def test_a_fallback_to_serial_mid_list_runs_the_recount(many_slices,
+                                                       monkeypatch):
+    """Frame h's candidates fit the program over the first half of the
+    slices and not over any window of the second: the first window's
+    matrix is fetched, the list falls back to the serial walk, and the
+    matrix covers half the slices, so the recount runs."""
+    pql = f'TopN({G0}, frame="h", {{ids}}n=4)'
+    want, _ = _two_phase(many_slices, pql, 4)
+    ex = _fresh(many_slices)
+    monkeypatch.setattr(ex, "TOPN_CANDIDATES_MAX", 32)
+    seen = _phase1_passes(ex, monkeypatch)
+    with querystats.scope(querystats.QueryStats()) as qs:
+        got = ex.execute("i", pql.format(ids=""))[0]
+        stats = qs.to_dict()
+    assert got == want
+    assert seen == [HALF]
+    assert stats["servedBy"]["serial"] >= 1
+    assert (stats["topnRecountsDerived"], stats["topnRecountsRun"]) == (0, 1)

@@ -87,7 +87,8 @@ def test_a_profiled_multi_slice_topn_reads_its_mask_and_slices(server, n, cut):
     spans = doc["profile"]["spans"]
     masks = [sp for sp in spans if sp["name"] == "topn.mask"]
     by_id = {sp["spanId"]: sp for sp in spans}
-    # One mask, phase 1's: the re-query is given ids and masks nothing.
+    # One mask, phase 1's: the re-query is read from phase 1's matrix
+    # and masks nothing.
     assert len(masks) == 1
     assert by_id[masks[0]["parentId"]]["name"] != "topn.phase2"
     candidates = len(set().union(*map(set, F_ROWS.values())))
@@ -96,8 +97,11 @@ def test_a_profiled_multi_slice_topn_reads_its_mask_and_slices(server, n, cut):
     res = doc["profile"]["resources"]
     # Slice 2 holds candidates but none of g's bits: scanned, not live.
     assert (res["topnSlicesScanned"], res["topnSlicesLive"]) == (3, 2)
+    # Phase 1's matrix holds the recount: both phases are its serves.
     assert res["servedBy"] == {"batched": 2}
+    assert (res["topnRecountsDerived"], res["topnRecountsRun"]) == (1, 0)
     after = _get(server, "/debug/vars")
+    assert after["topnRecountsDerived"] - before["topnRecountsDerived"] == 1
     assert after["topnSlicesScanned"] - before["topnSlicesScanned"] == 3
     assert after["topnSlicesLive"] - before["topnSlicesLive"] == 2
 
