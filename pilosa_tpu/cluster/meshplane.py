@@ -46,14 +46,12 @@ the collective path at commit with zero failed ops.
 """
 import logging
 import threading
-import time
 from collections import OrderedDict
 
 import numpy as np
 
 from pilosa_tpu import WORDS_PER_SLICE, lockcheck, querystats, tracing
 from pilosa_tpu.cluster.placement import PHASE_TRANSITION
-from pilosa_tpu.observe import kerneltime as kerneltime_mod
 from pilosa_tpu.plancache import slice_key
 from pilosa_tpu.storage import fragment as _frag
 
@@ -104,7 +102,7 @@ def _topn_static_gate(ex, call):
     only, no tanimoto/threshold/filter semantics (those apply per
     NODE partial over HTTP, which a global psum can't reproduce).
     Returns (sorted unique row_ids, frame_name, view) or raises
-    MeshDecline. ONE implementation shared by ``_run_topn`` and
+    MeshDecline. ONE implementation shared by ``_collective_topn`` and
     ``explain_decision`` so the twin cannot drift."""
     row_ids, has_ids = call.uint_slice_arg("ids")
     if not has_ids or not row_ids:
@@ -121,7 +119,7 @@ def _topn_static_gate(ex, call):
 def _sum_static_gate(ex, index, call):
     """Sum/Average's static schema gate: (frame_name, field_name,
     field) or MeshDecline("schema"/"unsupported"). Shared by
-    ``_run_sum`` and ``explain_decision``."""
+    ``_collective_sum`` and ``explain_decision``."""
     from pilosa_tpu import errors as perr
 
     frame_name = call.args.get("frame") or ""
@@ -264,20 +262,12 @@ class MeshPlane:
             owners = self._owners(ex, index, slices)
             with tracing.span("mesh.collective", kind=kind,
                               slices=len(slices)):
-                t0 = time.perf_counter()
-                compiles0 = self.engine.compiles
-                if kind == "count":
-                    out = self._run_count(ex, index, call, slices,
-                                          owners)
-                elif kind == "topn":
-                    out = self._run_topn(ex, index, call, slices,
-                                         owners)
-                else:
-                    out = self._run_sum(ex, index, call, slices, owners)
-                self._note_launch(kind, time.perf_counter() - t0,
-                                  len(slices),
-                                  compiled=self.engine.compiles
-                                  > compiles0)
+                run = {"count": self._collective_count,
+                       "topn": self._collective_topn,
+                       "sum": self._collective_sum}[kind]
+                out = run(ex, index, call, slices, owners)
+                with self._mu:
+                    self._stats["launches"][kind] += 1
                 querystats.note_tier("mesh")
                 return out
         except MeshDecline as d:
@@ -327,7 +317,7 @@ class MeshPlane:
 
         # Count's tree — and TopN's src / Sum's filter child when
         # present — must compile through the batched planner, exactly
-        # like _run_count/_run_topn/_run_sum.
+        # like _collective_count/_collective_topn/_collective_sum.
         if name == "Count" or call.children:
             plan, _leaves = explain_mod.plan_readonly(
                 ex, index, call.children[0])
@@ -347,18 +337,6 @@ class MeshPlane:
             if h is None or h not in members:
                 return "declined", "not_resident"
         return "served", None
-
-    def _note_launch(self, kind, seconds, n_slices, compiled):
-        with self._mu:
-            self._stats["launches"][kind] += 1
-        obs = kerneltime_mod.ACTIVE
-        if obs.enabled:
-            # Compile vs steady-state attribution rides the PR 13
-            # kerneltime tier: one cost cell per (kind, slice-scale).
-            obs.note("mesh_" + kind, "collective",
-                     kerneltime_mod.shape_bucket(
-                         n_slices * WORDS_PER_SLICE * 4),
-                     seconds, compiled=compiled, device=True)
 
     # ------------------------------------------------------------ coverage
 
@@ -420,7 +398,7 @@ class MeshPlane:
 
     # ----------------------------------------------------------- programs
 
-    def _run_count(self, ex, index, call, slices, owners):
+    def _collective_count(self, ex, index, call, slices, owners):
         if len(call.children) != 1:
             raise MeshDecline("unsupported")
         plan, leaves = ex._plan_memoized(index, call.children[0])
@@ -436,10 +414,10 @@ class MeshPlane:
             # run — the count over every slice is exactly 0.
             return 0
         with _dispatch_mu:
-            return int(np.asarray(self.engine.tree_count(
-                plan, args, specs, len(slices))))
+            return int(self.engine.tree_count(plan, args, specs,
+                                              len(slices)))
 
-    def _run_topn(self, ex, index, call, slices, owners):
+    def _collective_topn(self, ex, index, call, slices, owners):
         """TopN's exact re-count (phase 2 — explicit ids, the device
         half of the two-phase algorithm) as one collective. Candidate
         discovery reads host cache metadata and stays on its existing
@@ -464,14 +442,14 @@ class MeshPlane:
             extra_bytes=self.engine.pad_slices(len(slices))
             * len(row_ids) * win[1] * 4)
         with _dispatch_mu:
-            counts = np.asarray(self.engine.topn_tree_counts(
-                matrix, src_plan, src_args, specs, len(slices)))
+            counts = self.engine.topn_tree_counts(
+                matrix, src_plan, src_args, specs, len(slices))
         pairs = [(int(r), int(c)) for r, c in zip(row_ids, counts)
                  if c > 0]
         pairs.sort(key=lambda rc: (-rc[1], rc[0]))
         return pairs
 
-    def _run_sum(self, ex, index, call, slices, owners):
+    def _collective_sum(self, ex, index, call, slices, owners):
         from pilosa_tpu.executor import SumCount
         from pilosa_tpu.storage.view import view_field_name
 
@@ -496,8 +474,8 @@ class MeshPlane:
             extra_bytes=self.engine.pad_slices(len(slices))
             * (depth + 1) * win[1] * 4)
         with _dispatch_mu:
-            out = np.asarray(self.engine.bsi_sum_counts(
-                planes, filt_plan, filt_args, specs, len(slices)))
+            out = self.engine.bsi_sum_counts(
+                planes, filt_plan, filt_args, specs, len(slices))
         count = int(out[depth])
         total = sum((1 << i) * int(c) for i, c in enumerate(out[:depth]))
         return SumCount(total + count * field.min, count)

@@ -169,9 +169,10 @@ class Config:
             "max-batch-bits": 8_000_000,
         }
         # Workload observatory (observe/): kernel-cost attribution +
-        # slice/row heatmaps. Always-on by default — the measured
-        # overhead gate is `make obscheck` (≤ 2% on warm engine QPS);
-        # disabling restores the one-nop-attribute-read hot path.
+        # slice/row heatmaps. Always-on by default — `make obscheck`
+        # checks that its surfaces are live; what it costs a request is
+        # not measured by any check; disabling restores the
+        # one-nop-attribute-read hot path.
         self.observe = {
             "enabled": True,
             # 1-in-N kernel dispatches block_until_ready so TRUE

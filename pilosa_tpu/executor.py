@@ -46,9 +46,9 @@ from pilosa_tpu import tracing
 from pilosa_tpu.bitmap import Bitmap
 from pilosa_tpu.cluster import hedge as hedge_mod
 from pilosa_tpu.observe import costmodel as costmodel_mod
-from pilosa_tpu.observe import devprof as devprof_mod
 from pilosa_tpu.observe import heatmap as heatmap_mod
 from pilosa_tpu.observe import kerneltime as kerneltime_mod
+from pilosa_tpu.ops import bitops
 from pilosa_tpu.ops import containers as containers_mod
 from pilosa_tpu.ops.bitops import program_name
 from pilosa_tpu.plancache import (FragList, PlanCache, as_slice_list,
@@ -86,18 +86,6 @@ def _plan_operands(node):
     return max((_plan_operands(kid) for kid in node[1]), default=0)
 
 
-def _run_count(fn, stacks):
-    """The batched Count call as the served path makes it: enqueue,
-    device wait and device-to-host copy in one expression."""
-    return np.asarray(fn(*stacks))
-
-
-def _launch_devices(operands):
-    """The devices a launch spans: those its device operands lie on."""
-    return max((len(a.sharding.device_set) for a in operands
-                if hasattr(a, "sharding")), default=1)
-
-
 def _zeros_once(shape):
     """``zero(device)``: one uint32 zero array of ``shape`` a device,
     shared by every missing or pad position of one stack."""
@@ -107,40 +95,6 @@ def _zeros_once(shape):
 
     return functools.cache(
         lambda device: jnp.zeros(shape, jnp.uint32, device=device))
-
-
-def _run_count_split(fn, stacks):
-    """The same call under a trace, cut where the time can hide: the
-    jitted call until it returns (enqueue, tagged ``program`` = the
-    name the launch carries in a device trace after ``jit_``), the wait
-    for the device, and the copy to the host."""
-    with tracing.span("kernel.dispatch", program=fn.__name__,
-                      devices=_launch_devices(stacks)):
-        out = fn(*stacks)
-    with tracing.span("kernel.wait"):
-        out.block_until_ready()
-    with tracing.span("kernel.fetch"):
-        return np.asarray(out)
-
-
-def _run_outputs(fn, stacks):
-    """A batched program with several outputs (Sum's plane and filter
-    counts), as the served path calls it: enqueue, device wait and the
-    copies to the host in one expression."""
-    return [np.asarray(o) for o in fn(*stacks)]
-
-
-def _run_outputs_split(fn, stacks):
-    """The same call under a trace, cut as ``_run_count_split`` cuts a
-    Count."""
-    with tracing.span("kernel.dispatch", program=fn.__name__,
-                      devices=_launch_devices(stacks)):
-        outs = fn(*stacks)
-    with tracing.span("kernel.wait"):
-        for o in outs:
-            o.block_until_ready()
-    with tracing.span("kernel.fetch"):
-        return [np.asarray(o) for o in outs]
 
 
 def _popcounts(x):
@@ -467,10 +421,6 @@ class Executor:
             "executor.Executor._rb_lanes_mu", threading.Lock())
         self._rb_stats = {"rounds": 0, "batched_calls": 0,
                           "max_batch": 0}
-        # Workload-observatory steady-state sampling tick for the
-        # batched count program (see _batched_count) — racy GIL-atomic
-        # increment, the _co_stats discipline.
-        self._obs_tick = 0
         # Runtime-telemetry histograms (stats.py), wired by the server
         # via set_histograms; nop defaults keep bare Executor
         # construction (tests, benchmarks) at one attribute read per
@@ -484,10 +434,6 @@ class Executor:
     # seconds): the le= series the coalescer's batching behavior reads
     # from directly.
     CO_GROUP_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0)
-
-    # Steady-state kernel-note stride for the batched count program
-    # (compiles always record exactly; see _batched_count).
-    OBS_STRIDE = 8
 
     def set_histograms(self, hset):
         """Install the server's HistogramSet: end-to-end execute
@@ -2403,75 +2349,17 @@ class Executor:
         # Cache key is the tree STRUCTURE (leaf slots, not leaf ids):
         # Count(Intersect(Bitmap(3), Bitmap(9))) reuses the executable
         # compiled for Count(Intersect(Bitmap(1), Bitmap(2))).
-        obs = kerneltime_mod.ACTIVE
-        # ONE plan stringification per query (the fn-cache key):
-        # tuple repr is µs-scale, and the observatory's hit check
-        # reuses it rather than paying a second pass.
         tree_key = str(plan)
-        with tracing.span("kernel:count_batched", slices=len(slices),
-                          width32=win[1]) as ksp:
-            hit = True
-            if ksp is not tracing.NOP_SPAN or obs.enabled:
-                # First-compile vs steady-state attribution: a fn-cache
-                # miss means this dispatch pays the XLA compile (the
-                # cost the width warmer pre-pays off the serving path —
-                # its _warm_stats success count rides along as context).
-                # Lock-free racy membership read (GIL-atomic): a
-                # concurrent insert misattributes at most one sample,
-                # and taking _cache_mu here would tax every warm query.
-                hit = (tree_key, padded_n, win[1]) in self._batched_cache
-            if ksp is not tracing.NOP_SPAN:
-                ksp.tag(first_compile=not hit,
-                        warm_compiled=self._warm_stats["compiled"])
-                with tracing.span("kernel.fn") as fsp:
-                    fn = self._batched_fn(tree_key, plan, padded_n,
-                                          win[1])
-                    fsp.tag(compile=not hit)
-                run = _run_count_split
-            else:
+        with tracing.span(
+                "kernel:count_batched", slices=len(slices), width32=win[1],
+                warm_compiled=self._warm_stats["compiled"]) as ksp:
+            with tracing.span("kernel.fn") as fsp:
                 fn = self._batched_fn(tree_key, plan, padded_n, win[1])
-                run = _run_count
-            if not obs.enabled:
-                counts = run(fn, stacks)
-            else:
-                # The batched tree program: one cost row per
-                # (slice-count, width) shape class — np.asarray
-                # blocks, so samples are device time. COMPILE
-                # dispatches (fn-cache miss, known up front) always
-                # record exactly; steady-state dispatches record
-                # 1-in-OBS_STRIDE with scaled weight — the hit check
-                # already ran, and the stride keeps two clock readings
-                # and a locked note off fifteen warm queries in
-                # sixteen (counts and sums scale, means stay true).
-                self._obs_tick = w = self._obs_tick + 1
-                w = 0 if w % self.OBS_STRIDE else self.OBS_STRIDE
-                if not hit or w:
-                    t0 = time.perf_counter()
-                    counts = run(fn, stacks)
-                    obs.note(
-                        "count_batched", "dense*dense",
-                        kerneltime_mod.shape_bucket(padded_n * win[1] * 4),
-                        time.perf_counter() - t0, compiled=not hit,
-                        device=True, n=(1 if not hit else w))
-                else:
-                    counts = run(fn, stacks)
-                if not hit:
-                    # Cache-size gauge stamped on compiles only —
-                    # per-query introspection would tax the warm path.
-                    try:
-                        obs.note_jit_cache("count_batched",
-                                           fn._cache_size())
-                    except Exception:  # noqa: BLE001 — jit internals vary; pilint: disable=swallow
-                        pass
-                    if devprof_mod.ACTIVE.enabled:
-                        # This dispatch already paid the XLA compile —
-                        # the analytic flops/bytes capture (one extra
-                        # lowering, once per cell) rides it, never
-                        # steady state.
-                        devprof_mod.ACTIVE.note_compile(
-                            "count_batched", "dense*dense",
-                            kerneltime_mod.shape_bucket(
-                                padded_n * win[1] * 4), fn, stacks)
+            counts = bitops.launch(
+                "count_batched", fn, *stacks, cut=bitops.KERNEL_CUT,
+                cell="dense*dense",
+                bucket=kerneltime_mod.shape_bucket(padded_n * win[1] * 4),
+                marks=((ksp, "first_compile"), (fsp, "compile")))
         self._warm_wider(tree_key, plan, padded_n, win[1], stacks)
         with tracing.span("reduce"):
             return int(counts[: len(slices)].sum())
@@ -3239,25 +3127,11 @@ class Executor:
                 per_query.append(self._co_member_args(
                     index, req["leaves"], slices, pad, n_dev, win, fm))
         args = self._co_stack_args(per_query, leaves0, k_pad, n_dev)
-        obs = kerneltime_mod.ACTIVE
-        tree_key = str(plan)
-        key = ("countK", tree_key, len(slices) + pad, win[1], k_pad)
-        with self._cache_mu:
-            compiled = obs.enabled and key not in self._batched_cache
-        fn = self._co_fused_fn(tree_key, plan, len(slices) + pad,
+        fn = self._co_fused_fn(str(plan), plan, len(slices) + pad,
                                win[1], k_pad)
-        t0 = time.perf_counter()
-        counts = np.asarray(fn(*args))
-        if obs.enabled:
-            obs.note("coalesce_count_fused", "dense*dense",
-                     kerneltime_mod.lane_bucket(k),
-                     time.perf_counter() - t0, compiled=compiled,
-                     device=True)
-            if compiled and devprof_mod.ACTIVE.enabled:
-                # Analytic capture rides the compile dispatch only.
-                devprof_mod.ACTIVE.note_compile(
-                    "coalesce_count_fused", "dense*dense",
-                    kerneltime_mod.lane_bucket(k), fn, args)
+        counts = bitops.launch("coalesce_count_fused", fn, *args,
+                               cut=bitops.NO_CUT, cell="dense*dense",
+                               bucket=kerneltime_mod.lane_bucket(k))
         # Per-member kernel-cost share: the fused program popcounts
         # each member's own [rows, S, W] stack — the same
         # bytes-popcounted the serial path would have charged it.
@@ -3316,8 +3190,6 @@ class Executor:
 
         Single-row-leaf plans never touch the device at all: the
         per-slice cardinality IS the container count."""
-        from pilosa_tpu.ops import bitops
-
         k = len(reqs)
         shape = self._lane_plan_shape(reqs[0]["plan"])
         if shape[0] == "count":
@@ -3503,14 +3375,10 @@ class Executor:
                 reprs_a.append(ra)
                 reprs_b.append(rb)
             member_pair.append(pid)
-        obs = kerneltime_mod.ACTIVE
-        t0 = time.perf_counter()
-        inter = containers_mod.host_repr_and_counts(reprs_a, reprs_b,
-                                                    span)
-        if obs.enabled:
-            obs.note(f"fused_count_{op}", "hostrepr",
-                     kerneltime_mod.lane_bucket(len(reprs_a)),
-                     time.perf_counter() - t0, device=True)
+        inter = bitops.launch(
+            f"fused_count_{op}", containers_mod.host_repr_and_counts,
+            reprs_a, reprs_b, span, cut=bitops.NO_CUT, cell="hostrepr",
+            bucket=kerneltime_mod.lane_bucket(len(reprs_a)))
         for req, pid in zip(reqs, member_pair):
             ca = reprs_a[pid][2]
             cb = reprs_b[pid][2]
@@ -3638,9 +3506,12 @@ class Executor:
         fn = self._co_sum_fn(str(plan), plan, depth,
                              len(slices) + pad, win[1], k_pad,
                              len(reqs[0]["leaves"]))
-        plane_counts, filt_counts = fn(planes_stack, *args)
-        plane_counts = np.asarray(plane_counts)[:, : len(slices)]
-        filt_counts = np.asarray(filt_counts)[:, : len(slices)]
+        plane_counts, filt_counts = bitops.launch(
+            "coalesce_sum_fused", fn, planes_stack, *args,
+            cut=bitops.NO_CUT, cell="dense*dense",
+            bucket=kerneltime_mod.lane_bucket(k))
+        plane_counts = plane_counts[:, : len(slices)]
+        filt_counts = filt_counts[:, : len(slices)]
         for i, req in enumerate(reqs):
             count = int(filt_counts[i].sum())
             total = sum((1 << b) * int(plane_counts[i, :, b].sum())
@@ -3688,9 +3559,10 @@ class Executor:
         fn = self._co_minmax_fn(str(plan), plan, depth,
                                 reqs[0]["find_max"], len(slices) + pad,
                                 win[1], k_pad, len(reqs[0]["leaves"]))
-        indicators, counts = fn(planes_stack, *args)
-        indicators = np.asarray(indicators)
-        counts = np.asarray(counts)
+        indicators, counts = bitops.launch(
+            "coalesce_minmax_fused", fn, planes_stack, *args,
+            cut=bitops.NO_CUT, cell="dense*dense",
+            bucket=kerneltime_mod.lane_bucket(k))
         for i, req in enumerate(reqs):
             count = int(counts[i])
             if count == 0:
@@ -3926,8 +3798,12 @@ class Executor:
             return prelude
         plan, stacks, padded_n, win = prelude
         fn = self._batched_bitmap_fn(str(plan), plan, padded_n, win[1])
-        result, counts = fn(*stacks)
-        counts = np.asarray(counts)[: len(slices)]
+        result, counts = bitops.launch(
+            "bitmap_batched", fn, *stacks, cut=bitops.NO_CUT,
+            fetch=lambda out: (out[0], np.asarray(out[1])),
+            cell="dense*dense",
+            bucket=kerneltime_mod.shape_bucket(padded_n * win[1] * 4))
+        counts = counts[: len(slices)]
         # The result stays ONE device stack: slicing it into per-slice
         # segments here would cost a dispatch (sharded: a cross-device
         # gather) per slice. Bitmap.defer_stack materializes segments
@@ -4560,25 +4436,17 @@ class Executor:
             # keep/drop in integers, on the device. The threshold is
             # a traced operand: one executable for every threshold.
             op = "topn_tanimoto"
-            fn, hit = self._batched_topn_tanimoto_fn(r_pad, padded_n,
-                                                     gathered)
+            fn = self._batched_topn_tanimoto_fn(r_pad, padded_n,
+                                                gathered)
             args = [src_stack, np.int32(tanimoto)] + rows
         else:
             op = "topn_src_rows" if src_stack is not None else "topn_rows"
-            fn, hit = self._batched_topn_fn(src_stack is not None, r_pad,
-                                            padded_n, gathered)
+            fn = self._batched_topn_fn(src_stack is not None, r_pad,
+                                       padded_n, gathered)
             args = ([src_stack] if src_stack is not None else []) + rows
-        t0 = time.perf_counter()
-        run = (_run_count if tracing.active_span() is None
-               else _run_count_split)
-        counts = run(fn, args)
-        if not hit and kerneltime_mod.ACTIVE.enabled:
-            # The fn-cache miss is this program's XLA compile: counted
-            # where /debug/kernels counts the Count programs'.
-            kerneltime_mod.ACTIVE.note(
-                op, "dense*dense",
-                kerneltime_mod.shape_bucket(rows[0].nbytes * len(rows)),
-                time.perf_counter() - t0, compiled=True, device=True)
+        counts = bitops.launch(
+            op, fn, *args, cut=bitops.KERNEL_CUT, cell="dense*dense",
+            bucket=kerneltime_mod.shape_bucket(rows[0].nbytes * len(rows)))
         out = counts[: len(row_ids), : len(slices)]
         return self._topn_counts_memoize(pkey, out, epoch)
 
@@ -4893,10 +4761,10 @@ class Executor:
                                build, "topn_src", _plan_operands(plan))
 
     def _batched_topn_fn(self, has_src, r_pad, padded_n, gathered):
-        """(program, whether the fn cache already held it): int32
-        [r_pad, padded_n] popcounts of the candidates' rows (one
-        ``_rows_stack`` or ``r_pad`` leaf stacks), each intersected
-        with its slice's src where there is one."""
+        """The program of int32[r_pad, padded_n] popcounts of the
+        candidates' rows (one ``_rows_stack`` or ``r_pad`` leaf
+        stacks), each intersected with its slice's src where there is
+        one."""
         def build():
             def fn(*args):
                 src, rows = (args[0], args[1:]) if has_src else (None, args)
@@ -4905,16 +4773,14 @@ class Executor:
                     src, rows, gathered)
             return fn
 
-        key = ("topn", has_src, r_pad, padded_n, gathered)
-        hit = key in self._batched_cache
         return self._cached_fn(
-            key, build, "topn_src_rows" if has_src else "topn_rows",
-            r_pad), hit
+            ("topn", has_src, r_pad, padded_n, gathered), build,
+            "topn_src_rows" if has_src else "topn_rows", r_pad)
 
     def _batched_topn_tanimoto_fn(self, r_pad, padded_n, gathered):
-        """(program, whether the fn cache already held it): the
-        per-(candidate, slice) |row ∩ src| of the candidates' rows,
-        zeroed where the integer gate drops the pair."""
+        """The program of the per-(candidate, slice) |row ∩ src| of
+        the candidates' rows, zeroed where the integer gate drops the
+        pair."""
         import jax.numpy as jnp
 
         from pilosa_tpu.ops import topn as topn_ops
@@ -4930,9 +4796,8 @@ class Executor:
                 return jnp.where(keep, inter, 0)
             return fn
 
-        key = ("topn_tan", r_pad, padded_n, gathered)
-        hit = key in self._batched_cache
-        return self._cached_fn(key, build, "topn_tanimoto", r_pad), hit
+        return self._cached_fn(("topn_tan", r_pad, padded_n, gathered),
+                               build, "topn_tanimoto", r_pad)
 
     def _batched_sum(self, index, call, slices):
         """Sum over the local slice list as one sharded XLA program:
@@ -4949,37 +4814,16 @@ class Executor:
         field, depth, plan, planes_stack, leaf_stacks, padded_n, win = pre
 
         tree_key = str(plan)
-        stacks = [planes_stack, *leaf_stacks]
-        obs = kerneltime_mod.ACTIVE
         with tracing.span("kernel:sum_batched", slices=len(slices),
-                          width32=win[1]) as ksp:
-            traced = ksp is not tracing.NOP_SPAN
-            hit = True
-            if traced or obs.enabled:
-                # As _batched_count: a racy, lock-free membership read.
-                hit = (("sum", tree_key, depth, padded_n, win[1])
-                       in self._batched_cache)
+                          width32=win[1]):
             with tracing.span("kernel.fn") as fsp:
                 fn = self._batched_sum_fn(tree_key, plan, depth, padded_n,
                                           win[1])
-                if traced:
-                    fsp.tag(compile=not hit)
-            run = _run_outputs_split if traced else _run_outputs
-            t0 = time.perf_counter()
-            plane_counts, filt_counts = run(fn, stacks)
-            if obs.enabled:
-                # One cost row per (slice-count, width) shape class, as
-                # a batched Count's: a compile always records (it is
-                # what ``compileCalls`` of /debug/kernels counts), a
-                # steady dispatch 1-in-OBS_STRIDE with scaled weight.
-                self._obs_tick = w = self._obs_tick + 1
-                w = 0 if w % self.OBS_STRIDE else self.OBS_STRIDE
-                if not hit or w:
-                    obs.note(
-                        "sum_batched", "dense*dense",
-                        kerneltime_mod.shape_bucket(padded_n * win[1] * 4),
-                        time.perf_counter() - t0, compiled=not hit,
-                        device=True, n=(1 if not hit else w))
+            plane_counts, filt_counts = bitops.launch(
+                "sum_batched", fn, planes_stack, *leaf_stacks,
+                cut=bitops.KERNEL_CUT, cell="dense*dense",
+                bucket=kerneltime_mod.shape_bucket(padded_n * win[1] * 4),
+                marks=((fsp, "compile"),))
         with tracing.span("sum.reduce"):
             count = int(filt_counts[: len(slices)].sum())
             total = sum((1 << i) * int(plane_counts[: len(slices), i].sum())
@@ -5078,12 +4922,14 @@ class Executor:
 
         fn = self._batched_minmax_fn(str(plan), plan, depth, find_max,
                                      padded_n, win[1])
-        indicators, count = fn(planes_stack, *leaf_stacks)
+        indicators, count = bitops.launch(
+            "minmax_batched", fn, planes_stack, *leaf_stacks,
+            cut=bitops.NO_CUT, cell="dense*dense",
+            bucket=kerneltime_mod.shape_bucket(padded_n * win[1] * 4))
         count = int(count)
         if count == 0:
             return BATCH_EMPTY
-        value = sum((1 << i) * int(b)
-                    for i, b in enumerate(np.asarray(indicators)))
+        value = sum((1 << i) * int(b) for i, b in enumerate(indicators))
         return SumCount(value + field.min, count)
 
     def _batched_minmax_fn(self, tree_key, plan, depth, find_max,

@@ -33,8 +33,8 @@ import math
 import time
 
 # Serving-path sampling stride for un-inspected queries: at 27k q/s a
-# warm dashboard still calibrates ~400 samples/s, while the estimate's
-# memo-miss cost amortizes far below the 2% inspector overhead gate.
+# warm dashboard still calibrates ~400 samples/s, while one query in
+# STRIDE pays the estimate's memo-miss cost.
 STRIDE = 64
 
 # Ring of recent predicted/measured ratios per tier — the median

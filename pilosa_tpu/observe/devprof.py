@@ -71,11 +71,12 @@ class DevProfiler:
 
     # ------------------------------------------------------ write path
 
-    def note_compile(self, op, cell, bucket, fn, args):
+    def note_compile(self, op, cell, bucket, fn, args, static=None):
         """Capture XLA cost_analysis for a kernel cell's first
-        compile. Called from dispatch seams ONLY when this dispatch
+        compile. Called by ``bitops.launch`` ONLY when this launch
         already paid a compile (jit-cache growth), so the extra
-        lowering never rides steady state."""
+        lowering never rides steady state. ``static`` holds the
+        launch's keyword arguments."""
         if self._unsupported:
             return
         key = (op, cell, bucket)
@@ -86,7 +87,8 @@ class DevProfiler:
         # — the compile that could explain it already happened).
         self._cells[key] = None
         try:
-            ca = fn.lower(*args).compile().cost_analysis()
+            ca = fn.lower(*args, **(static or {})).compile() \
+                .cost_analysis()
         except NotImplementedError:
             self._unsupported = True
             return
@@ -260,7 +262,7 @@ class NopDevProfiler:
 
     enabled = False
 
-    def note_compile(self, op, cell, bucket, fn, args):
+    def note_compile(self, op, cell, bucket, fn, args, static=None):
         pass
 
     def lookup(self, op, cell, bucket):

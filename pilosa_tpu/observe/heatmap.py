@@ -111,8 +111,8 @@ class Heatmap:
     def touch_read(self, index, frame, row_id, slice_num, weight=0):
         """ONE stride-sampled hook for the fragment read layer: row
         and slice heat from a single method call (the hot serving
-        loops' hook — every saved call layer counts against the 2%
-        observatory budget). ``weight`` is the UNSCALED bytes of one
+        loops' hook, so one call layer a read). ``weight`` is the
+        UNSCALED bytes of one
         read; sampling scales it. The first WARM_TOUCHES reads sample
         exactly, so a fresh process shows heat before the stride
         engages."""

@@ -19,8 +19,9 @@ Three cost populations per cell:
   from the tracing-only ``first_compile`` span tag to always-on
   counters (a chip window must explain its numbers without re-running
   under the tracer).
-- **steady**: everything else. With async dispatch this is ENQUEUE
-  wall time — cheap and pipelining-neutral.
+- **steady**: everything else, noted one launch in ``STRIDE`` with
+  weight ``STRIDE``. A launch that does not wait for the device
+  records ENQUEUE wall time — cheap and pipelining-neutral.
 - **device-sampled**: 1-in-N dispatches (``[observe]
   kernel-sample-rate``) additionally ``block_until_ready`` so TRUE
   device time is measured without stalling the other N-1 calls.
@@ -37,6 +38,12 @@ import time
 # practice; the cap is a backstop against a pathological caller
 # minting unbounded bucket labels, not a working limit.
 MAX_CELLS = 4096
+
+# Steady-state launches are noted one in STRIDE, each note standing
+# for STRIDE launches (the statsd |@rate idiom): counts and sums
+# scale, means stay true. Compiles and device-sampled launches always
+# record.
+STRIDE = 8
 
 # Slot layout of one cell accumulator (a plain list: GIL-atomic
 # increments, no per-call allocation).
@@ -82,7 +89,8 @@ class KernelObservatory:
         self._cells = {}       # (op, cell, bucket) -> [6 slots]
         self._jit_cache = {}   # kernel name -> last seen cache size
         self._overflow = 0
-        self._tick = 0
+        self._tick = 0         # kernel-sample-rate
+        self._steady = 0       # STRIDE
         # Device-transfer rollup (host<->HBM), fed from the existing
         # querystats seams in storage/fragment.py.
         self._transfers = [0, 0, 0.0]  # count, bytes, seconds
@@ -100,21 +108,22 @@ class KernelObservatory:
         self._tick += 1
         return self._tick % n == 0
 
+    def steady_weight(self):
+        """STRIDE on the one steady-state launch in STRIDE that is
+        noted, else 0. One racy GIL-atomic tick for every launch."""
+        self._steady += 1
+        return 0 if self._steady % STRIDE else STRIDE
+
     def note(self, op, cell, bucket, seconds, compiled=False,
              device=False, n=1):
         """Record a dispatch into its (op, cell, bucket) cost cell.
-        ``compiled`` marks a jit-cache-growth dispatch (its time is
-        compile, not steady state); ``compiled=None`` means "auto":
-        the cell's FIRST sample counts as the compile — jitted
-        kernels are shape-bucketed, so the first dispatch of a
-        (op, cell, bucket) class is where its XLA compile lands
-        (stride-sampled hot paths use this: exact jit-cache
-        introspection per call would eat the 2% observatory budget).
-        ``device`` marks a dispatch that blocked until the result was
-        ready. ``n > 1`` is the statsd-|@rate idiom for stride-
-        sampled paths: this observation stands for ``n`` calls of
-        ~``seconds`` each, so counts and sums scale while means stay
-        unbiased. A compile is always ONE event regardless of n."""
+        ``compiled`` marks a dispatch whose jit cache grew (its time is
+        compile, not steady state). ``device`` marks a dispatch that
+        blocked until the result was ready. ``n > 1`` is the statsd-|@rate
+        idiom for stride-sampled launches: this observation stands for
+        ``n`` calls of ~``seconds`` each, so counts and sums scale while
+        means stay unbiased. A compile is always ONE event regardless
+        of n."""
         key = (op, cell, bucket)
         acc = self._cells.get(key)
         if acc is None:
@@ -122,8 +131,6 @@ class KernelObservatory:
                 self._overflow += 1
                 return
             acc = self._cells.setdefault(key, [0, 0.0, 0, 0.0, 0, 0.0])
-            if compiled is None:
-                compiled = True
         acc[_CALLS] += n
         acc[_SECONDS] += seconds * n
         if compiled:
@@ -134,14 +141,9 @@ class KernelObservatory:
             acc[_DEV_SECONDS] += seconds * n
 
     def note_jit_cache(self, name, size):
-        """Record a kernel's jit executable-cache size; returns True
-        when it GREW since last seen (this dispatch paid a compile).
-        First sight of a kernel with a nonzero cache is growth too —
-        a fresh process's first dispatch is exactly the compile the
-        table must attribute."""
-        prev = self._jit_cache.get(name)
+        """Record a kernel's jit executable-cache size (the
+        ``jitCacheSizes`` gauge; a launch that compiled stamps it)."""
         self._jit_cache[name] = size
-        return prev is None or size > prev
 
     def note_transfer(self, nbytes, seconds=0.0):
         """One host->device (or device->host) transfer, from the
@@ -259,12 +261,15 @@ class NopKernelObservatory:
     def should_sample(self):
         return False
 
+    def steady_weight(self):
+        return 0
+
     def note(self, op, cell, bucket, seconds, compiled=False,
              device=False, n=1):
         pass
 
     def note_jit_cache(self, name, size):
-        return False
+        pass
 
     def note_transfer(self, nbytes, seconds=0.0):
         pass

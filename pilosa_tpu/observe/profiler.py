@@ -219,8 +219,8 @@ class Profiler:
             self._rotate(t)
         # Trie mutation under _mu: readers (_walk) iterate children
         # dicts under the lock, and an unlocked insert here could
-        # resize a dict mid-iteration. Uncontended acquire per sample
-        # at ~19 Hz — profcheck's <=2% overhead gate covers it.
+        # resize a dict mid-iteration: one uncontended acquire a
+        # sample, at ~19 Hz.
         with self._mu:
             node = self._root.get(subsystem)
             if node is None:

@@ -42,6 +42,11 @@ _U32 = jnp.uint32
 # (jax.distributed.initialize must run before the first device op).
 _FULL = np.uint32(0xFFFFFFFF)
 
+# Operand formats (see "Format-polymorphic dispatch" below).
+FMT_DENSE = "dense"
+FMT_ARRAY = "array"
+FMT_RUN = "run"
+
 
 # ---------------------------------------------------------------------------
 # Binary algebra (materializing). Ref semantics: roaring.go Intersect :1925,
@@ -102,17 +107,11 @@ def _popcount_sum(x):
 # Per-kernel dispatch-time histogram (stats.Histogram), wired by the
 # server when [metrics] histograms are on; the module default is the
 # shared nop so bare kernel use (tests, benchmarks) pays one attribute
-# read. Dispatch time is ENQUEUE wall time — the histogram never calls
-# block_until_ready, so async dispatch pipelining is unchanged (the
-# traced path below still blocks, as spans must measure device time).
+# read. It times the launches that return a device array: ENQUEUE wall
+# time untraced (async dispatch pipelining is unchanged), device time
+# under a trace, whose span must wait for the device.
 _DISPATCH_HIST = stats_mod.NOP_HISTOGRAM
 _HIST_KERNELS = {}
-
-# Steady-state observatory note stride for untraced dispatches
-# (compile/device-sampled dispatches always record; racy GIL-atomic
-# tick — the containers.OBS_STRIDE discipline).
-OBS_STRIDE = 8
-_obs_tick = 0
 
 
 def set_dispatch_histogram(hist):
@@ -153,19 +152,75 @@ def program_name(tier, operands):
     return f"pilosa_{tier}_{k}"
 
 
-def _traced_dispatch(name, fn, *args):
-    """Dispatch a jitted kernel under the active trace span; a plain
-    call when no trace is active (one attribute read of overhead).
-    Traced dispatches block until the result is ready — the span must
-    measure device time, not async-enqueue time — and tag whether this
-    call paid an XLA compile (jit cache growth) or hit steady state."""
-    if lockcheck.ACTIVE.enabled:
+# Where a launch that brings its result to the host is cut under a
+# trace: the jitted call until it returns (tagged ``program``, the name
+# the launch carries in a device trace after ``jit_``), the wait for
+# the device, and the copy to the host. NO_CUT makes no span.
+KERNEL_CUT = ("kernel.dispatch", "kernel.wait", "kernel.fetch")
+TOP_CUT = ("top.kernel", "top.wait", "top.fetch")
+NO_CUT = (None, None, None)
+
+
+def _cut_span(name):
+    return tracing.NOP_SPAN if name is None else tracing.span(name)
+
+
+def _outputs(out):
+    return out if isinstance(out, (tuple, list)) else (out,)
+
+
+def to_host(out):
+    """The launch's output(s) as NumPy arrays: the default fetch."""
+    if isinstance(out, (tuple, list)):
+        return [np.asarray(o) for o in out]
+    return np.asarray(out)
+
+
+def _no_wait():
+    pass
+
+
+def _launch_devices(operands):
+    """The devices a launch spans: those its device operands lie on."""
+    return max((len(a.sharding.device_set) for a in operands
+                if hasattr(a, "sharding")), default=1)
+
+
+def launch(name, fn, *args, cut=None, fetch=to_host, tags=None,
+           cell=FMT_DENSE, bucket=None, marks=(), static=None,
+           points=None):
+    """Call the program ``fn`` on ``args`` (and ``static`` keyword
+    arguments): the one way a device program of the served path is
+    launched. ``fn`` is the jitted function itself, or a host pass
+    standing in for one (it has no jit cache, so it never compiles).
+
+    With ``cut=None`` the device array comes back unfetched, under one
+    ``kernel:{name}`` span that waits for the device; untraced, only
+    the launches ``kernel-sample-rate`` picks wait. With a ``cut`` the
+    launch waits for the device and returns ``fetch(out)``, the three
+    steps under the cut's spans; the first is tagged ``program`` and
+    ``tags`` (by default the devices the launch spans). Untraced,
+    every span is ``tracing.NOP_SPAN``. ``points`` (by default: no
+    cut, the kernels of this module) takes the ``lockcheck`` device
+    point, charges ``bytesPopcounted`` for a ``count*`` program and
+    feeds the dispatch histogram.
+
+    The launch compiled exactly when ``fn``'s jit cache grew across
+    it: the ``kernel:{name}`` span is tagged ``first_compile`` with
+    that, and each ``(span, tag)`` of ``marks`` likewise. The kernel
+    observatory notes the launch under ``(name, cell, bucket)``
+    (``bucket`` defaults to the first operand's size class): a compile
+    or a sampled launch always, with the analytic devprof capture on a
+    compile; a steady one by ``kerneltime.STRIDE``."""
+    if points is None:
+        points = cut is None
+    if points and lockcheck.ACTIVE.enabled:
         # A lock held across a kernel dispatch/device sync serializes
         # every thread behind HBM round-trip latency (and behind an
         # XLA compile on the first shape). Locks that by design cover
         # their own device mirrors register allow_across_io=True.
         lockcheck.ACTIVE.io_point("device.dispatch", kind="device")
-    qs = querystats.active()
+    qs = querystats.active() if points else None
     if qs is not None and name.startswith("count"):
         # bytes-popcounted is the kernel cost unit (arXiv:1611.07612):
         # charge the primary operand's footprint per popcount dispatch.
@@ -173,99 +228,53 @@ def _traced_dispatch(name, fn, *args):
         if nb:
             qs.add("bytesPopcounted", int(nb))
     obs = _kt.ACTIVE
-    if tracing.active_span() is None:
-        h = _DISPATCH_HIST
-        if not h.enabled and not obs.enabled:
-            return fn(*args)
-        if not obs.enabled:
-            t0 = time.perf_counter()
-            out = fn(*args)
-            _kernel_hist(name).observe(time.perf_counter() - t0)
-            return out
-        # Workload-observatory path (observe/kerneltime.py): the
-        # tracing-only first_compile probe promoted to always-on
-        # counters — jit cache growth marks this dispatch's time as
-        # COMPILE; 1-in-N sampled dispatches additionally block so
-        # true device time is measured without stalling the other
-        # N-1 calls' async pipelining. Every dispatch pays ONE
-        # post-call cache-size probe (the note_jit_cache delta is the
-        # compile detector — exact, per kernel); STEADY notes are
-        # stride-sampled with scaled weight so the per-slice serial
-        # dense loop pays a locked note one dispatch in OBS_STRIDE,
-        # while compile and device-sampled dispatches always record.
-        sampled = obs.should_sample()
-        t0 = time.perf_counter()
-        out = fn(*args)
-        # Enqueue time captured BEFORE any sampled block: the
-        # pre-existing kernel_dispatch_seconds histogram keeps its
-        # enqueue-time semantics on this path even when sampling
-        # blocks 1-in-N dispatches for the observatory.
-        enqueue_dt = time.perf_counter() - t0
-        if sampled:
-            try:
-                out.block_until_ready()
-            except AttributeError:
-                pass  # abstract value: inside another jit trace
-        dt = time.perf_counter() - t0
-        compiled = False
-        try:
-            compiled = obs.note_jit_cache(name, fn._cache_size())
-        except Exception:  # noqa: BLE001 — jit internals vary; pilint: disable=swallow
-            pass  # jit cache introspection is best-effort
-        global _obs_tick
-        _obs_tick += 1
-        if compiled or sampled:
-            bucket = _kt.shape_bucket(getattr(args[0], "nbytes", 0))
-            obs.note(name, FMT_DENSE, bucket, dt, compiled=compiled,
-                     device=sampled)
-            if compiled and _devprof.ACTIVE.enabled:
-                # This dispatch already paid the XLA compile — the
+    size = getattr(fn, "_cache_size", None)
+    pre = size() if size is not None else 0
+    sampled = cut is None and obs.should_sample()
+    d, w, f = cut or NO_CUT
+    t0 = time.perf_counter()
+    with _cut_span(None if cut else "kernel:" + name) as ksp:
+        with _cut_span(d) as dsp:
+            if dsp is not tracing.NOP_SPAN:
+                dsp.tag(program=fn.__name__, **(
+                    tags or {"devices": _launch_devices(args)}))
+            out = fn(*args, **(static or {}))
+        enqueued = time.perf_counter() - t0
+        traced = (ksp is not tracing.NOP_SPAN
+                  or dsp is not tracing.NOP_SPAN)
+        waited = cut is not None or sampled or traced
+        if waited:
+            with _cut_span(w):
+                for o in _outputs(out):
+                    # An abstract value (a launch inside another jit
+                    # trace) or a host pass's result has nothing to
+                    # wait for.
+                    getattr(o, "block_until_ready", _no_wait)()
+        if cut is not None:
+            with _cut_span(f):
+                out = fetch(out)
+    dt = time.perf_counter() - t0
+    compiled = size is not None and size() > pre
+    ksp.tag(first_compile=compiled)
+    for sp, tag in marks:
+        sp.tag(**{tag: compiled})
+    if obs.enabled:
+        n = 1 if compiled or sampled else obs.steady_weight()
+        if n:
+            if bucket is None:
+                bucket = _kt.shape_bucket(getattr(args[0], "nbytes", 0))
+            obs.note(name, cell, bucket, dt, compiled=compiled,
+                     device=waited, n=n)
+        if compiled:
+            obs.note_jit_cache(name, size())
+            if _devprof.ACTIVE.enabled:
+                # This launch already paid the XLA compile — the
                 # analytic flops/bytes capture (one extra lowering,
                 # once per cell) rides it, never steady state.
-                _devprof.ACTIVE.note_compile(name, FMT_DENSE, bucket,
-                                             fn, args)
-        elif _obs_tick % OBS_STRIDE == 0:
-            obs.note(name, FMT_DENSE,
-                     _kt.shape_bucket(getattr(args[0], "nbytes", 0)),
-                     dt, n=OBS_STRIDE)
-        if h.enabled:
-            _kernel_hist(name).observe(enqueue_dt)
-        return out
-    try:
-        pre = fn._cache_size()
-    except Exception:  # noqa: BLE001 — jit internals vary by version; pilint: disable=swallow
-        pre = None
-    t0 = time.perf_counter()
-    compiled = False
-    with tracing.span(f"kernel:{name}") as sp:
-        out = fn(*args)
-        try:
-            out.block_until_ready()
-        except AttributeError:
-            pass  # abstract value: dispatched inside another jit trace
-        if pre is not None:
-            try:
-                post = fn._cache_size()
-                compiled = post > pre
-                sp.tag(first_compile=compiled)
-                if obs.enabled:
-                    obs.note_jit_cache(name, post)
-            except Exception:  # noqa: BLE001; pilint: disable=swallow
-                pass  # jit cache introspection is best-effort
-    dt = time.perf_counter() - t0
-    if obs.enabled:
-        # Traced dispatches block, so this sample IS device time.
-        bucket = _kt.shape_bucket(getattr(args[0], "nbytes", 0))
-        obs.note(name, FMT_DENSE, bucket, dt,
-                 compiled=compiled, device=True)
-        if compiled and _devprof.ACTIVE.enabled:
-            _devprof.ACTIVE.note_compile(name, FMT_DENSE, bucket,
-                                         fn, args)
-    if _DISPATCH_HIST.enabled:
-        # Traced dispatches block, so this sample is device time — a
-        # superset of the untraced enqueue time, but losing kernel
-        # samples whenever tracing is on would be worse.
-        _kernel_hist(name).observe(dt)
+                _devprof.ACTIVE.note_compile(name, cell, bucket, fn,
+                                             args, static)
+    if points and _DISPATCH_HIST.enabled:
+        _kernel_hist(name).observe(dt if traced else enqueued)
     return out
 
 
@@ -276,7 +285,7 @@ def _count_impl(a):
 
 def count(a):
     """Total set bits. Ref: Bitmap.Count (roaring.go:185)."""
-    return _traced_dispatch("count", _count_impl, a)
+    return launch("count", _count_impl, a)
 
 
 @jax.jit
@@ -290,7 +299,7 @@ def count_rows(m):
     The workhorse of TopN (fragment.go:831) and cache recalculation —
     one fused popcount+reduce over the whole row matrix.
     """
-    return _traced_dispatch("count_rows", _count_rows_impl, m)
+    return launch("count_rows", _count_rows_impl, m)
 
 
 @jax.jit
@@ -300,7 +309,7 @@ def _count_and_impl(a, b):
 
 def count_and(a, b):
     """|a ∩ b| without materializing. Ref: intersectionCount* :1811-1923."""
-    return _traced_dispatch("count_and", _count_and_impl, a, b)
+    return launch("count_and", _count_and_impl, a, b)
 
 
 @jax.jit
@@ -309,7 +318,7 @@ def _count_or_impl(a, b):
 
 
 def count_or(a, b):
-    return _traced_dispatch("count_or", _count_or_impl, a, b)
+    return launch("count_or", _count_or_impl, a, b)
 
 
 @jax.jit
@@ -318,7 +327,7 @@ def _count_xor_impl(a, b):
 
 
 def count_xor(a, b):
-    return _traced_dispatch("count_xor", _count_xor_impl, a, b)
+    return launch("count_xor", _count_xor_impl, a, b)
 
 
 @jax.jit
@@ -327,7 +336,7 @@ def _count_andnot_impl(a, b):
 
 
 def count_andnot(a, b):
-    return _traced_dispatch("count_andnot", _count_andnot_impl, a, b)
+    return launch("count_andnot", _count_andnot_impl, a, b)
 
 
 @jax.jit
@@ -338,12 +347,15 @@ def _count_and_rows_impl(m, filt):
     )
 
 
-def count_and_rows(m, filt):
+def count_and_rows(m, filt, **cut):
     """Per-row intersection counts vs one filter row:
     uint32[R, W], uint32[W] -> int32[R]. TopN's Src-intersection path
-    (fragment.go:886-906) as a single broadcasted kernel.
+    (fragment.go:886-906) as a single broadcasted kernel. ``cut``
+    (``cut``, ``tags``) goes to ``launch``: ``topn.fetch_counts``
+    brings the counts to the host with it.
     """
-    return _traced_dispatch("count_and_rows", _count_and_rows_impl, m, filt)
+    return launch("count_and_rows", _count_and_rows_impl, m, filt,
+                  points=True, **cut)
 
 
 def row_at(m, i):
@@ -360,11 +372,11 @@ def _count_and_rows_at_impl(m, i):
     return _count_and_rows_impl(m, row_at(m, i))
 
 
-def count_and_rows_at(m, i):
+def count_and_rows_at(m, i, **cut):
     """``count_and_rows`` with row ``i`` of ``m`` itself as the filter:
     uint32[R, W], int32 scalar -> int32[R]."""
-    return _traced_dispatch("count_and_rows_at", _count_and_rows_at_impl,
-                            m, i)
+    return launch("count_and_rows_at", _count_and_rows_at_impl, m, i,
+                  points=True, **cut)
 
 
 # ---------------------------------------------------------------------------
@@ -445,10 +457,6 @@ def zero_range(a, start, end):
 # kernels here (ops/containers.py does exactly that at import); no
 # executor or storage dispatch code changes.
 # ---------------------------------------------------------------------------
-
-FMT_DENSE = "dense"
-FMT_ARRAY = "array"
-FMT_RUN = "run"
 
 # (op, fmt_a, fmt_b) -> kernel.  op ∈ {"and", "or", "xor", "andnot"}.
 # Count kernels return a host/device int (|a OP b|); pair kernels

@@ -41,7 +41,6 @@ each other or any valid position.
 """
 import os
 import threading
-import time
 
 import numpy as np
 
@@ -368,31 +367,10 @@ def _jitted(name, builder):
     return fn
 
 
-# Serial-cell observation stride: the per-slice compressed count path
-# dispatches one cell PER SLICE, so exact per-call bookkeeping there
-# would cost two clock readings and a locked note a slice. 1-in-N
-# calls record with weight N (the statsd |@rate idiom — counts/sums scale,
-# means stay unbiased); the deterministic tick guarantees a sample
-# every N dispatches. Fused LANE cells stay exactly instrumented —
-# they launch once per tick, not per slice.
-OBS_STRIDE = 16
-_obs_tick = 0
-
-
-def _obs_weight():
-    """0 = skip this call's observation; else the weight to scale
-    by. Racy GIL-atomic tick (the _co_stats discipline). The serial
-    cells keep their own closure ticks (a nonlocal increment beats a
-    global-function call on the per-slice path); this module-level
-    twin serves any future cell that has no closure to hang one on."""
-    global _obs_tick
-    _obs_tick += 1
-    if _obs_tick % OBS_STRIDE:
-        return 0
-    return OBS_STRIDE
-
-
 def _count_array_dense_impl():
+    """|array ∩ dense| via gather + bit-test: one gathered word per
+    position, no dense intermediate (ref: intersectArrayBitmap count
+    shape, roaring.go:1862-1878)."""
     import jax.numpy as jnp
 
     def fn(pos, words):
@@ -404,15 +382,11 @@ def _count_array_dense_impl():
     return fn
 
 
-def count_array_dense(pos, words):
-    """|array ∩ dense| via gather + bit-test: one gathered word per
-    position, no dense intermediate (ref: intersectArrayBitmap count
-    shape, roaring.go:1862-1878)."""
-    return _jitted("count_array_dense", _count_array_dense_impl)(
-        pos, words)
-
-
 def _count_array_array_impl():
+    """|array ∩ array| as a sorted-merge membership test (searchsorted
+    — the vectorized analog of intersectArrayArray's galloping merge,
+    roaring.go:1811-1830). Operand sentinels differ by construction
+    (pad_positions offsets), so padding can never match."""
     import jax.numpy as jnp
 
     def fn(pos_a, pos_b):
@@ -422,16 +396,11 @@ def _count_array_array_impl():
     return fn
 
 
-def count_array_array(pos_a, pos_b):
-    """|array ∩ array| as a sorted-merge membership test (searchsorted
-    — the vectorized analog of intersectArrayArray's galloping merge,
-    roaring.go:1811-1830). Operand sentinels differ by construction
-    (pad_positions offsets), so padding can never match."""
-    return _jitted("count_array_array", _count_array_array_impl)(
-        pos_a, pos_b)
-
-
 def _count_array_run_impl():
+    """|array ∩ run|: position-in-interval membership (ref:
+    intersectArrayRun, roaring.go:1832-1860). Sentinel positions sit
+    at/past the window limit, where no run can cover them (run ends
+    are ≤ limit)."""
     import jax.numpy as jnp
 
     def fn(pos, starts, ends):
@@ -441,15 +410,6 @@ def _count_array_run_impl():
         inside = (pos >= starts[idx]) & (pos < ends[idx])
         return jnp.sum(inside.astype(jnp.int32))
     return fn
-
-
-def count_array_run(pos, starts, ends):
-    """|array ∩ run|: position-in-interval membership (ref:
-    intersectArrayRun, roaring.go:1832-1860). Sentinel positions sit
-    at/past the window limit, where no run can cover them (run ends
-    are ≤ limit)."""
-    return _jitted("count_array_run", _count_array_run_impl)(
-        pos, starts, ends)
 
 
 def _run_mask_impl():
@@ -486,6 +446,9 @@ def run_mask(starts, ends, n_words):
 
 
 def _count_run_dense_impl():
+    """|run ∩ dense| fused: run mask → AND → popcount in one XLA
+    program (the count analog of intersectBitmapRun,
+    roaring.go:1880-1904)."""
     import jax.numpy as jnp
     from jax import lax
 
@@ -494,15 +457,6 @@ def _count_run_dense_impl():
         return jnp.sum(lax.population_count(
             lax.bitwise_and(words, mask)).astype(jnp.int32))
     return fn
-
-
-def count_run_dense(starts, ends, words):
-    """|run ∩ dense| fused: run mask → AND → popcount in one XLA
-    program (the count analog of intersectBitmapRun,
-    roaring.go:1880-1904) — nothing dense is ever materialized in HBM
-    beyond what fusion keeps in registers."""
-    return _jitted("count_run_dense", _count_run_dense_impl)(
-        starts, ends, words)
 
 
 def count_run_run(runs_a, runs_b):
@@ -822,75 +776,62 @@ def host_repr_and_counts(reprs_a, reprs_b, span):
     return total
 
 
-def _fused_and_counts(conts_a, conts_b):
+def _fused_and_counts(conts_a, conts_b, name, cell):
     """``np.int64[N]`` of per-member |a ∩ b| for two same-format
-    operand lists — one lane launch on accelerators, the vectorized
-    host pass for position/interval lanes on the CPU backend (run×run
-    stays host-side everywhere: prefix sums over ≤2·RUN_MAX_RUNS ints
-    per member beat any transfer)."""
+    operand lists, from one launch noted as ``name`` in ``cell``: a
+    lane program on accelerators, the vectorized host pass for
+    position/interval lanes on the CPU backend (run×run stays
+    host-side everywhere: prefix sums over ≤2·RUN_MAX_RUNS ints per
+    member beat any transfer)."""
     fa, fb = conts_a[0].fmt, conts_b[0].fmt
     A, R, D = bitops.FMT_ARRAY, bitops.FMT_RUN, bitops.FMT_DENSE
-    if fa == D and fb != D:
-        return _fused_and_counts(conts_b, conts_a)
-    if fa == R and fb == A:
-        return _fused_and_counts(conts_b, conts_a)
-    if fa == A and fb == A:
-        if _lane_host():
-            return _host_count_array_array(conts_a, conts_b)
-        out = fused_count_array_array(
-            stack_positions(conts_a),
-            stack_positions(conts_b, sentinel_off=1))
+    if (fa == D and fb != D) or (fa == R and fb == A):
+        return _fused_and_counts(conts_b, conts_a, name, cell)
+    if fa == A and _lane_host():
+        fn = {A: _host_count_array_array, D: _host_count_array_dense,
+              R: _host_count_array_run}[fb]
+        args = (conts_a, conts_b)
+    elif fa == A and fb == A:
+        fn = _vmapped("fused_count_array_array", _count_array_array_impl)
+        args = (stack_positions(conts_a),
+                stack_positions(conts_b, sentinel_off=1))
     elif fa == A and fb == D:
-        if _lane_host():
-            return _host_count_array_dense(conts_a, conts_b)
-        out = fused_count_array_dense(stack_positions(conts_a),
-                                      stack_dense(conts_b))
+        fn = _vmapped("fused_count_array_dense", _count_array_dense_impl)
+        args = (stack_positions(conts_a), stack_dense(conts_b))
     elif fa == A and fb == R:
-        if _lane_host():
-            return _host_count_array_run(conts_a, conts_b)
-        s, e = stack_runs(conts_b)
-        out = fused_count_array_run(stack_positions(conts_a), s, e)
+        fn = _vmapped("fused_count_array_run", _count_array_run_impl)
+        args = (stack_positions(conts_a), *stack_runs(conts_b))
     elif fa == R and fb == D:
-        s, e = stack_runs(conts_a)
-        out = fused_count_run_dense(s, e, stack_dense(conts_b))
+        fn = _vmapped("fused_count_run_dense", _count_run_dense_impl)
+        args = (*stack_runs(conts_a), stack_dense(conts_b))
     elif fa == R and fb == R:
-        return np.array([count_run_run(a.runs, b.runs)
-                         for a, b in zip(conts_a, conts_b)],
-                        dtype=np.int64)
+        fn, args = _runs_and_runs_counts, (conts_a, conts_b)
     elif fa == D and fb == D:
-        out = fused_count_dense_dense(stack_dense(conts_a),
-                                      stack_dense(conts_b))
+        fn = _vmapped("fused_count_dense_dense",
+                      _fused_count_dense_dense_impl)
+        args = (stack_dense(conts_a), stack_dense(conts_b))
     else:
         raise TypeError(f"no fused and-count lane for {fa}x{fb}")
-    return np.asarray(out).astype(np.int64)
+    return bitops.launch(
+        name, fn, *args, cut=bitops.NO_CUT, cell=cell,
+        bucket=_kt.lane_bucket(len(conts_a))).astype(np.int64)
+
+
+def _runs_and_runs_counts(conts_a, conts_b):
+    return np.array([count_run_run(a.runs, b.runs)
+                     for a, b in zip(conts_a, conts_b)], dtype=np.int64)
 
 
 def _fused_count_cell(op):
     """One (op, fmt, fmt) lane cell: intersection counts from ONE
     launch, then the same or/xor/andnot identities as the serial
     _count_cell applied per member from the host-known cardinalities
-    (exact for two operands) — so fused and serial can only agree."""
+    (exact for two operands) — so fused and serial can only agree.
+    The launch is noted per lane: cell = the member format pair,
+    bucket = the member-count class (the lane tier's cost axis)."""
     def cell(conts_a, conts_b):
-        obs = _kt.ACTIVE
-        if not obs.enabled:
-            inter = _fused_and_counts(conts_a, conts_b)
-        else:
-            # Fused-lane attribution: one note per lane launch, cell
-            # = the member format pair, bucket = the member-count
-            # class (the lane tier's cost axis). np.asarray in
-            # _fused_and_counts blocks, so samples are device time.
-            # Compile separation is the first-sample-of-cell rule
-            # (note's compiled=None): a lane cell's first launch at a
-            # member-count bucket IS where its vmapped kernel
-            # compiles, and a jit-cache walk per launch would tax
-            # every tick.
-            t0 = time.perf_counter()
-            inter = _fused_and_counts(conts_a, conts_b)
-            obs.note(f"fused_count_{op}",
-                     f"{conts_a[0].fmt}*{conts_b[0].fmt}",
-                     _kt.lane_bucket(len(conts_a)),
-                     time.perf_counter() - t0,
-                     compiled=None, device=True)
+        inter = _fused_and_counts(conts_a, conts_b, f"fused_count_{op}",
+                                  f"{conts_a[0].fmt}*{conts_b[0].fmt}")
         if op == "and":
             return inter
         ca = np.array([c.count for c in conts_a], dtype=np.int64)
@@ -936,61 +877,43 @@ def _runs_to_dense(starts, ends, width32):
 # Dense×dense is NOT registered: bitops routes it to the pre-existing
 # fused kernels unconditionally (the exact current path).
 
-def _and_count(a, b):
+def _and_program(a, b):
+    """(program, operands) that count |a ∩ b|: a jitted cell, or
+    run×run's host pass."""
     fa, fb = a.fmt, b.fmt
     A, R, D = bitops.FMT_ARRAY, bitops.FMT_RUN, bitops.FMT_DENSE
+    if (fa, fb) in ((D, A), (R, A), (D, R)):
+        return _and_program(b, a)
     if fa == A and fb == A:
-        return int(count_array_array(a.device_positions(),
-                                     b.device_positions(sentinel_off=1)))
+        return (_jitted("count_array_array", _count_array_array_impl),
+                (a.device_positions(), b.device_positions(sentinel_off=1)))
     if fa == A and fb == D:
-        return int(count_array_dense(a.device_positions(),
-                                     b.dense_words()))
-    if fa == D and fb == A:
-        return _and_count(b, a)
+        return (_jitted("count_array_dense", _count_array_dense_impl),
+                (a.device_positions(), b.dense_words()))
     if fa == A and fb == R:
-        s, e = b.device_runs()
-        return int(count_array_run(a.device_positions(), s, e))
-    if fa == R and fb == A:
-        return _and_count(b, a)
+        return (_jitted("count_array_run", _count_array_run_impl),
+                (a.device_positions(), *b.device_runs()))
     if fa == R and fb == D:
-        s, e = a.device_runs()
-        return int(count_run_dense(s, e, b.dense_words()))
-    if fa == D and fb == R:
-        return _and_count(b, a)
+        return (_jitted("count_run_dense", _count_run_dense_impl),
+                (*a.device_runs(), b.dense_words()))
     if fa == R and fb == R:
-        return count_run_run(a.runs, b.runs)
+        return count_run_run, (a.runs, b.runs)
     raise TypeError(f"no and-count cell for {fa}x{fb}")
 
 
 def _count_cell(op):
-    tick = 0
+    """One (op, fmt, fmt) serial cell: |a ∩ b| from one launch, noted
+    per format pair and operand size class, then the or/xor/andnot
+    identities from the cardinalities."""
+    name = f"count_{op}"
 
     def cell(a, b):
         need = op != "and"  # |a∩b| alone needs no cardinalities
         a, b = as_container(a, need), as_container(b, need)
-        obs = _kt.ACTIVE
-        w = 0
-        if obs.enabled:
-            nonlocal tick
-            tick += 1
-            if tick % OBS_STRIDE == 0:
-                w = OBS_STRIDE
-        if not w:
-            inter = _and_count(a, b)
-        else:
-            # Stride-sampled serial-cell attribution: these cells
-            # coerce to a host int (the int() in _and_count blocks),
-            # so every sample is device time. Compile attribution is
-            # the first-sample-of-cell rule (note's compiled=None) —
-            # exact jit-cache introspection here, once a slice, would
-            # cost more than the cell it watches; the exact probes
-            # live on the bitops and fused-lane paths.
-            t0 = time.perf_counter()
-            inter = _and_count(a, b)
-            dt = time.perf_counter() - t0
-            obs.note(f"count_{op}", f"{a.fmt}*{b.fmt}",
-                     _kt.shape_bucket(a.nbytes() + b.nbytes()), dt,
-                     compiled=None, device=True, n=w)
+        fn, args = _and_program(a, b)
+        inter = int(bitops.launch(
+            name, fn, *args, cut=bitops.NO_CUT, cell=f"{a.fmt}*{b.fmt}",
+            bucket=_kt.shape_bucket(a.nbytes() + b.nbytes())))
         if op == "and":
             return inter
         if op == "or":

@@ -31,7 +31,6 @@ mirrors.
 Shapes are bucketed (rows and nnz pad to powers of two) so jit
 compilation stays bounded, the bitops/containers discipline.
 """
-import time
 
 import numpy as np
 
@@ -113,24 +112,14 @@ def pack_classify(rowidx, positions, n_rows, width32):
     ridx[:nnz] = rowidx
     pos = np.zeros(nnz_pad, dtype=np.int32)
     pos[:nnz] = positions
-    obs = _kt.ACTIVE
-    if not obs.enabled:
-        fn = _pack_classify_kernel(n_rows_pad, width32)
-        words, counts, n_runs = fn(jnp.asarray(ridx), jnp.asarray(pos))
-        return (words[:n_rows], np.asarray(counts)[:n_rows],
-                np.asarray(n_runs)[:n_rows])
-    # Write-path attribution: the kernel cache is keyed by shape
-    # bucket, so a fresh key IS the compile; np.asarray on the stat
-    # vectors blocks, so every sample is device time.
-    compiled = ("pack_classify", n_rows_pad, width32) not in _kernel_cache
-    fn = _pack_classify_kernel(n_rows_pad, width32)
-    t0 = time.perf_counter()
-    words, counts, n_runs = fn(jnp.asarray(ridx), jnp.asarray(pos))
-    out = (words[:n_rows], np.asarray(counts)[:n_rows],
-           np.asarray(n_runs)[:n_rows])
-    obs.note("ingest.pack_classify", "write", _kt.shape_bucket(nnz_pad * 4),
-             time.perf_counter() - t0, compiled=compiled, device=True)
-    return out
+    # The packed matrix stays on the device; the two stat vectors come
+    # to the host.
+    words, counts, n_runs = bitops.launch(
+        "ingest.pack_classify", _pack_classify_kernel(n_rows_pad, width32),
+        jnp.asarray(ridx), jnp.asarray(pos), cut=bitops.NO_CUT,
+        fetch=lambda out: (out[0], *bitops.to_host(out[1:])),
+        cell="write", bucket=_kt.shape_bucket(nnz_pad * 4))
+    return words[:n_rows], counts[:n_rows], n_runs[:n_rows]
 
 
 def _classify_stats_impl(n_rows_pad):
@@ -174,21 +163,15 @@ def classify_stats_device(rowidx, positions, n_rows):
     pos = np.zeros(nnz_pad, dtype=np.int32)
     pos[:nnz] = positions
     key = ("classify_stats", n_rows_pad)
-    compiled = key not in _kernel_cache
     fn = _kernel_cache.get(key)
     if fn is None:
         fn = _kernel_cache[key] = jax.jit(
             _classify_stats_impl(n_rows_pad))
-    obs = _kt.ACTIVE
-    if not obs.enabled:
-        counts, runs = fn(jnp.asarray(ridx), jnp.asarray(pos))
-        return np.asarray(counts)[:n_rows], np.asarray(runs)[:n_rows]
-    t0 = time.perf_counter()
-    counts, runs = fn(jnp.asarray(ridx), jnp.asarray(pos))
-    out = np.asarray(counts)[:n_rows], np.asarray(runs)[:n_rows]
-    obs.note("ingest.classify", "write", _kt.shape_bucket(nnz_pad * 4),
-             time.perf_counter() - t0, compiled=compiled, device=True)
-    return out
+    counts, runs = bitops.launch(
+        "ingest.classify", fn, jnp.asarray(ridx), jnp.asarray(pos),
+        cut=bitops.NO_CUT, cell="write",
+        bucket=_kt.shape_bucket(nnz_pad * 4))
+    return counts[:n_rows], runs[:n_rows]
 
 
 def classify_stats_host(rowidx, positions, n_rows):

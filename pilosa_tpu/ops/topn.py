@@ -18,15 +18,11 @@ cache is kept host-side for API parity and warm-start; it names the
 rows a TopN may return (the ``elig`` operand), the counts are always
 the device's.
 """
-import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
-from pilosa_tpu import tracing
-from pilosa_tpu.observe import kerneltime
 from pilosa_tpu.ops import bitops
 
 
@@ -188,32 +184,16 @@ tanimoto_select_at = _program(_tanimoto_select_at,
 
 
 def fetch_counts(fn, matrix, *args, op=None, **static):
-    """The per-fragment TopN call as ``Fragment.top`` makes it: enqueue,
-    device wait and the copy of the program's one output (a count a
-    row of the mirror, or a selection: ``static`` is its ``k``) to the
-    host in one expression; under a trace cut where the time can hide,
-    into ``top.kernel`` (the jitted call until it returns, tagged
-    ``scanned`` = the rows of the operand it was given and ``program``
-    = ``fn``'s name, which the launch carries in a device trace after
-    ``jit_``), ``top.wait``
-    (``block_until_ready``) and ``top.fetch`` (``np.asarray``). With
-    ``op``, a dispatch that grew ``fn``'s executable cache is noted as
-    that op's compile in the kernel observatory (``/debug/kernels``)."""
-    t0 = time.perf_counter()
-    if tracing.active_span() is None:
-        counts = np.asarray(fn(matrix, *args, **static))
-    else:
-        with tracing.span("top.kernel", scanned=matrix.shape[0],
-                          program=fn.__name__):
-            out = fn(matrix, *args, **static)
-        with tracing.span("top.wait"):
-            out.block_until_ready()
-        with tracing.span("top.fetch"):
-            counts = np.asarray(out)
-    obs = kerneltime.ACTIVE
-    if (op is not None and obs.enabled
-            and obs.note_jit_cache(op, fn._cache_size())):
-        obs.note(op, bitops.FMT_DENSE,
-                 kerneltime.shape_bucket(matrix.nbytes),
-                 time.perf_counter() - t0, compiled=True, device=True)
-    return counts
+    """The per-fragment TopN call as ``Fragment.top`` makes it: one
+    launch (``bitops.launch``) of ``fn`` that brings its one output (a
+    count a row of the mirror, or a selection: ``static`` is its ``k``)
+    to the host, cut under a trace into ``top.kernel`` (tagged
+    ``scanned`` = the rows of the operand it was given and
+    ``program``), ``top.wait`` and ``top.fetch``. ``op`` names the
+    launch in the kernel observatory (``/debug/kernels``); without it
+    ``fn`` is one of ``bitops``' kernels, which launches its program
+    under its own name."""
+    cut = {"cut": bitops.TOP_CUT, "tags": {"scanned": matrix.shape[0]}}
+    if op is None:
+        return fn(matrix, *args, **cut)
+    return bitops.launch(op, fn, matrix, *args, static=static, **cut)

@@ -25,6 +25,8 @@ import numpy as np
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from pilosa_tpu import WORDS_PER_SLICE
+from pilosa_tpu.observe import kerneltime
 from pilosa_tpu.ops import bitops
 
 
@@ -108,10 +110,6 @@ class MeshQueryEngine:
         self._nv = {}   # n_valid -> committed device scalar (reused
         #                 per call: a fresh jnp.int32 would device_put
         #                 a replicated scalar on EVERY query)
-        # Monotone build counter: callers diff it for compile-vs-steady
-        # attribution — a len(_fns) delta goes blind once the LRU is
-        # full (evictions keep the length constant).
-        self.compiles = 0
 
     # ------------------------------------------------------------ layout
 
@@ -272,8 +270,16 @@ class MeshQueryEngine:
             while len(self._fns) >= self.TREE_FN_CACHE_MAX:
                 self._fns.pop(next(iter(self._fns)))
             fn = self._fns[key] = build()
-            self.compiles += 1
         return fn
+
+    def _launch(self, kind, fn, n_valid, args):
+        """One launch of a collective program (``bitops.launch``), its
+        replicated result on the host: noted as ``mesh_<kind>`` in the
+        ``collective`` cell, a size class by the real slices."""
+        return bitops.launch(
+            "mesh_" + kind, fn, self._nv_arg(n_valid), *args,
+            cut=bitops.NO_CUT, cell="collective",
+            bucket=kerneltime.shape_bucket(n_valid * WORDS_PER_SLICE * 4))
 
     def _nv_arg(self, n_valid):
         arr = self._nv.get(n_valid)
@@ -312,7 +318,7 @@ class MeshQueryEngine:
                 in_specs=(P(),) + self._in_specs(specs), out_specs=P()))
 
         fn = self._tree_fn("count", plan, specs, shapes, build)
-        return fn(self._nv_arg(n_valid), *args)
+        return self._launch("count", fn, n_valid, args)
 
     def topn_tree_counts(self, matrix, src_plan, src_args, specs,
                          n_valid):
@@ -348,7 +354,7 @@ class MeshQueryEngine:
                 out_specs=P()))
 
         fn = self._tree_fn("topn", src_plan, all_specs, shapes, build)
-        return fn(self._nv_arg(n_valid), *all_args)
+        return self._launch("topn", fn, n_valid, all_args)
 
     def bsi_sum_counts(self, planes, filt_plan, filt_args, specs,
                        n_valid):
@@ -395,7 +401,7 @@ class MeshQueryEngine:
 
         fn = self._tree_fn("bsi_sum", filt_plan, all_specs, shapes,
                            build)
-        return fn(self._nv_arg(n_valid), *all_args)
+        return self._launch("sum", fn, n_valid, all_args)
 
     def bsi_range_count(self, planes, op, bits, n_valid, hi_bits=None):
         """|columns matching a BSI condition| as one collective — the

@@ -3075,7 +3075,8 @@ class Fragment:
             filt = bitops.bitmap_and(
                 planes[bit_depth],
                 jnp.asarray(np.ascontiguousarray(filter_words).view(np.uint32)))
-        counts = np.asarray(bsi_ops.plane_counts(planes[:bit_depth], filt))
+        counts = bitops.launch("bsi_plane_counts", bsi_ops.plane_counts,
+                               planes[:bit_depth], filt, cut=bitops.NO_CUT)
         total = sum((1 << i) * int(c) for i, c in enumerate(counts))
         return total, int(bitops.count(filt))
 
@@ -3090,15 +3091,16 @@ class Fragment:
             "<": bsi_ops.bsi_lt, "<=": bsi_ops.bsi_lte,
             ">": bsi_ops.bsi_gt, ">=": bsi_ops.bsi_gte,
         }[op]
-        out = np.asarray(fn(planes[:bit_depth], exists, bits))
+        out = bitops.launch(fn.__name__, fn, planes[:bit_depth], exists,
+                            bits, cut=bitops.NO_CUT)
         return np.ascontiguousarray(out).view(np.uint64)
 
     def field_range_between(self, bit_depth, lo, hi):
         planes = self._planes(bit_depth)
-        out = np.asarray(bsi_ops.bsi_between(
-            planes[:bit_depth], planes[bit_depth],
-            bsi_ops.value_to_bits(lo, bit_depth),
-            bsi_ops.value_to_bits(hi, bit_depth)))
+        out = bitops.launch(
+            "bsi_between", bsi_ops.bsi_between, planes[:bit_depth],
+            planes[bit_depth], bsi_ops.value_to_bits(lo, bit_depth),
+            bsi_ops.value_to_bits(hi, bit_depth), cut=bitops.NO_CUT)
         return np.ascontiguousarray(out).view(np.uint64)
 
     def field_not_null(self, bit_depth):
@@ -3114,9 +3116,14 @@ class Fragment:
                 filt, jnp.asarray(np.ascontiguousarray(filter_words).view(np.uint32)))
         if int(bitops.count(filt)) == 0:
             return 0, 0
-        ind, remaining = bsi_ops.bsi_extrema_indicators(
-            planes[:bit_depth], filt, find_max)
-        value = sum((1 << i) * int(b) for i, b in enumerate(np.asarray(ind)))
+        # The indicators come to the host; what remains stays on the
+        # device for its count.
+        ind, remaining = bitops.launch(
+            "bsi_extrema", bsi_ops.bsi_extrema_indicators,
+            planes[:bit_depth], filt, cut=bitops.NO_CUT,
+            fetch=lambda out: (np.asarray(out[0]), out[1]),
+            static={"find_max": find_max})
+        value = sum((1 << i) * int(b) for i, b in enumerate(ind))
         return value, int(bitops.count(remaining))
 
     # ---------------------------------------------------------------- TopN

@@ -77,11 +77,10 @@ def test_kernel_transfer_rollup_and_jit_cache():
     obs.note_transfer(2048, 0.002)
     assert obs.snapshot()["transfers"] == {
         "count": 2, "bytes": 3072, "seconds": 0.003}
-    # First sight counts as growth (a fresh process's first dispatch
-    # IS the compile), then only increases do.
-    assert obs.note_jit_cache("k", 1) is True
-    assert obs.note_jit_cache("k", 1) is False
-    assert obs.note_jit_cache("k", 2) is True
+    # The cache-size gauge: the last size a compiling launch stamped.
+    obs.note_jit_cache("k", 1)
+    assert obs.snapshot()["jitCacheSizes"] == {"k": 1}
+    obs.note_jit_cache("k", 2)
     assert obs.metrics()["jit_cache_size;kernel:k"] == 2
 
 
@@ -126,7 +125,7 @@ def test_compile_vs_steady_separation_on_fresh_jit_cache():
         # Steady-state notes are stride-sampled (compiles always
         # record), so drive enough repeats to guarantee a steady
         # sample lands.
-        for _ in range(1 + 2 * bitops.OBS_STRIDE):
+        for _ in range(1 + 2 * kt.STRIDE):
             assert int(bitops.count(a)) == 0
         bucket = kt.shape_bucket(7013 * 4)
         row = next(r for r in obs.snapshot()["cells"]
@@ -140,8 +139,9 @@ def test_compile_vs_steady_separation_on_fresh_jit_cache():
 
 def test_serial_compressed_cell_attribution():
     """The registered (op, fmt, fmt) serial cells record into their
-    format-pair cost cell — stride-sampled (1-in-OBS_STRIDE with
-    weight OBS_STRIDE), so N dispatches land ~N scaled calls."""
+    format-pair cost cell — stride-sampled (1-in-STRIDE with weight
+    STRIDE, the one stride of kerneltime), so N dispatches land ~N
+    scaled calls."""
     from pilosa_tpu.ops import bitops, containers
 
     obs = kt.enable()
@@ -152,15 +152,15 @@ def test_serial_compressed_cell_attribution():
         run = containers.Container(
             bitops.FMT_RUN, 1024, 8,
             runs=np.array([[4, 12]], np.int32))
-        n = 2 * containers.OBS_STRIDE
+        n = 2 * kt.STRIDE
         for _ in range(n):
             assert bitops.dispatch_count("and", arr, run) == 2  # {5, 9}
         rows = [r for r in obs.snapshot()["cells"]
                 if r["op"] == "count_and" and r["cell"] == "array*run"]
         # The deterministic stride guarantees >= floor(n / stride)
-        # samples, each standing for OBS_STRIDE calls.
+        # samples, each standing for STRIDE calls.
         assert rows, obs.snapshot()["cells"]
-        assert rows[0]["calls"] >= n - containers.OBS_STRIDE, rows
+        assert rows[0]["calls"] >= n - kt.STRIDE, rows
     finally:
         kt.disable()
 
@@ -314,7 +314,8 @@ def test_nop_path_single_attribute_read():
     # Every hot hook is inert and every surface still answers.
     assert kt.NOP.note("a", "b", "c", 1.0) is None
     assert kt.NOP.should_sample() is False
-    assert kt.NOP.note_jit_cache("k", 1) is False
+    assert kt.NOP.note_jit_cache("k", 1) is None
+    assert kt.NOP.steady_weight() == 0
     assert heatmap_mod.NOP.touch_row("i", "f", 1) is None
     assert slo_mod.NOP.record("interactive", 1.0) is None
     assert kt.NOP.snapshot() == {"enabled": False}

@@ -14,8 +14,8 @@ import urllib.request
 import pytest
 
 from pilosa_tpu import SLICE_WIDTH, querystats, tracing
-from pilosa_tpu import executor as executor_mod
 from pilosa_tpu.executor import Executor
+from pilosa_tpu.ops import bitops
 from pilosa_tpu.ops import topn as topn_ops
 from pilosa_tpu.pql.parser import parse
 from pilosa_tpu.server.server import Server
@@ -59,10 +59,10 @@ def _profiled(s, pql):
 PROGRAM_CASES = {
     "count": ('Count(Intersect(Bitmap(frame="f", rowID=1), '
               'Bitmap(frame="f", rowID=2)))',
-              "batched", "_run_count_split", "kernel.dispatch",
+              "batched", "count_batched", "kernel.dispatch",
               "pilosa_count_batched_k2"),
     "sum": ('Sum(Bitmap(frame="f", rowID=2), frame="g", field="v")',
-            "batched", "_run_outputs_split", "kernel.dispatch",
+            "batched", "sum_batched", "kernel.dispatch",
             "pilosa_sum_batched_k1"),
     "topn": ('TopN(Bitmap(frame="f", rowID=3), frame="f", n=2, '
              'tanimotoThreshold=10)',
@@ -83,14 +83,24 @@ def test_the_launch_site_span_names_the_jitted_function(
     reads on a device trace's ``XLA Modules`` line. The spans' other
     tags stay."""
     pql, path, site, span_name, want = PROGRAM_CASES[case]
-    owner = topn_ops if site == "fetch_counts" else executor_mod
-    real, fns = getattr(owner, site), []
+    fns = []
+    if site == "fetch_counts":
+        real = topn_ops.fetch_counts
 
-    def spy(fn, *a, **k):
-        fns.append(fn)
-        return real(fn, *a, **k)
+        def spy(fn, *a, **k):
+            fns.append(fn)
+            return real(fn, *a, **k)
 
-    monkeypatch.setattr(owner, site, spy)
+        monkeypatch.setattr(topn_ops, site, spy)
+    else:
+        real = bitops.launch
+
+        def spy(name, fn, *a, **k):
+            if name == site:
+                fns.append(fn)
+            return real(name, fn, *a, **k)
+
+        monkeypatch.setattr(bitops, "launch", spy)
     monkeypatch.setattr(served.executor, "_force_path", path)
     prof = _profiled(served, pql)["profile"]
     assert fns, "the traced launch site did not run"
@@ -111,12 +121,18 @@ def test_the_launch_site_span_names_the_jitted_function(
 
 
 def test_the_untraced_arms_are_the_parents(served, monkeypatch):
-    """No trace: no split runner, no ``Span``, the same answers."""
+    """No trace: the launches run and make no ``Span``; the same
+    answers."""
+    launched, real = [], bitops.launch
+
+    def spy(name, *a, **k):
+        launched.append(name)
+        return real(name, *a, **k)
+
     def refuse(*a, **k):
         raise AssertionError("ran without a trace")
 
-    monkeypatch.setattr(executor_mod, "_run_count_split", refuse)
-    monkeypatch.setattr(executor_mod, "_run_outputs_split", refuse)
+    monkeypatch.setattr(bitops, "launch", spy)
     monkeypatch.setattr(tracing.Span, "__init__", refuse)
     monkeypatch.setattr(served.executor, "_force_path", "batched")
     got = _post(served, "/index/i/query",
@@ -130,6 +146,7 @@ def test_the_untraced_arms_are_the_parents(served, monkeypatch):
                 'TopN(Bitmap(frame="f", rowID=2), frame="f", n=1, '
                 'tanimotoThreshold=10)')
     assert got["results"][0][0]["id"] == 2
+    assert {"count_batched", "sum_batched"} <= set(launched)
 
 
 # ------------------------------------------------- which pick is a probe

@@ -190,10 +190,10 @@ def test_a_profiled_sum_carries_its_spans_and_counters(served):
 
 
 def test_an_untraced_sum_emits_no_span(served, monkeypatch):
-    """Outside a trace the call is one expression: no span object is
-    made, the split runner is not used, and the counters still move."""
-    from pilosa_tpu import executor as executor_mod
+    """Outside a trace no span object is made, the launch runs, and
+    the counters still move."""
     from pilosa_tpu import tracing
+    from pilosa_tpu.ops import bitops
 
     server, client, reference = served
     ex = server.executor
@@ -205,14 +205,19 @@ def test_an_untraced_sum_emits_no_span(served, monkeypatch):
         made.append(a)
         real(self, *a, **k)
 
+    launched, real_launch = [], bitops.launch
+
+    def spy_launch(name, *a, **k):
+        launched.append(name)
+        return real_launch(name, *a, **k)
+
     monkeypatch.setattr(tracing.Span, "__init__", spy)
-    monkeypatch.setattr(executor_mod, "_run_outputs_split",
-                        lambda *a: pytest.fail("split runner untraced"))
+    monkeypatch.setattr(bitops, "launch", spy_launch)
     before = ex.bsi_prelude["bsiPreludeMisses"]
     query = SUM.format(dates=_bitmap("d_year", 1997), d=">< [6, 8]",
                        q="< 12")
     assert _ask(client, query)["results"] == [reference.answer(query)]
-    assert made == []
+    assert made == [] and "sum_batched" in launched
     assert ex.bsi_prelude["bsiPreludeMisses"] == before + 1
 
 
@@ -222,23 +227,20 @@ def _spied_operands(server, client, monkeypatch, query, profile=False):
     """The operands the batched Sum's program was called with, the leaf
     specs they were built from (the planes of the summed field go first
     and are no leaf), and the request's ``resources`` where profiled."""
-    from pilosa_tpu import executor as executor_mod
+    from pilosa_tpu.ops import bitops
     from pilosa_tpu.pql.parser import parse
 
     ex = server.executor
     ex._force_path = "batched"
-    seen = []
+    seen, real = [], bitops.launch
 
-    def spied(real):
-        def spy(fn, stacks):
+    def spy(name, fn, *stacks, **k):
+        if name == "sum_batched":
             seen.append(list(stacks))
-            return real(fn, stacks)
-        return spy
+        return real(name, fn, *stacks, **k)
 
     with monkeypatch.context() as patch:
-        for name in ("_run_outputs", "_run_outputs_split"):
-            patch.setattr(executor_mod, name,
-                          spied(getattr(executor_mod, name)))
+        patch.setattr(bitops, "launch", spy)
         doc = _ask(client, query, profile=profile)
     (stacks,) = seen
     leaves = ex._co_bsi_resolve("ssb", parse(query).calls[0])[5]

@@ -77,8 +77,8 @@ def program_batched(gathered):
     ex._batched_cache, ex.BATCHED_FN_CACHE_MAX = {}, 8
     import threading
     ex._cache_mu = threading.Lock()
-    fn, hit = ex._batched_topn_tanimoto_fn(1, N, gathered)
-    assert not hit and ex._batched_topn_tanimoto_fn(1, N, gathered)[1]
+    fn = ex._batched_topn_tanimoto_fn(1, N, gathered)
+    assert ex._batched_topn_tanimoto_fn(1, N, gathered) is fn
     rows = jnp.asarray(prefix_rows(N))
     d = np.arange(1, N + 1)
     every_t = jax.vmap(fn, in_axes=(None, 0, None))

@@ -95,9 +95,9 @@ def test_one_and_fifty_candidates_cost_the_batched_path_the_same(tmp_path):
             return leaf(*a, **k)
 
         def spy_fn(*a):
-            fn, hit = run(*a)
-            return (lambda *args: (seen["operands"].append(len(args)),
-                                   fn(*args))[1]), hit
+            fn = run(*a)
+            return lambda *args: (seen["operands"].append(len(args)),
+                                  fn(*args))[1]
 
         Fragment.device_rows_win = spy_gather
         ex._leaf_stack, ex._batched_topn_tanimoto_fn = spy_leaf, spy_fn
@@ -267,9 +267,9 @@ def test_a_profiled_one_slice_topn_skips_the_recount(server, path):
 @pytest.mark.parametrize("server", [1, 2], indirect=True)
 def test_unprofiled_topn_pays_for_none_of_it(server, monkeypatch):
     """With no trace active every span of the TopN path is the shared
-    no-op, no counter is kept, and the fragment's call stays the one
-    expression (no split into wait and fetch): whether the second
-    phase runs (two slices) or is skipped (one)."""
+    no-op, the fragment's launch cut into ``top.kernel`` / ``top.wait``
+    / ``top.fetch`` included, and no counter is kept: whether the
+    second phase runs (two slices) or is skipped (one)."""
     made, split = [], []
     real_span, real_init = tracing.span, tracing.Span.__init__
 
@@ -290,7 +290,7 @@ def test_unprofiled_topn_pays_for_none_of_it(server, monkeypatch):
     names = [n for n, _ in made]
     assert {"topn.phase1", "topn.phase2", "top.src", "top.select"} \
         <= set(names)
-    assert not {"top.kernel", "top.wait", "top.fetch"} & set(names)
+    assert {"top.kernel", "top.wait", "top.fetch"} <= set(names)
     assert all(nop for _, nop in made) and not split
 
 

@@ -510,27 +510,31 @@ def test_batched_count_span_tree_and_stack_builds(tmp_path):
 
 def test_new_span_sites_cost_nothing_without_a_trace(tmp_path, monkeypatch):
     """No trace active: every new site gets the shared no-op, the
-    dispatch / wait / fetch split is not taken, and nothing of the
-    capture mirror runs."""
-    from pilosa_tpu import executor as executor_mod
+    launch's dispatch / wait / fetch cut makes no span either, and
+    nothing of the capture mirror runs."""
+    from pilosa_tpu.ops import bitops
 
     s = Server(str(tmp_path / "d"), bind="localhost:0").open()
     try:
         _seed_rows(s)
-        seen = []
-        real_span = tracing.span
+        seen, launched = [], []
+        real_span, real_launch = tracing.span, bitops.launch
 
         def spy(name, **tags):
             out = real_span(name, **tags)
             seen.append((name, out))
             return out
 
-        def no_split(fn, stacks):
-            raise AssertionError("the split ran without a trace")
+        def spy_launch(name, *a, **k):
+            launched.append(name)
+            return real_launch(name, *a, **k)
+
+        def no_span(*a, **k):
+            raise AssertionError("a span was made without a trace")
 
         monkeypatch.setattr(tracing, "span", spy)
-        monkeypatch.setattr(executor_mod, "_run_count_split", no_split)
-        monkeypatch.setattr(tracing.Span, "__init__", no_split)
+        monkeypatch.setattr(bitops, "launch", spy_launch)
+        monkeypatch.setattr(tracing.Span, "__init__", no_span)
         assert tracing._CAPTURE is None and tracing.active_span() is None
         status, _, payload = s.handler.dispatch(
             "POST", "/index/i/query", {}, PAIR.format(a=1, b=3).encode(),
@@ -541,8 +545,9 @@ def test_new_span_sites_cost_nothing_without_a_trace(tmp_path, monkeypatch):
                 "stacks.memo", "stacks.build", "build.frags",
                 "build.window", "build.args", "reduce", "encode"} \
             <= {name for name, _ in seen}
-        assert not {"kernel.fn", "kernel.dispatch", "kernel.wait",
-                    "kernel.fetch"} & {name for name, _ in seen}
+        assert "count_batched" in launched
+        assert {"kernel.fn", "kernel.dispatch", "kernel.wait",
+                "kernel.fetch"} <= {name for name, _ in seen}
     finally:
         s.close()
 
@@ -551,18 +556,23 @@ def test_resource_exhausted_counts_an_oom_fallback(tmp_path, monkeypatch):
     """A batched call the device refuses for memory still answers (per
     slice), still records ``batched:error``, and is counted: in the
     query's resources and in /debug/vars."""
-    from pilosa_tpu import executor as executor_mod
+    from pilosa_tpu.ops import bitops
 
     s = Server(str(tmp_path / "d"), bind="localhost:0").open()
     try:
         _seed_rows(s)
+        real_launch = bitops.launch
 
-        def refuse(fn, stacks):
-            raise RuntimeError("RESOURCE_EXHAUSTED: Out of memory while "
-                               "trying to allocate 125042688 bytes.")
+        def failing(error):
+            def launch(name, *a, **k):
+                if name == "count_batched":
+                    raise RuntimeError(error)
+                return real_launch(name, *a, **k)
+            return launch
 
-        monkeypatch.setattr(executor_mod, "_run_count", refuse)
-        monkeypatch.setattr(executor_mod, "_run_count_split", refuse)
+        monkeypatch.setattr(bitops, "launch", failing(
+            "RESOURCE_EXHAUSTED: Out of memory while trying to allocate "
+            "125042688 bytes."))
         doc = _profiled(s, PAIR.format(a=1, b=2))
         assert doc["results"] == [0]
         res = doc["profile"]["resources"]
@@ -571,10 +581,8 @@ def test_resource_exhausted_counts_an_oom_fallback(tmp_path, monkeypatch):
         assert res["servedBy"] == {"serial": 1}
         assert jget(f"{base(s)}/debug/vars")["oomFallbacks"] == 1
 
-        def other(fn, stacks):
-            raise RuntimeError("INTERNAL: something else")
-
-        monkeypatch.setattr(executor_mod, "_run_count_split", other)
+        monkeypatch.setattr(bitops, "launch",
+                            failing("INTERNAL: something else"))
         res = _profiled(s, PAIR.format(a=1, b=3))["profile"]["resources"]
         assert res["oomFallbacks"] == 0
         assert "batched:error" in res["fallbackChain"]

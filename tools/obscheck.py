@@ -96,9 +96,9 @@ def phase_surfaces(fails):
             # compressed workload on its batched arm in one run.
             server.executor._force_path = "serial"
             try:
-                # >= OBS_STRIDE dispatches per op cell (6 pairs x 3
-                # slices = 18), so every op's stride-sampled serial
-                # cell is GUARANTEED at least one sample.
+                # >= kerneltime.STRIDE launches per op cell (6 pairs x
+                # 3 slices = 18), past each cell's first (compiling)
+                # launch, so the stride reaches the serial cells.
                 for op in ("Union", "Intersect", "Xor", "Difference"):
                     for a, b in ((1, 2), (1, 3), (2, 3), (1, 4),
                                  (2, 4), (3, 4)):
